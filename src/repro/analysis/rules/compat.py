@@ -13,14 +13,11 @@ from typing import Iterable
 from repro.analysis.core import Finding, Rule, dotted_name, register_rule
 from repro.compat import (
     COMPAT_SENSITIVE_ATTRS,
-    COMPAT_SENSITIVE_KWARGS,
-    COMPAT_SENSITIVE_METHODS,
     COMPAT_SENSITIVE_MODULES,
     COMPAT_SENSITIVE_NAMES,
 )
 
-# compat.py holds the shims; test_compat.py exercises the version-sensitive
-# surface on purpose.
+# compat.py holds the wrappers; test_compat.py exercises them on purpose.
 _EXEMPT = ("src/repro/compat.py", "tests/test_compat.py")
 
 
@@ -29,11 +26,9 @@ class CompatIsolationRule(Rule):
     id = "RPR001"
     summary = "version-sensitive JAX symbol referenced outside repro.compat"
     rationale = (
-        "The runtime supports JAX 0.4.30-0.6.x; symbols that moved or "
-        "changed signature across that range (shard_map, AxisType, "
-        "AbstractMesh, make_mesh, axis_size, TPUCompilerParams, check_rep, "
-        "Compiled.cost_analysis) must be reached through repro.compat so "
-        "every call site works on every supported version."
+        "JAX symbols that moved or changed signature in past releases "
+        "(shard_map, AxisType, AbstractMesh, make_mesh, axis_size) are "
+        "reached through repro.compat, so the next move is a one-file edit."
     )
 
     def applies_to(self, relpath: str) -> bool:
@@ -78,29 +73,3 @@ class CompatIsolationRule(Rule):
                         f"{dotted} is version-sensitive; use the "
                         "repro.compat equivalent",
                     )
-            elif isinstance(node, ast.Call):
-                for kw in node.keywords:
-                    if kw.arg in COMPAT_SENSITIVE_KWARGS:
-                        yield self.finding(
-                            relpath,
-                            kw.value,
-                            f"keyword {kw.arg!r} is the pre-0.5 spelling; "
-                            "compat.shard_map takes check_vma",
-                        )
-                func = node.func
-                if (
-                    isinstance(func, ast.Attribute)
-                    and func.attr in COMPAT_SENSITIVE_METHODS
-                    and not self._is_compat_receiver(func.value)
-                ):
-                    yield self.finding(
-                        relpath,
-                        node,
-                        f".{func.attr}() return shape is version-dependent; "
-                        f"call compat.{func.attr}(...) instead",
-                    )
-
-    @staticmethod
-    def _is_compat_receiver(value: ast.AST) -> bool:
-        dotted = dotted_name(value)
-        return dotted is not None and (dotted == "compat" or dotted.endswith(".compat"))

@@ -1,28 +1,18 @@
-"""JAX version-compatibility layer.
+"""The mesh / sharding surface of JAX, pinned to the installed 0.9 series.
 
-The runtime targets JAX 0.4.x through 0.6.x, which moved or reshaped several
-symbols this repo depends on:
+These are the JAX symbols that moved or changed signature in past
+releases: ``shard_map`` (once ``jax.experimental.shard_map`` with a
+``check_rep`` flag), ``make_mesh`` and ``AxisType`` (axis types),
+``AbstractMesh`` (constructor signature) and ``lax.axis_size``.  Call sites
+take them from here, so the next such move is an edit to this one file.
 
-* ``shard_map`` — ``jax.experimental.shard_map.shard_map`` (0.4.x, with a
-  ``check_rep`` flag) became ``jax.shard_map`` (0.5+, flag renamed
-  ``check_vma``).
-* ``AxisType`` — ``jax.sharding.AxisType`` and the ``axis_types=`` parameter
-  of ``jax.make_mesh`` only exist on 0.5+.
-* ``AbstractMesh`` — 0.4.x takes a pair tuple ``((name, size), ...)``;
-  0.5+ takes ``(axis_sizes, axis_names)``.
-* tree utils — ``jax.tree.map`` & co. replaced ``jax.tree_util.tree_map``
-  (old alias kept, new namespace absent on very old releases).
-
-POLICY: no version-sensitive JAX symbol may be referenced outside this
-module (enforced by ISSUE-1's acceptance grep and tests/test_compat.py).
-Call sites import ``shard_map``, ``make_mesh``, ``abstract_mesh``,
-``AxisType`` and the ``tree_*`` aliases from here.
+POLICY: none of these symbols may be referenced outside this module
+(machine-enforced by rule RPR001 of ``python -m repro.analysis``, which
+reads the ``COMPAT_SENSITIVE_*`` registry below).
 """
 
 from __future__ import annotations
 
-import enum
-import inspect
 from typing import Any, Optional, Sequence
 
 import jax
@@ -31,24 +21,21 @@ import jax
 # from one place.
 from jax.sharding import Mesh, NamedSharding, PartitionSpec  # noqa: F401
 
-
 # ---------------------------------------------------------------------------
-# The compat-sensitivity registry. ONE list of the JAX symbols whose name,
-# location, or signature changed across the supported 0.4.x–0.6.x range.
-# repro.analysis rule RPR001 reads these to forbid any reference outside this
-# module (replacing the old ROADMAP `rg` spot-check); keeping the data here
-# means adding a shim and banning direct use are the same edit.
+# The compat-sensitivity registry: the symbols above, which must never be
+# spelled at call sites.  repro.analysis rule RPR001 reads these, so adding
+# a wrapper and banning direct use are the same edit.
 # ---------------------------------------------------------------------------
 
-# Dotted attribute paths that must never be spelled at call sites.
+# Dotted attribute paths.
 COMPAT_SENSITIVE_ATTRS = frozenset(
     {
-        "jax.shard_map",  # 0.5+ only (0.4.x: jax.experimental.shard_map)
+        "jax.shard_map",
         "jax.experimental.shard_map.shard_map",
-        "jax.sharding.AxisType",  # 0.5+ only
-        "jax.sharding.AbstractMesh",  # ctor signature flipped at 0.5
-        "jax.make_mesh",  # axis_types= param is 0.5+ only
-        "jax.lax.axis_size",  # 0.5+ only
+        "jax.sharding.AxisType",
+        "jax.sharding.AbstractMesh",
+        "jax.make_mesh",
+        "jax.lax.axis_size",
     }
 )
 
@@ -57,96 +44,19 @@ COMPAT_SENSITIVE_MODULES = frozenset({"jax.experimental.shard_map"})
 
 # Names that must not be from-imported out of any jax.* module.
 COMPAT_SENSITIVE_NAMES = frozenset(
-    {
-        "shard_map",
-        "AxisType",
-        "AbstractMesh",
-        "make_mesh",
-        "axis_size",
-        "TPUCompilerParams",  # renamed CompilerParams at 0.5
-        "CompilerParams",
-    }
+    {"shard_map", "AxisType", "AbstractMesh", "make_mesh", "axis_size"}
 )
 
-# Keyword arguments whose spelling is version-dependent (check_rep became
-# check_vma; compat.shard_map accepts only the new spelling).
-COMPAT_SENSITIVE_KWARGS = frozenset({"check_rep"})
-
-# Methods whose return shape is version-dependent; call the wrapper instead.
-COMPAT_SENSITIVE_METHODS = frozenset({"cost_analysis"})
-
-
-def _version_tuple(v: str) -> tuple:
-    parts = []
-    for piece in v.split(".")[:3]:
-        digits = "".join(ch for ch in piece if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: tuple = _version_tuple(jax.__version__)
-
-
-# ---------------------------------------------------------------------------
-# shard_map: jax.shard_map (0.5+) vs jax.experimental.shard_map (0.4.x)
-# ---------------------------------------------------------------------------
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_shard_map_impl).parameters)
+AxisType = jax.sharding.AxisType
+axis_size = jax.lax.axis_size
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
-    """Version-agnostic shard_map.
-
-    ``check_vma`` follows the 0.5+ spelling; on 0.4.x it is forwarded as
-    ``check_rep`` (same semantics: per-output replication/varying-manual-axes
-    checking). ``None`` keeps the installed JAX's default.
-    """
-    kwargs: dict = {}
-    if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kwargs["check_rep"] = check_vma
-    return _shard_map_impl(
+    """``jax.shard_map``; ``check_vma=None`` keeps JAX's default."""
+    kwargs: dict = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )
-
-
-# ---------------------------------------------------------------------------
-# AxisType / mesh construction
-# ---------------------------------------------------------------------------
-HAS_AXIS_TYPES: bool = hasattr(jax.sharding, "AxisType")
-
-if HAS_AXIS_TYPES:
-    AxisType = jax.sharding.AxisType
-else:
-
-    class AxisType(enum.Enum):
-        """Stand-in for jax.sharding.AxisType on JAX < 0.5.
-
-        0.4.x meshes have no axis-type concept; every axis behaves like the
-        later ``Auto`` (GSPMD decides). The enum exists so call sites can
-        spell intent uniformly; it is dropped at mesh construction.
-        """
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-# jax.make_mesh only exists from 0.4.35; below that, fall back to arranging
-# jax.devices() by hand. Introspection must stay guarded so merely importing
-# compat never crashes on an older JAX.
-_HAS_MAKE_MESH: bool = hasattr(jax, "make_mesh")
-_MAKE_MESH_PARAMS = (
-    frozenset(inspect.signature(jax.make_mesh).parameters)
-    if _HAS_MAKE_MESH
-    else frozenset()
-)
 
 
 def make_mesh(
@@ -156,108 +66,23 @@ def make_mesh(
     axis_types: Optional[Sequence[Any]] = None,
     devices=None,
 ) -> Mesh:
-    """jax.make_mesh that tolerates JAX versions without ``axis_types``.
-
-    When the installed JAX supports axis types and ``axis_types`` is None,
-    every axis defaults to Auto (the 0.4.x behavior), so meshes built here
-    lower identically across versions.
-    """
-    axis_shapes = tuple(axis_shapes)
+    """``jax.make_mesh`` with every axis ``Auto`` unless ``axis_types`` says
+    otherwise (GSPMD decides placement, which the sharding rules assume)."""
     axis_names = tuple(axis_names)
-    if not _HAS_MAKE_MESH:
-        import numpy as np
-
-        n_dev = 1
-        for s in axis_shapes:
-            n_dev *= s
-        devs = list(devices) if devices is not None else jax.devices()[:n_dev]
-        return Mesh(np.asarray(devs).reshape(axis_shapes), axis_names)
-    kwargs: dict = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPES and "axis_types" in _MAKE_MESH_PARAMS:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axis_names)
-        kwargs["axis_types"] = tuple(axis_types)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-# AbstractMesh: 0.4.x __init__(shape_tuple=((name, size), ...));
-# 0.5+ __init__(axis_sizes, axis_names, *, axis_types=...). Absent on very
-# old JAX, so introspect lazily via getattr.
-_ABSTRACT_MESH_CLS = getattr(jax.sharding, "AbstractMesh", None)
-_ABSTRACT_MESH_PAIR_STYLE: bool = _ABSTRACT_MESH_CLS is not None and (
-    "shape_tuple" in inspect.signature(_ABSTRACT_MESH_CLS.__init__).parameters
-)
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axis_names)
+    kwargs: dict = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(
+        tuple(axis_shapes), axis_names, axis_types=tuple(axis_types), **kwargs
+    )
 
 
 def abstract_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """Device-free mesh for sharding resolution, on any supported JAX."""
+    """Device-free mesh for sharding resolution."""
     axis_shapes = tuple(axis_shapes)
     axis_names = tuple(axis_names)
     if len(axis_shapes) != len(axis_names):
         raise ValueError(
             f"axis_shapes {axis_shapes} and axis_names {axis_names} disagree"
         )
-    if _ABSTRACT_MESH_CLS is None:
-        raise NotImplementedError(
-            f"jax {jax.__version__} has no jax.sharding.AbstractMesh; "
-            "device-free sharding resolution needs jax >= 0.4.31"
-        )
-    if _ABSTRACT_MESH_PAIR_STYLE:
-        return _ABSTRACT_MESH_CLS(tuple(zip(axis_names, axis_shapes)))
-    return _ABSTRACT_MESH_CLS(axis_shapes, axis_names)
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU compiler params: pltpu.CompilerParams (0.5+) was named
-# pltpu.TPUCompilerParams on 0.4.x (same fields). Lazy import so compat
-# stays light for the many call sites that never touch Pallas.
-# ---------------------------------------------------------------------------
-def pallas_tpu_compiler_params(**kwargs):
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-# ---------------------------------------------------------------------------
-# Compiled.cost_analysis(): 0.4.x returns a one-element list of dicts,
-# 0.5+ returns the dict directly (or None when unavailable).
-# ---------------------------------------------------------------------------
-def cost_analysis(compiled) -> dict:
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-# ---------------------------------------------------------------------------
-# axis_size: jax.lax.axis_size is 0.5+; older JAX gets it via psum(1, axis),
-# which constant-folds to a concrete int inside shard_map/pmap traces.
-# ---------------------------------------------------------------------------
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-
-    def axis_size(axis_name):
-        return jax.lax.psum(1, axis_name)
-
-
-# ---------------------------------------------------------------------------
-# tree utils: jax.tree.* (0.4.25+) vs jax.tree_util.tree_*
-# ---------------------------------------------------------------------------
-if hasattr(jax, "tree") and hasattr(jax.tree, "map"):
-    tree_map = jax.tree.map
-    tree_leaves = jax.tree.leaves
-    tree_flatten = jax.tree.flatten
-    tree_unflatten = jax.tree.unflatten
-    tree_structure = jax.tree.structure
-else:  # pragma: no cover - pre-0.4.25 fallback
-    from jax import tree_util as _tree_util
-
-    tree_map = _tree_util.tree_map
-    tree_leaves = _tree_util.tree_leaves
-    tree_flatten = _tree_util.tree_flatten
-    tree_unflatten = _tree_util.tree_unflatten
-    tree_structure = _tree_util.tree_structure
+    return jax.sharding.AbstractMesh(axis_shapes, axis_names)

@@ -7,6 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 
@@ -25,14 +26,11 @@ def flash_attention(
     scale: Optional[float] = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, tq, h, hd = q.shape
     _, tk, kvh, _ = k.shape
     scale = scale if scale is not None else hd ** -0.5
     kv_valid = tk if kv_valid is None else kv_valid
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
 
     bq = min(bq, _round_up(tq, 8))
     bk = min(bk, _round_up(tk, 8))
@@ -50,6 +48,6 @@ def flash_attention(
         n_rep=h // kvh,
         bq=bq,
         bk=bk,
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )
     return out.transpose(0, 2, 1, 3)[:, :tq]

@@ -14,7 +14,7 @@ TPU-native adaptation of the paper's DPU datapath (DESIGN.md §3):
 
 Analog channel stages (DESIGN.md §8) run *inside* the kernel so the noisy
 path needs no extra HBM traffic: inter-modulation / cross-weight crosstalk
-as extra chunk-local MXU passes against neighbor-shifted operands, filter
+as one extra chunk-local MXU pass against neighbor-shifted operands, filter
 truncation as a psum scale, detector noise from a counter-based gaussian
 generator (`repro.noise.stages`) seeded by a scalar SMEM input — bitwise
 deterministic for a fixed seed + tiling, statistically matching the jnp
@@ -22,9 +22,13 @@ oracle (which draws from flat, untiled streams).
 
 Blocking: grid ``(R/TR, C/TC, K/TK)`` with the K axis innermost so the output
 tile stays resident in VMEM and accumulates across K-tiles (standard Pallas
-matmul accumulation).  ``TK`` must be a multiple of ``n_chunk``; MXU-aligned
-tiles (multiples of 128) are used when ADC/analog fidelity is off (chunking
-is then numerically irrelevant), and exact-N chunks when it is on.
+matmul accumulation).  Every block is lane-aligned: ``TK`` is a multiple
+of ``chunk_lanes``, itself a multiple of 128.  When ADC/analog fidelity is
+off chunking is numerically irrelevant and the tile is one MXU pass; when
+it is on, each DPE chunk of ``N`` operands sits zero-padded in its own
+``chunk_lanes``-wide slot (``repro.photonic.engine.to_chunk_slots``), so
+a chunk never starts off a lane boundary and zero padding adds nothing to
+its integer psum.  All products run on the MXU as int8 x int8 -> int32.
 
 Two entry points share the datapath:
 
@@ -48,7 +52,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels.photonic_gemm.epilogue import (
     EpilogueSpec,
     apply_epilogue,
@@ -57,11 +60,25 @@ from repro.kernels.photonic_gemm.epilogue import (
 from repro.noise.stages import fold_seed, gaussian_from_counter, neighbor_sum
 
 
+_MM = (((1,), (0,)), ((), ()))
+
+
+def _int8_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """One slice pair's psum on the MXU: int8 x int8 -> int32, exact.
+
+    Slices are signed magnitudes of int8 operands, so they fit int8; the
+    TPU's matrix unit multiplies int8 natively and refuses int32 operands.
+    """
+    return jax.lax.dot_general(
+        a.astype(jnp.int8), b.astype(jnp.int8), _MM, preferred_element_type=jnp.int32
+    )
+
+
 def _f32_dot(a: jax.Array, b: jax.Array) -> jax.Array:
     return jax.lax.dot_general(
         a.astype(jnp.float32),
         b.astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
+        _MM,
         preferred_element_type=jnp.float32,
     )
 
@@ -73,7 +90,7 @@ def _accumulate(
     *,
     slice_bits: int,
     num_slices: int,
-    n_chunk: int,
+    chunk_lanes: int,
     adc_bits: Optional[int],
     noise_sigma: float,
     filter_alpha: float,
@@ -85,7 +102,9 @@ def _accumulate(
 
     The single definition of the bit-sliced / psum-chunked / analog-stage
     accumulation, shared by the integer and fused kernels so the two can
-    never drift.
+    never drift.  The K-tile holds ``TK // chunk_lanes`` chunk slots; each
+    slot starts with one DPE chunk's ``N`` operands and is zero beyond
+    them (the layout of :func:`repro.photonic.engine.to_chunk_slots`).
     """
     analog = (
         noise_sigma > 0.0
@@ -95,7 +114,7 @@ def _accumulate(
     )
     tr, tk = x.shape
     _, tc = w.shape
-    chunks = tk // n_chunk
+    chunks = tk // chunk_lanes
 
     sgn_x, mag_x = jnp.sign(x), jnp.abs(x)
     sgn_w, mag_w = jnp.sign(w), jnp.abs(w)
@@ -107,57 +126,50 @@ def _accumulate(
         for ti in range(num_slices):
             ws = sgn_w * ((mag_w >> (slice_bits * ti)) & mask)
             shift = slice_bits * (si + ti)
-            if not analog and adc_bits is None and chunks >= 1:
+            if not analog and adc_bits is None:
                 # Ideal ADC: chunk boundaries are numerically irrelevant —
                 # one MXU pass over the whole K-tile.
-                psum = jax.lax.dot_general(
-                    xs,
-                    ws,
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32,
-                )
-                acc = acc + (psum << shift)
-            else:
-                # DPU-faithful: run each N-size chunk through the analog
-                # signal chain (crosstalk -> filter -> noise -> ADC).
-                lim = 2 ** (adc_bits - 1) - 1 if adc_bits is not None else None
-                for g in range(chunks):
-                    sl = slice(g * n_chunk, (g + 1) * n_chunk)
-                    x_c, w_c = xs[:, sl], ws[sl, :]
-                    psum = jax.lax.dot_general(
-                        x_c,
-                        w_c,
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.int32,
-                    )
-                    if analog:
-                        a = psum.astype(jnp.float32)
+                acc = acc + (_int8_dot(xs, ws) << shift)
+                continue
+            # DPU-faithful: run each chunk through the analog signal chain
+            # (crosstalk -> filter -> noise -> ADC).
+            lim = 2 ** (adc_bits - 1) - 1 if adc_bits is not None else None
+            for g in range(chunks):
+                sl = slice(g * chunk_lanes, (g + 1) * chunk_lanes)
+                x_c, w_c = xs[:, sl], ws[sl, :]
+                psum = _int8_dot(x_c, w_c)
+                if analog:
+                    a = psum.astype(jnp.float32)
+                    if intermod_eps > 0.0 or crossweight_eps > 0.0:
+                        # Inter-modulation (x leaks to adjacent lanes) and
+                        # cross-weight (w to adjacent rows) crosstalk are
+                        # one integer: nb(x) @ w == x @ nb(w), since the
+                        # neighbour relation is symmetric and the slot's
+                        # padding lanes are zero.  One exact contraction
+                        # serves both terms.
+                        t = _f32_dot(neighbor_sum(x_c, axis=1), w_c)
                         if intermod_eps > 0.0:
-                            a = a + intermod_eps * _f32_dot(
-                                neighbor_sum(x_c, axis=1), w_c
-                            )
+                            a = a + intermod_eps * t
                         if crossweight_eps > 0.0:
-                            a = a + crossweight_eps * _f32_dot(
-                                x_c, neighbor_sum(w_c, axis=0)
-                            )
-                        if filter_alpha > 0.0:
-                            a = a * (1.0 - filter_alpha)
-                        if noise_sigma > 0.0:
-                            z = gaussian_from_counter(
-                                fold_seed(tile_seed, si * num_slices + ti, g),
-                                a.shape,
-                            )
-                            if valid_chunks is not None:
-                                # Chunks entirely inside K-padding carry no
-                                # data and fire no optical pass — mask their
-                                # noise so variance matches the oracle.
-                                gchunk = pl.program_id(2) * chunks + g
-                                z = z * (gchunk < valid_chunks).astype(jnp.float32)
-                            a = a + noise_sigma * z
-                        psum = jnp.round(a).astype(jnp.int32)
-                    if lim is not None:
-                        psum = jnp.clip(psum, -lim, lim)
-                    acc = acc + (psum << shift)
+                            a = a + crossweight_eps * t
+                    if filter_alpha > 0.0:
+                        a = a * (1.0 - filter_alpha)
+                    if noise_sigma > 0.0:
+                        z = gaussian_from_counter(
+                            fold_seed(tile_seed, si * num_slices + ti, g),
+                            a.shape,
+                        )
+                        if valid_chunks is not None:
+                            # Chunks entirely inside K-padding carry no
+                            # data and fire no optical pass — mask their
+                            # noise so variance matches the oracle.
+                            gchunk = pl.program_id(2) * chunks + g
+                            z = z * (gchunk < valid_chunks).astype(jnp.float32)
+                        a = a + noise_sigma * z
+                    psum = jnp.round(a).astype(jnp.int32)
+                if lim is not None:
+                    psum = jnp.clip(psum, -lim, lim)
+                acc = acc + (psum << shift)
     return acc
 
 
@@ -165,7 +177,7 @@ def _kernel(
     *refs,
     slice_bits: int,
     num_slices: int,
-    n_chunk: int,
+    chunk_lanes: int,
     adc_bits: Optional[int],
     noise_sigma: float,
     filter_alpha: float,
@@ -205,7 +217,7 @@ def _kernel(
         tile_seed,
         slice_bits=slice_bits,
         num_slices=num_slices,
-        n_chunk=n_chunk,
+        chunk_lanes=chunk_lanes,
         adc_bits=adc_bits,
         noise_sigma=noise_sigma,
         filter_alpha=filter_alpha,
@@ -224,7 +236,7 @@ def _fused_kernel(
     out_dtype,
     slice_bits: int,
     num_slices: int,
-    n_chunk: int,
+    chunk_lanes: int,
     adc_bits: Optional[int],
     noise_sigma: float,
     filter_alpha: float,
@@ -276,7 +288,7 @@ def _fused_kernel(
         tile_seed,
         slice_bits=slice_bits,
         num_slices=num_slices,
-        n_chunk=n_chunk,
+        chunk_lanes=chunk_lanes,
         adc_bits=adc_bits,
         noise_sigma=noise_sigma,
         filter_alpha=filter_alpha,
@@ -303,7 +315,7 @@ def _fused_kernel(
     static_argnames=(
         "slice_bits",
         "num_slices",
-        "n_chunk",
+        "chunk_lanes",
         "adc_bits",
         "noise_sigma",
         "filter_alpha",
@@ -323,7 +335,7 @@ def photonic_gemm_pallas(
     *,
     slice_bits: int = 4,
     num_slices: int = 2,
-    n_chunk: int = 128,
+    chunk_lanes: int = 128,
     adc_bits: Optional[int] = None,
     noise_sigma: float = 0.0,
     filter_alpha: float = 0.0,
@@ -342,7 +354,10 @@ def photonic_gemm_pallas(
         wq.shape,
         (tile_r, tile_c, tile_k),
     )
-    assert tile_k % n_chunk == 0, (tile_k, n_chunk)
+    assert tile_k % chunk_lanes == 0 and chunk_lanes % 128 == 0, (
+        tile_k,
+        chunk_lanes,
+    )
     analog = (
         noise_sigma > 0.0
         or filter_alpha > 0.0
@@ -357,7 +372,7 @@ def photonic_gemm_pallas(
         _kernel,
         slice_bits=slice_bits,
         num_slices=num_slices,
-        n_chunk=n_chunk,
+        chunk_lanes=chunk_lanes,
         adc_bits=adc_bits,
         noise_sigma=noise_sigma,
         filter_alpha=filter_alpha,
@@ -381,7 +396,7 @@ def photonic_gemm_pallas(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tile_r, tile_c), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c), jnp.int32),
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -396,7 +411,7 @@ def photonic_gemm_pallas(
         "out_dtype",
         "slice_bits",
         "num_slices",
-        "n_chunk",
+        "chunk_lanes",
         "adc_bits",
         "noise_sigma",
         "filter_alpha",
@@ -422,7 +437,7 @@ def photonic_gemm_fused_pallas(
     out_dtype=jnp.float32,
     slice_bits: int = 4,
     num_slices: int = 2,
-    n_chunk: int = 128,
+    chunk_lanes: int = 128,
     adc_bits: Optional[int] = None,
     noise_sigma: float = 0.0,
     filter_alpha: float = 0.0,
@@ -451,7 +466,10 @@ def photonic_gemm_fused_pallas(
         wq.shape,
         (tile_r, tile_c, tile_k),
     )
-    assert tile_k % n_chunk == 0, (tile_k, n_chunk)
+    assert tile_k % chunk_lanes == 0 and chunk_lanes % 128 == 0, (
+        tile_k,
+        chunk_lanes,
+    )
     analog = (
         noise_sigma > 0.0
         or filter_alpha > 0.0
@@ -473,7 +491,7 @@ def photonic_gemm_fused_pallas(
         out_dtype=out_dtype,
         slice_bits=slice_bits,
         num_slices=num_slices,
-        n_chunk=n_chunk,
+        chunk_lanes=chunk_lanes,
         adc_bits=adc_bits,
         noise_sigma=noise_sigma,
         filter_alpha=filter_alpha,
@@ -508,7 +526,7 @@ def photonic_gemm_fused_pallas(
         out_specs=pl.BlockSpec((tile_r, tile_c), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c), out_dtype),
         scratch_shapes=[pltpu.VMEM((tile_r, tile_c), jnp.int32)],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -41,7 +41,6 @@ def photonic_gemm_int(
     cfg: DPUConfig,
     *,
     backend: str = "pallas",
-    interpret: Optional[bool] = None,
     tile_r: int = 128,
     tile_c: int = 128,
     prng_key: Optional[jax.Array] = None,
@@ -52,7 +51,6 @@ def photonic_gemm_int(
         xq,
         wq,
         prng_key=prng_key,
-        interpret=interpret,
         tile_r=tile_r,
         tile_c=tile_c,
     )

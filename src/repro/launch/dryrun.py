@@ -1,12 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-from repro.launch import profile as _profile  # noqa: E402
-
-# Tuned launch profile (log hygiene, persistent compilation cache; the
-# device-count flag above is already set, so the merge leaves it alone).
-_profile.apply()
-
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture x input-shape x mesh) cell: build the step function
@@ -15,13 +6,28 @@ ShapeDtypeStruct inputs (no allocation), ``.compile()`` on the production
 mesh, and record ``memory_analysis()`` / ``cost_analysis()`` / the HLO
 collective summary to a JSON cache consumed by the roofline report.
 
-The XLA_FLAGS line above MUST stay the first statement — jax locks the
-device count at first init.  Smoke tests / benches never import this module.
+A CPU-only tool: the production mesh is 256-512 virtual host devices.  The
+environment lines below pin the CPU backend and the device count before
+anything imports jax (the count locks at first init); other XLA flags
+are kept.  Smoke tests / benches never import this module.
 
 Usage:
   python -m repro.launch.dryrun --arch granite-3-8b --shape train_4k --mesh single
   python -m repro.launch.dryrun --sweep            # all cells, subprocess each
 """
+
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+_DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
+os.environ["XLA_FLAGS"] = " ".join(
+    [
+        flag
+        for flag in os.environ.get("XLA_FLAGS", "").split()
+        if not flag.startswith(_DEVICE_COUNT_FLAG)
+    ]
+    + [f"{_DEVICE_COUNT_FLAG}=512"]
+)
 
 import argparse
 import dataclasses
@@ -32,6 +38,8 @@ import sys
 import time
 import traceback
 from pathlib import Path
+
+from repro.launch import profile as _profile
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "results" / "dryrun"
 HLO_DIR = Path(__file__).resolve().parents[3] / "results" / "hlo"
@@ -61,7 +69,6 @@ def run_cell(
     import jax
     import jax.numpy as jnp
 
-    from repro import compat
     from repro.core.dpu import DPUConfig
     from repro.launch import hlo_analysis
     from repro.launch.mesh import make_production_mesh, require_devices
@@ -112,7 +119,7 @@ def run_cell(
         "padded_vocab": cfg.padded_vocab,
         "num_kv_heads_effective": cfg.num_kv_heads,
         "param_count": sum(
-            int(jnp.prod(jnp.array(l.shape))) for l in compat.tree_leaves(param_sds)
+            int(jnp.prod(jnp.array(l.shape))) for l in jax.tree.leaves(param_sds)
         ),
         "photonic_engine": None if eng is None else eng.describe().to_dict(),
     }
@@ -142,9 +149,9 @@ def run_cell(
             jitted = jax.jit(
                 step,
                 in_shardings=(
-                    compat.tree_map(lambda _: repl, bsds),
-                    compat.tree_map(lambda _: repl, opt_sds),
-                    compat.tree_map(lambda _: bsh, batch_sds),
+                    jax.tree.map(lambda _: repl, bsds),
+                    jax.tree.map(lambda _: repl, opt_sds),
+                    jax.tree.map(lambda _: bsh, batch_sds),
                 ),
                 donate_argnums=(0, 1),
             )
@@ -245,7 +252,7 @@ def run_cell(
         ):
             out[field] = getattr(ma, field, None)
 
-        ca = compat.cost_analysis(compiled)
+        ca = (compiled.cost_analysis() or {})
         out["hlo_flops_per_device"] = ca.get("flops")
         out["hlo_bytes_per_device"] = ca.get("bytes accessed")
 
@@ -264,7 +271,7 @@ def run_cell(
             with shd.use_rules(mesh, lcfg.logical_rules):
                 lj, largs = build(lcfg)
                 lcomp = lj.lower(*largs).compile()
-            lca = compat.cost_analysis(lcomp)
+            lca = (lcomp.cost_analysis() or {})
             dot_b = hlo_analysis.matmul_traffic_bytes(lcomp.as_text())
             ladder_steps[step_name] = {
                 "coeff": coeff,
@@ -477,6 +484,7 @@ def main():
     if args.annotate_cell:
         path = _cell_path(args.arch, args.shape, args.mesh, "base")
         existing = json.loads(path.read_text())
+        _profile.apply()
         out = run_cell(args.arch, args.shape, args.mesh, skip_main=True)
         out["dot_bytes_per_device_exact"] = out.get("dot_bytes_ladder_only", 0.0) + (
             existing.get("argument_size_in_bytes") or 0.0
@@ -486,6 +494,9 @@ def main():
         return
 
     assert args.arch and args.shape, "--arch and --shape required (or --sweep)"
+    # Tuned launch profile (log hygiene, persistent compilation cache) for
+    # the cell's own process; the sweep parents above stay off jax.
+    _profile.apply()
     overrides = {}
     for ov in args.override:
         k, v = ov.split("=", 1)

@@ -22,9 +22,10 @@ invoking shell happened to export:
   the curated set changes numerics — the bitwise contracts
   (DESIGN.md §7/§14) hold with or without the profile.
 * **persistent compilation cache** — ``jax_compilation_cache_dir``
-  pointed at a keyed directory so repeat benchmark runs (and CI, which
-  restores the directory from its cache action) skip recompilation; the
-  first trace of a decode step dominates cold benchmark wall-clock.
+  pointed at ``JAX_COMPILATION_CACHE_DIR`` when it is set, else at
+  ``<checkout>/.jax_cache``, so repeat runs (and CI, which restores the
+  directory from its cache action) skip recompilation; the first trace of
+  a decode step dominates cold wall-clock.
 
 :func:`describe` snapshots the resolved profile; ``benchmarks/run.py``
 embeds it in ``results/BENCH_photonic.json`` so every committed number
@@ -34,6 +35,7 @@ names the environment that produced it.
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Dict, List, Optional
 
 # Known tcmalloc install paths (Debian/Ubuntu multiarch, RH, conda).
@@ -60,9 +62,10 @@ XLA_FLAG_DEFAULTS: List[str] = [
     "--xla_cpu_parallel_codegen_split_count=8",
 ]
 
-_DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "repro_jax_cache"
-)
+# The persistent compilation cache's home when JAX_COMPILATION_CACHE_DIR is
+# unset: one fixed, gitignored directory inside the checkout.  A cache hit
+# needs the same path every run, so it is never temporary or per-process.
+DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 
 def find_tcmalloc() -> Optional[str]:
@@ -91,9 +94,30 @@ def _merge_xla_flags(extra: List[str]) -> str:
     return merged
 
 
+def compilation_cache_dir() -> str:
+    """``JAX_COMPILATION_CACHE_DIR`` when set, else :data:`DEFAULT_CACHE_DIR`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+
+
+def enable_compilation_cache() -> str:
+    """Point JAX's persistent compilation cache at
+    :func:`compilation_cache_dir` and return that directory.
+
+    Call before the first compile.  Every compile is cached: the default
+    one-second floor would skip exactly the small kernels whose compiles
+    dominate a cold start.
+    """
+    import jax
+
+    cache = compilation_cache_dir()
+    os.makedirs(cache, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", cache)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache
+
+
 def apply(
     *,
-    cache_dir: Optional[str] = None,
     xla_flags: Optional[List[str]] = None,
     compilation_cache: bool = True,
 ) -> Dict[str, object]:
@@ -110,44 +134,23 @@ def apply(
     for key, val in ENV_DEFAULTS.items():
         os.environ.setdefault(key, val)
     _merge_xla_flags(XLA_FLAG_DEFAULTS if xla_flags is None else xla_flags)
-
-    resolved_cache = None
     if compilation_cache:
-        resolved_cache = (
-            cache_dir
-            or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-            or _DEFAULT_CACHE_DIR
-        )
-        try:
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", resolved_cache)
-            # Cache every compile — benchmark steps are small; the default
-            # 1 s floor would skip exactly the dispatch-bound kernels the
-            # fused-hot-path benchmark measures.
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-            os.makedirs(resolved_cache, exist_ok=True)
-        except Exception:
-            resolved_cache = None  # old jax / read-only FS: run uncached
+        enable_compilation_cache()
     return describe()
 
 
 def describe() -> Dict[str, object]:
     """Snapshot of the effective launch profile (recorded into benchmark
     JSON so committed numbers name their environment)."""
-    try:
-        import jax
+    import jax
 
-        cache = jax.config.jax_compilation_cache_dir
-    except Exception:
-        cache = None
     return {
         "tcmalloc_found": find_tcmalloc(),
         "tcmalloc_active": tcmalloc_active(),
         "ld_preload": os.environ.get("LD_PRELOAD") or None,
         "tf_cpp_min_log_level": os.environ.get("TF_CPP_MIN_LOG_LEVEL"),
         "xla_flags": os.environ.get("XLA_FLAGS") or None,
-        "jax_compilation_cache_dir": cache,
+        "jax_compilation_cache_dir": jax.config.jax_compilation_cache_dir,
     }
 
 
@@ -161,5 +164,5 @@ def child_env(base: Optional[Dict[str, str]] = None) -> Dict[str, str]:
     tc = find_tcmalloc()
     if tc and "tcmalloc" not in env.get("LD_PRELOAD", ""):
         env["LD_PRELOAD"] = ":".join(filter(None, [env.get("LD_PRELOAD"), tc]))
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", _DEFAULT_CACHE_DIR)
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", compilation_cache_dir())
     return env

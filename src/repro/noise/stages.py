@@ -140,11 +140,16 @@ def gaussian_from_counter(base: jax.Array, shape) -> jax.Array:
             stride = stride * jnp.uint32(shape[ax])
     u1 = hash_mix32(base ^ (ctr * jnp.uint32(_S1)))
     u2 = hash_mix32(base ^ (ctr * jnp.uint32(_S2)) ^ jnp.uint32(_S3))
-    # 24-bit mantissa uniforms; u1 offset keeps log() finite.
-    f1 = (u1 >> jnp.uint32(8)).astype(jnp.float32) * (1.0 / (1 << 24)) + (
-        0.5 / (1 << 24)
-    )
-    f2 = (u2 >> jnp.uint32(8)).astype(jnp.float32) * (1.0 / (1 << 24))
+    # 24-bit mantissa uniforms; u1 offset keeps log() finite.  The 24-bit
+    # words go through int32 (same value) because the TPU kernel compiler
+    # has no uint32 -> float32 conversion.
+    def unit(u):
+        return (u >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32) * (
+            1.0 / (1 << 24)
+        )
+
+    f1 = unit(u1) + (0.5 / (1 << 24))
+    f2 = unit(u2)
     return jnp.sqrt(-2.0 * jnp.log(f1)) * jnp.cos((2.0 * jnp.pi) * f2)
 
 

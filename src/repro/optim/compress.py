@@ -45,7 +45,7 @@ def compressed_psum(tree: Any, axis_name: str) -> Any:
         total = jax.lax.psum(q2.astype(jnp.int32), axis_name)
         return (total.astype(jnp.float32) * scale_max).astype(g.dtype)
 
-    return compat.tree_map(leaf, tree)
+    return jax.tree.map(leaf, tree)
 
 
 def ring_int8_allreduce(tree: Any, axis_name) -> Any:
@@ -106,7 +106,7 @@ def ring_int8_allreduce(tree: Any, axis_name) -> Any:
             out = out[: g.size]
         return out.reshape(shape).astype(g.dtype)
 
-    return compat.tree_map(leaf, tree)
+    return jax.tree.map(leaf, tree)
 
 
 def quantize_dequantize(tree: Any) -> Tuple[Any, Any]:
@@ -117,11 +117,11 @@ def quantize_dequantize(tree: Any) -> Tuple[Any, Any]:
         deq = (q.astype(jnp.float32) * scale).astype(g.dtype)
         return deq, (g - deq)
 
-    pairs = compat.tree_map(leaf, tree)
-    comp = compat.tree_map(
+    pairs = jax.tree.map(leaf, tree)
+    comp = jax.tree.map(
         lambda p: p[0], pairs, is_leaf=lambda x: isinstance(x, tuple)
     )
-    resid = compat.tree_map(
+    resid = jax.tree.map(
         lambda p: p[1], pairs, is_leaf=lambda x: isinstance(x, tuple)
     )
     return comp, resid
@@ -129,5 +129,5 @@ def quantize_dequantize(tree: Any) -> Tuple[Any, Any]:
 
 def with_error_feedback(grads: Any, residual: Any) -> Tuple[Any, Any]:
     """Add carried residual, compress, return (compressed, new residual)."""
-    fed = compat.tree_map(lambda g, r: g + r.astype(g.dtype), grads, residual)
+    fed = jax.tree.map(lambda g, r: g + r.astype(g.dtype), grads, residual)
     return quantize_dequantize(fed)

@@ -46,10 +46,12 @@ from repro.analysis.contracts import count_weight_round_ops  # noqa: F401
 from repro.core.dpu import (
     DPUConfig,
     bit_slices,
+    dense_vjp,
     quant_scale,
     quantize_symmetric,
     quantize_with_scale,
 )
+from repro.kernels import pallas_interpret
 from repro.kernels.photonic_gemm.epilogue import (
     ACTIVATIONS,
     Epilogue,
@@ -116,10 +118,6 @@ def _digital_reference(x, wf, bias, spec: EpilogueSpec) -> jax.Array:
     return y
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
-
-
 def site_hash(site: str) -> int:
     """Stable 32-bit FNV-1a of a site name (process-independent)."""
     h = 2166136261
@@ -131,10 +129,12 @@ def site_hash(site: str) -> int:
 def pallas_tiling(cfg: DPUConfig, k: int, c: int) -> Tuple[int, int, int]:
     """Static Pallas tiling ``(n_chunk, tile_k, tile_c)`` for a (K, C) weight.
 
-    Depends only on the config and the weight shape — never on the
+    ``n_chunk`` is the psum chunk (the DPE size ``N`` when ADC/analog
+    fidelity is on), ``tile_k`` the K extent of one kernel block in the
+    chunk-slot layout (:func:`to_chunk_slots`), ``tile_c`` the column
+    block.  Depends only on the config and the weight shape — never on the
     activations — which is what makes the padded weight layout prepackable
-    (:mod:`repro.photonic.packing`).  Matches the historical
-    ``photonic_gemm_int`` tile selection bit-for-bit.
+    (:mod:`repro.photonic.packing`).
     """
     channel = cfg.effective_channel()
     analog = channel is not None and channel.analog
@@ -144,12 +144,64 @@ def pallas_tiling(cfg: DPUConfig, k: int, c: int) -> Tuple[int, int, int]:
         tile_k = 512 if k >= 512 else _round_up(max(k, 128), 128)
         n_chunk = min(128, tile_k)
     else:
-        # DPU-faithful chunking at the achievable DPE size N.
-        n = cfg.n
-        n_chunk = n
-        tile_k = n * max(1, 512 // n)
+        # DPU-faithful chunking at the achievable DPE size N: 512 // N
+        # chunks per block, each in its own lane-aligned slot.
+        n_chunk = cfg.n
+        tile_k = chunk_lanes(n_chunk) * max(1, 512 // n_chunk)
     tile_c = min(128, _round_up(c, 128))
     return n_chunk, tile_k, tile_c
+
+
+def chunk_lanes(n_chunk: int) -> int:
+    """Lane width of one chunk slot: ``n_chunk`` rounded up to the 128
+    lanes of a TPU vector register."""
+    return _round_up(n_chunk, 128)
+
+
+def stored_k(k: int, tiling: Tuple[int, int, int]) -> int:
+    """Stored K extent of a logical K under ``tiling``: one slot per
+    chunk, padded to whole ``tile_k`` blocks."""
+    n_chunk, tile_k, _ = tiling
+    return _round_up(-(-k // n_chunk) * chunk_lanes(n_chunk), tile_k)
+
+
+def to_chunk_slots(a: jax.Array, k: int, tiling, axis: int) -> jax.Array:
+    """Lay the logical K axis of ``a`` out as the Pallas kernel reads it.
+
+    Chunk ``g`` (logical rows ``[g*N, (g+1)*N)``) lands at the start of
+    slot ``[g*L, (g+1)*L)`` with ``L = chunk_lanes(N)``; the rest of each
+    slot and the tail up to :func:`stored_k` are zero.  Zeros add nothing
+    to an integer psum, hash neutrally into noise seeds, and never leak
+    crosstalk into a chunk.  With ``N`` a multiple of 128 this is plain
+    zero padding.
+    """
+    n_chunk = tiling[0]
+    lanes = chunk_lanes(n_chunk)
+    axis %= a.ndim
+    g = stored_k(k, tiling) // lanes
+    pad = [(0, 0)] * a.ndim
+    pad[axis] = (0, g * n_chunk - k)
+    a = jnp.pad(a, pad)
+    if lanes != n_chunk:
+        shape = a.shape
+        a = a.reshape(shape[:axis] + (g, n_chunk) + shape[axis + 1 :])
+        pad = [(0, 0)] * a.ndim
+        pad[axis + 1] = (0, lanes - n_chunk)
+        a = jnp.pad(a, pad).reshape(shape[:axis] + (g * lanes,) + shape[axis + 1 :])
+    return a
+
+
+def logical_weight(wq: jax.Array, k: int, c: int, tiling) -> jax.Array:
+    """The logical ``(..., K, C)`` rows of a weight stored raw
+    (``tiling=None``) or in the kernel layout of ``tiling`` — the inverse
+    of :func:`to_chunk_slots`."""
+    wq = wq[..., :c]
+    if tiling is not None:
+        n_chunk, lanes = tiling[0], chunk_lanes(tiling[0])
+        lead, g = wq.shape[:-2], wq.shape[-2] // lanes
+        wq = wq.reshape(*lead, g, lanes, c)[..., :n_chunk, :]
+        wq = wq.reshape(*lead, g * n_chunk, c)
+    return wq[..., :k, :]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -358,7 +410,6 @@ class PhotonicEngine:
         prng_key: Optional[jax.Array] = None,
         logical_kc: Optional[Tuple[int, int]] = None,
         tiling: Optional[Tuple[int, int, int]] = None,
-        interpret: Optional[bool] = None,
         tile_r: int = 128,
         tile_c: int = 128,
         epilogue: Optional[EpilogueArgs] = None,
@@ -397,7 +448,7 @@ class PhotonicEngine:
                 shard=shard,
                 prng_key=prng_key,
                 logical_kc=logical_kc,
-                interpret=interpret,
+                tiling=tiling,
                 tile_r=tile_r,
                 tile_c=tile_c,
                 epilogue=epilogue,
@@ -446,8 +497,6 @@ class PhotonicEngine:
             return acc if epilogue is None else _finish(acc, epilogue)
 
         assert self.backend == "pallas", self.backend
-        if interpret is None:
-            interpret = _on_cpu()
         r = xq.shape[0]
         if tiling is not None:
             n_chunk, tile_k, tc = tiling  # prepacked layout is authoritative
@@ -456,17 +505,20 @@ class PhotonicEngine:
             # Honour the caller's tile_c bound exactly as the legacy entry
             # point did (values above 128 are legal).
             tc = min(tile_c, _round_up(c, 128))
+            cp = _round_up(c, tc)
+            wq = to_chunk_slots(wq[:k, :c], k, (n_chunk, tile_k, tc), axis=0)
+            wq = jnp.pad(wq, ((0, 0), (0, cp - c)))
         tr = min(tile_r, _round_up(r, 8))
-        rp, kp, cp = _round_up(r, tr), _round_up(k, tile_k), _round_up(c, tc)
-        xp = jnp.pad(xq, ((0, rp - r), (0, kp - k)))
-        if wq.shape != (kp, cp):
-            wq = jnp.pad(wq[:k, :c], ((0, kp - k), (0, cp - c)))
+        rp, cp = _round_up(r, tr), wq.shape[1]
+        xp = to_chunk_slots(
+            jnp.pad(xq[:, :k], ((0, rp - r), (0, 0))), k, (n_chunk, tile_k, tc), 1
+        )
         ch = channel
         seed_arr = None if seed is None else seed.astype(jnp.int32).reshape(1)
         stages = dict(
             slice_bits=cfg.bits,
             num_slices=cfg.num_slices,
-            n_chunk=n_chunk,
+            chunk_lanes=chunk_lanes(n_chunk),
             adc_bits=adc_bits,
             noise_sigma=ch.detector_sigma_lsb if analog else 0.0,
             filter_alpha=ch.filter_alpha if analog else 0.0,
@@ -476,7 +528,7 @@ class PhotonicEngine:
             tile_r=tr,
             tile_c=tc,
             tile_k=tile_k,
-            interpret=interpret,
+            interpret=pallas_interpret(),
         )
         if epilogue is None:
             out = photonic_gemm_pallas(xp, wq, seed_arr, **stages)
@@ -508,7 +560,7 @@ class PhotonicEngine:
         shard,
         prng_key,
         logical_kc,
-        interpret,
+        tiling,
         tile_r,
         tile_c,
         epilogue: Optional[EpilogueArgs],
@@ -541,7 +593,7 @@ class PhotonicEngine:
         p = mode.plane_bits
         planes = mode.num_planes(cfg.operand_bits)
         x_pl = bit_slices(xq, p, planes)  # (P, R, K) int8
-        w_pl = bit_slices(wq[:k, :c], p, planes)  # (P, K, C) int8
+        w_pl = bit_slices(logical_weight(wq, k, c, tiling), p, planes)  # (P, K, C)
         acc = jnp.zeros((xq.shape[0], c), jnp.int32)
         for si in range(planes):
             for ti in range(planes):
@@ -553,7 +605,6 @@ class PhotonicEngine:
                     shard=shard,
                     plane=si * planes + ti,
                     prng_key=prng_key,
-                    interpret=interpret,
                     tile_r=tile_r,
                     tile_c=tile_c,
                 )
@@ -763,8 +814,7 @@ def _float_bwd(meta, res, g):
     x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
     wf = w.astype(jnp.float32)
     g2, db = _epilogue_bwd(spec, g2, x2, wf, bias)
-    dx = (g2 @ wf.T).reshape(x.shape).astype(x.dtype)
-    dw = (x2.T @ g2).astype(w.dtype)
+    dx, dw = dense_vjp(x, w, g2.reshape(*x.shape[:-1], g2.shape[-1]))
     return dx, dw, db, key_zero_cotangent(fold), key_zero_cotangent(prng_key)
 
 
@@ -799,13 +849,14 @@ def _packed_fwd(meta, x, wq, w_scale, bias, fold, prng_key):
 
 
 def _packed_bwd(meta, res, g):
-    _, site, k, c, _, spec = meta
+    _, site, k, c, tiling, spec = meta
     x, wq, w_scale, bias, fold, prng_key = res
-    wf = wq[:k, :c].astype(jnp.float32) * w_scale.astype(jnp.float32)[None, :]
+    wf = logical_weight(wq, k, c, tiling).astype(jnp.float32)
+    wf = wf * w_scale.astype(jnp.float32)[None, :]
     g2 = g.reshape(-1, g.shape[-1]).astype(jnp.float32)
     x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
     g2, db = _epilogue_bwd(spec, g2, x2, wf, bias)
-    dx = (g2 @ wf.T).reshape(x.shape).astype(x.dtype)
+    dx, _ = dense_vjp(x, wf, g2.reshape(*x.shape[:-1], g2.shape[-1]))
     # Prepacked weights are frozen serving state: int8 slices get the
     # mandatory float0 cotangent, the scale a plain zero.
     return (

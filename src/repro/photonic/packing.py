@@ -11,10 +11,12 @@ and replaces the float (or int8-stored) weight with a
 * per-column symmetric int8 quantization (bit-identical to the per-call
   ``quantize_symmetric(w, bits, axis=0)`` it replaces — contraction-axis
   reduction only, so stacked ``(layers, K, C)`` defs pack layerwise),
-* for the ``pallas`` backend the weight is stored tile-padded in the
-  kernel's ``(Kp, Cp)`` layout (:func:`repro.photonic.engine.pallas_tiling`
-  is activation-independent, which is what makes this legal), so decode
-  steps never pad or re-slice the weight again.
+* for the ``pallas`` backend the weight is stored in the kernel's
+  ``(Kp, Cp)`` layout — chunk slots along K
+  (:func:`repro.photonic.engine.to_chunk_slots`), columns tile-padded;
+  :func:`repro.photonic.engine.pallas_tiling` is activation-independent,
+  which is what makes this legal — so decode steps never pad or re-slice
+  the weight again.
 
 ``PackedDense`` is a registered pytree whose array leaves carry any
 leading stack dims — ``jax.lax.scan`` over a stacked layer tree slices
@@ -30,7 +32,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.dpu import quantize_symmetric
-from repro.photonic.engine import PhotonicEngine, pallas_tiling
+from repro.photonic.engine import (
+    PhotonicEngine,
+    logical_weight,
+    pallas_tiling,
+    to_chunk_slots,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,7 +75,8 @@ class PackedDense:
     """A prepacked dense weight: int8 slices + per-column dequant scale.
 
     ``wq``      — int8, ``(..., K, C)`` (raw) or ``(..., Kp, Cp)`` when
-                  ``tiling`` is set (Pallas tile-padded layout).  With
+                  ``tiling`` is set (the Pallas kernel's chunk-slot
+                  layout, see :func:`_kernel_layout`).  With
                   ``shards > 1`` the stored rows are the concatenation of
                   the per-shard banks: ``(..., shards * Kp_local, Cp)``,
                   each bank independently tile-padded for its *local*
@@ -114,18 +122,19 @@ class PackedDense:
         """Per-shard logical contraction length."""
         return self.k // self.shards
 
+    def logical_wq(self) -> jax.Array:
+        """The int8 weight in its logical ``(..., K, C)`` layout."""
+        wq, lead = self.wq, self.wq.shape[:-2]
+        wq = wq.reshape(*lead, self.shards, wq.shape[-2] // self.shards, -1)
+        wq = logical_weight(wq, self.k_local, self.c, self.tiling)
+        return wq.reshape(*lead, self.k, self.c)
+
     def dequant(self) -> jax.Array:
         """The float32 weight this pack represents (logical K x C)."""
-        wq = self.wq
-        if self.shards > 1:
-            lead = wq.shape[:-2]
-            kp_local = wq.shape[-2] // self.shards
-            wq = wq.reshape(*lead, self.shards, kp_local, wq.shape[-1])
-            wq = wq[..., : self.k_local, : self.c]
-            wq = wq.reshape(*lead, self.k, self.c)
-        else:
-            wq = wq[..., : self.k, : self.c]
-        return wq.astype(jnp.float32) * self.w_scale.astype(jnp.float32)[..., None, :]
+        return (
+            self.logical_wq().astype(jnp.float32)
+            * self.w_scale.astype(jnp.float32)[..., None, :]
+        )
 
     def __repr__(self):
         return (
@@ -160,6 +169,20 @@ def _is_dense_def(node: Any) -> bool:
         return False
     w = node["w"]
     return (not isinstance(w, dict) and hasattr(w, "shape") and len(w.shape) >= 2)
+
+
+def _kernel_layout(wq: jax.Array, k_local: int, tiling, shards: int = 1):
+    """Logical ``(..., shards * k_local, C)`` int8 rows -> the Pallas
+    kernel's stored layout: each shard's bank in chunk slots
+    (:func:`~repro.photonic.engine.to_chunk_slots`), columns padded to
+    whole ``tile_c`` blocks, banks concatenated along K."""
+    lead, c = wq.shape[:-2], wq.shape[-1]
+    tile_c = tiling[2]
+    wq = wq.reshape(*lead, shards, k_local, c)
+    wq = to_chunk_slots(wq, k_local, tiling, axis=-2)
+    pad = [(0, 0)] * (wq.ndim - 1) + [(0, -(-c // tile_c) * tile_c - c)]
+    wq = jnp.pad(wq, pad)
+    return wq.reshape(*lead, shards * wq.shape[-2], wq.shape[-1])
 
 
 def pack_dense(
@@ -199,17 +222,8 @@ def pack_dense(
     tiling = None
     if engine.backend == "pallas":
         tile_dpu = engine.dpu.shard_local(k_local) if shards > 1 else engine.dpu
-        n_chunk, tile_k, tile_c = pallas_tiling(tile_dpu, k_local, c)
-        kp = -(-k_local // tile_k) * tile_k
-        cp = -(-c // tile_c) * tile_c
-        lead = wq.shape[:-2]
-        if shards > 1:
-            wq = wq.reshape(*lead, shards, k_local, c)
-        pad = [(0, 0)] * (wq.ndim - 2) + [(0, kp - k_local), (0, cp - c)]
-        wq = jnp.pad(wq, pad)
-        if shards > 1:
-            wq = wq.reshape(*lead, shards * kp, cp)
-        tiling = (n_chunk, tile_k, tile_c)
+        tiling = pallas_tiling(tile_dpu, k_local, c)
+        wq = _kernel_layout(wq, k_local, tiling, shards)
     out = {"w": PackedDense(wq, scale, k, c, tiling, shards)}
     if "b" in params:
         out["b"] = params["b"]
@@ -260,21 +274,15 @@ def fuse_qkv_params(attn: dict, engine: PhotonicEngine) -> dict:
             raise ValueError(
                 f"fuse_qkv_params: mismatched K {[pk.k for pk in packs]}"
             )
-        # Slice each bank to its logical columns (drops per-site tile
-        # padding), concatenate, re-pad once for the fused width.
-        wq = jnp.concatenate(
-            [pk.wq[..., : pk.k, : pk.c] for pk in packs], axis=-1
-        )
+        # Back to each bank's logical layout (drops per-site tile padding),
+        # concatenate, re-lay out once for the fused width.
+        wq = jnp.concatenate([pk.logical_wq() for pk in packs], axis=-1)
         scale = jnp.concatenate([pk.w_scale for pk in packs], axis=-1)
         c = sum(pk.c for pk in packs)
         tiling = None
         if engine.backend == "pallas":
-            n_chunk, tile_k, tile_c = pallas_tiling(engine.dpu, k, c)
-            kp = -(-k // tile_k) * tile_k
-            cp = -(-c // tile_c) * tile_c
-            pad = [(0, 0)] * (wq.ndim - 2) + [(0, kp - k), (0, cp - c)]
-            wq = jnp.pad(wq, pad)
-            tiling = (n_chunk, tile_k, tile_c)
+            tiling = pallas_tiling(engine.dpu, k, c)
+            wq = _kernel_layout(wq, k, tiling)
         fused = {"w": PackedDense(wq, scale, k, c, tiling, 1)}
     elif all(scaled):
         # int8-stored layout: columns (and their dequant scales) just

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 
 from repro import compat
 from repro.compat import PartitionSpec as P
-from repro.core.dpu import quantize_symmetric
+from repro.core.dpu import dense_vjp, quantize_symmetric
 from repro.kernels.photonic_gemm.epilogue import apply_epilogue, as_epilogue
 from repro.noise.stages import key_zero_cotangent
 from repro.photonic.engine import PhotonicEngine, _epilogue_bwd
@@ -336,8 +336,7 @@ def _tp_float_bwd(meta, res, g):
     x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
     wf = w.astype(jnp.float32)
     g2, db = _epilogue_bwd(spec, g2, x2, wf, bias)
-    dx = (g2 @ wf.T).reshape(x.shape).astype(x.dtype)
-    dw = (x2.T @ g2).astype(w.dtype)
+    dx, dw = dense_vjp(x, w, g2.reshape(*x.shape[:-1], g2.shape[-1]))
     return dx, dw, db, key_zero_cotangent(fold), key_zero_cotangent(prng_key)
 
 
@@ -426,7 +425,7 @@ def _tp_packed_bwd(meta, res, g):
     g2 = g.reshape(-1, g.shape[-1]).astype(jnp.float32)
     x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
     g2, db = _epilogue_bwd(spec, g2, x2, wf, bias)
-    dx = (g2 @ wf.T).reshape(x.shape).astype(x.dtype)
+    dx, _ = dense_vjp(x, wf, g2.reshape(*x.shape[:-1], g2.shape[-1]))
     # Prepacked weights are frozen serving state: int8 banks get the
     # mandatory float0 cotangent, the scale a plain zero.
     return (

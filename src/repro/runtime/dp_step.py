@@ -72,7 +72,7 @@ def make_dp_train_step(
         if compress_grads:
             # int8-wire ring all-reduce: halves the only collective's bytes
             grads = ring_int8_allreduce(grads, axes)
-            grads = compat.tree_map(lambda g: (g / n_dev).astype(g.dtype), grads)
+            grads = jax.tree.map(lambda g: (g / n_dev).astype(g.dtype), grads)
         else:
             grads = jax.lax.pmean(grads, axes)
         loss = jax.lax.pmean(loss, axes)

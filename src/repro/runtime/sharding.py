@@ -18,7 +18,6 @@ from typing import Any, Dict, Optional, Sequence, Tuple, Union
 import jax
 import numpy as np
 
-from repro import compat
 from repro.compat import Mesh, NamedSharding, PartitionSpec
 
 AxisName = Union[str, Tuple[str, ...]]
@@ -49,7 +48,7 @@ def zero1_axes(param_axes: Any, param_shapes: Any, divisor: int) -> Any:
     def is_axes_leaf(x):
         return isinstance(x, tuple) and all(isinstance(e, (str, type(None))) for e in x)
 
-    flat_shapes, treedef = compat.tree_flatten(param_shapes)
+    flat_shapes, treedef = jax.tree.flatten(param_shapes)
     flat_axes = treedef.flatten_up_to(param_axes)
 
     out = []
@@ -206,4 +205,4 @@ def tree_shardings(
 
     # tree_map flattens up to `shapes`' leaves, so the tuple-of-names leaves
     # of `axes` pass through intact.
-    return compat.tree_map(leaf, shapes, axes)
+    return jax.tree.map(leaf, shapes, axes)

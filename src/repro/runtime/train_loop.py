@@ -27,7 +27,6 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.checkpoint import ckpt
 from repro.data.pipeline import DataConfig, SyntheticTokens
 from repro.models.common import axes_tree, init_tree
@@ -67,14 +66,14 @@ def build_train_step(
             def micro(carry, mb):
                 acc, _ = carry
                 l, g = jax.value_and_grad(loss_fn)(params, mb)
-                return (compat.tree_map(jnp.add, acc, g), l), None
+                return (jax.tree.map(jnp.add, acc, g), l), None
 
-            mbs = compat.tree_map(
+            mbs = jax.tree.map(
                 lambda x: x.reshape(microbatches, -1, *x.shape[1:]), batch
             )
-            zero = compat.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+            zero = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
             (gsum, loss), _ = jax.lax.scan(micro, (zero, jnp.zeros(())), mbs)
-            grads = compat.tree_map(lambda g: g / microbatches, gsum)
+            grads = jax.tree.map(lambda g: g / microbatches, gsum)
         params, opt_state, metrics = adamw.update(opt_cfg, params, grads, opt_state)
         metrics["loss"] = loss
         return params, opt_state, metrics

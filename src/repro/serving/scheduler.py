@@ -248,6 +248,11 @@ class Scheduler:
         if done:
             self.allocator.free(s.blocks)
             self._table[slot, :] = kvc.NULL_BLOCK
+            # An idle row still rides the batched decode (and its
+            # activations feed the per-tensor quantization scale), so it
+            # must not carry the previous occupant's token or position.
+            self._tokens[slot, 0] = 0
+            self._pos[slot] = 0
             self.slots[slot] = None
             if slot in self._prefill_fifo:
                 self._prefill_fifo.remove(slot)

@@ -78,20 +78,19 @@ class TestRPR001:
         src = "from jax.sharding import AxisType\n"
         assert rule_ids(check_source(src, "src/repro/foo.py")) == ["RPR001"]
 
-    def test_check_rep_kwarg_fires(self):
-        src = (
-            "from repro import compat\n\n"
-            "f = compat.shard_map(g, mesh=m, in_specs=s, out_specs=o, "
-            "check_rep=False)\n"
-        )
-        f = check_source(src, "src/repro/foo.py")
-        assert rule_ids(f) == ["RPR001"]
-
-    def test_cost_analysis_method_fires_but_wrapper_is_clean(self):
-        bad = "ca = compiled.cost_analysis()\n"
-        assert rule_ids(check_source(bad, "src/repro/foo.py")) == ["RPR001"]
-        good = "from repro import compat\n\nca = compat.cost_analysis(compiled)\n"
-        assert check_source(good, "src/repro/foo.py") == []
+    @pytest.mark.parametrize(
+        "src",
+        [
+            "ca = compiled.cost_analysis()\n",
+            "from jax.experimental.pallas import tpu as pltpu\n\n"
+            "p = pltpu.CompilerParams(dimension_semantics=('parallel',))\n",
+            "import jax\n\nys = jax.tree.map(f, xs)\n",
+        ],
+        ids=["cost_analysis", "pallas_compiler_params", "jax_tree"],
+    )
+    def test_stable_jax_surface_is_clean(self, src):
+        # Symbols that are stable on the installed JAX are spelled directly.
+        assert check_source(src, "src/repro/foo.py") == []
 
     def test_compat_module_and_its_tests_exempt(self):
         src = "from jax.experimental.shard_map import shard_map\n"

@@ -1,10 +1,5 @@
-"""Regression tests for the JAX version-compat layer (repro.compat).
-
-These run against whichever JAX is installed — the whole point of the shim
-is that the same call sites work on 0.4.x (experimental shard_map, pair-form
-AbstractMesh, no AxisType) and on 0.5+/0.6.x (jax.shard_map, check_vma,
-axis_types meshes).
-"""
+"""Tests for the mesh / sharding surface in repro.compat (the JAX symbols
+that moved in past releases, wrapped in one file)."""
 
 import jax
 import jax.numpy as jnp
@@ -79,35 +74,16 @@ class TestAbstractMesh:
         assert spec == P(("data",), "model")
 
 
-class TestAxisType:
-    def test_axis_type_has_auto(self):
-        # Real enum on 0.5+, stub enum on 0.4.x — either way Auto must exist
-        # because make_mesh defaults every axis to it.
-        assert hasattr(compat.AxisType, "Auto")
-
+class TestMakeMesh:
     def test_make_mesh_singleton(self):
         mesh = compat.make_mesh((1, 1), ("data", "model"))
         assert tuple(mesh.axis_names) == ("data", "model")
         assert mesh.devices.size == 1
 
+    def test_axes_default_to_auto(self):
+        mesh = compat.make_mesh((1, 1), ("data", "model"))
+        assert tuple(mesh.axis_types) == (compat.AxisType.Auto,) * 2
+
     def test_make_mesh_explicit_axis_types(self):
-        # Passing axis_types must not crash on any version (it is dropped
-        # where unsupported).
         mesh = compat.make_mesh((1,), ("data",), axis_types=(compat.AxisType.Auto,))
         assert mesh.shape["data"] == 1
-
-
-class TestTreeAliases:
-    def test_map_flatten_roundtrip(self):
-        tree = {"a": jnp.arange(3), "b": (jnp.zeros(2), jnp.ones(1))}
-        doubled = compat.tree_map(lambda x: x * 2, tree)
-        np.testing.assert_array_equal(np.asarray(doubled["a"]), [0, 2, 4])
-        leaves, treedef = compat.tree_flatten(tree)
-        assert len(leaves) == 3
-        rebuilt = compat.tree_unflatten(treedef, leaves)
-        assert compat.tree_structure(rebuilt) == treedef
-        assert len(compat.tree_leaves(tree)) == 3
-
-    def test_version_tuple(self):
-        assert isinstance(compat.JAX_VERSION, tuple)
-        assert compat.JAX_VERSION >= (0, 4)

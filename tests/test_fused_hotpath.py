@@ -15,6 +15,7 @@ The load-bearing contracts:
 
 import dataclasses
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -312,16 +313,38 @@ class TestLaunchProfile:
         assert opts["--xla_foo"] == "1"
         assert opts["--xla_bar"] == "0"
 
-    def test_apply_returns_describe(self, tmp_path, monkeypatch):
+    @pytest.fixture()
+    def restore_cache_config(self):
+        import jax
+
+        keys = ("jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs")
+        saved = {k: getattr(jax.config, k) for k in keys}
+        yield
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+    @pytest.mark.parametrize("env_set", [True, False], ids=["env", "checkout"])
+    def test_apply_returns_describe(
+        self, tmp_path, monkeypatch, restore_cache_config, env_set
+    ):
         monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
         monkeypatch.delenv("TF_CPP_MIN_LOG_LEVEL", raising=False)
-        desc = profile.apply(cache_dir=str(tmp_path / "cache"))
+        if env_set:
+            want = str(tmp_path / "cache")
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+        else:
+            # Unset: one fixed directory inside the checkout, never a
+            # temporary or per-process one.
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        desc = profile.apply()
         # user-set option preserved, curated defaults appended
         assert "--xla_force_host_platform_device_count=4" in desc["xla_flags"]
         assert "--xla_cpu_parallel_codegen_split_count" in desc["xla_flags"]
-        assert desc["jax_compilation_cache_dir"] == str(tmp_path / "cache")
-        assert os.path.isdir(str(tmp_path / "cache"))
+        assert desc["jax_compilation_cache_dir"] == want
+        assert os.path.isdir(want)
         assert desc["tf_cpp_min_log_level"] == "3"
+        assert profile.child_env({})["JAX_COMPILATION_CACHE_DIR"] == want
 
     def test_child_env_injects_cache_and_tcmalloc(self, monkeypatch):
         monkeypatch.delenv("LD_PRELOAD", raising=False)

@@ -17,7 +17,13 @@ _SHIM_PATH = Path(__file__).with_name("_hypothesis_compat.py")
 
 @pytest.fixture()
 def shim():
-    """The shim module imported with hypothesis guaranteed-absent."""
+    """The shim module imported with hypothesis guaranteed-absent.
+
+    hypothesis is masked only while the shim executes: an installed
+    hypothesis also registers a pytest plugin whose hooks import it during
+    each test, so the mask must be lifted before the test body runs.  The
+    shim has picked its fallback branch by then.
+    """
     saved = {
         k: sys.modules.get(k) for k in list(sys.modules) if k.startswith("hypothesis")
     }
@@ -31,10 +37,12 @@ def shim():
         )
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        yield mod
     finally:
         del sys.modules["hypothesis"]
         sys.modules.update(saved)
+    try:
+        yield mod
+    finally:
         sys.modules.pop("_hypothesis_compat_forced_fallback", None)
 
 

@@ -86,6 +86,26 @@ def test_prepack_ideal_engine_equals_exact_int_gemm():
         np.testing.assert_allclose(np.asarray(y), gold, rtol=0, atol=0)
 
 
+@pytest.mark.parametrize("n", [17, 42, 128])
+def test_chunk_slot_layout_roundtrips_and_dequantizes(n):
+    """Analog tilings store each DPE chunk in its own 128-lane slot; the
+    stored pack reads back as the logical weight, bit for bit."""
+    from repro.core.dpu import quantize_symmetric
+    from repro.photonic.engine import chunk_lanes
+
+    eng = engine_for(_det_dpu(n), "pallas")
+    packed = pack_dense({"w": W}, eng)["w"]
+    n_chunk, tile_k, _ = packed.tiling
+    assert n_chunk == n and tile_k % 128 == 0 and chunk_lanes(n) % 128 == 0
+    slots = packed.wq.reshape(-1, chunk_lanes(n), packed.wq.shape[1])
+    assert not np.asarray(slots[:, n:, :]).any()  # slot padding is zero
+    wq, sw = quantize_symmetric(W, 8, axis=0)
+    np.testing.assert_array_equal(np.asarray(packed.logical_wq()), np.asarray(wq))
+    np.testing.assert_array_equal(
+        np.asarray(packed.dequant()), np.asarray(wq, np.float32) * np.asarray(sw)
+    )
+
+
 def test_prepack_pallas_layout_is_tile_padded():
     eng = engine_for(DPUConfig(organization="SMWA", bits=4, dpe_size=21), "pallas")
     packed = pack_dense({"w": W}, eng)["w"]
