@@ -400,6 +400,7 @@ def photonic_gemm_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="photonic_gemm_pallas",
     )(*args)
 
 
@@ -530,4 +531,5 @@ def photonic_gemm_fused_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="photonic_gemm_fused_pallas",
     )(*args)

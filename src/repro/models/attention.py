@@ -16,6 +16,11 @@ import jax.numpy as jnp
 from repro.models import common as cm
 from repro.models.common import P, ModelConfig, apply_rope, dense, qdense_def
 
+# Device-side name (``named_scope``) of the paged attention core: the KV
+# scatter and gather, the KV-head repeat, scores, softmax and P·V, up to
+# ``wo``.
+ATTN_CORE = "attn.core"
+
 
 # ---------------------------------------------------------------------------
 # Param definitions
@@ -369,20 +374,21 @@ def gqa_prefill_chunk(
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
 
-    blocks, offsets = kvc.chunk_dest(block_table, t0, tc, block_size)
-    kv_pool = kvc.scatter_kv(kv_pool, blocks, offsets, _kv_rows(k, v, cfg, 0))
+    with jax.named_scope(ATTN_CORE):
+        blocks, offsets = kvc.chunk_dest(block_table, t0, tc, block_size)
+        kv_pool = kvc.scatter_kv(kv_pool, blocks, offsets, _kv_rows(k, v, cfg, 0))
 
-    if t_full == tc:
-        kf, vf = k, v  # single chunk covers the prompt: legacy math exactly
-        q_offset = 0
-    else:
-        kf, vf = _kv_view(kvc.gather_kv(kv_pool, block_table[None], t_full), cfg)
-        q_offset = t0
-    out = chunked_attention(
-        q, kf, vf, causal=True, q_offset=q_offset,
-        chunk=cfg.attn_chunk, unroll=cfg.unroll_scans,
-        acc_dtype=jnp.float32 if cfg.attn_f32 else jnp.bfloat16,
-    )
+        if t_full == tc:
+            kf, vf = k, v  # single chunk covers the prompt: legacy math exactly
+            q_offset = 0
+        else:
+            kf, vf = _kv_view(kvc.gather_kv(kv_pool, block_table[None], t_full), cfg)
+            q_offset = t0
+        out = chunked_attention(
+            q, kf, vf, causal=True, q_offset=q_offset,
+            chunk=cfg.attn_chunk, unroll=cfg.unroll_scans,
+            acc_dtype=jnp.float32 if cfg.attn_f32 else jnp.bfloat16,
+        )
     out = dense(
         params["wo"], out.reshape(1, tc, -1), cfg, site="attn.wo", layer=layer
     )
@@ -416,17 +422,18 @@ def gqa_decode_paged(
     q = apply_rope(q, pos[:, None], cfg.rope_theta)
     k1 = apply_rope(k1, pos[:, None], cfg.rope_theta)
 
-    kv_pool = kvc.scatter_kv(kv_pool, blocks, offsets, _kv_rows(k1, v1, cfg, 1))
-    kf, vf = _kv_view(kvc.gather_kv(kv_pool, block_table, gather_len), cfg)
+    with jax.named_scope(ATTN_CORE):
+        kv_pool = kvc.scatter_kv(kv_pool, blocks, offsets, _kv_rows(k1, v1, cfg, 1))
+        kf, vf = _kv_view(kvc.gather_kv(kv_pool, block_table, gather_len), cfg)
 
-    kf = _repeat_kv(kf, h // kv)
-    vf = _repeat_kv(vf, h // kv)
-    qf = q.astype(jnp.float32) * (cfg.hd ** -0.5)
-    s = jnp.einsum("bqhd,bshd->bhqs", qf, kf, preferred_element_type=jnp.float32)
-    kv_pos = jnp.arange(gather_len)
-    s = jnp.where((kv_pos[None, :] <= pos[:, None])[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqs,bshd->bqhd", p, vf, preferred_element_type=jnp.float32)
+        kf = _repeat_kv(kf, h // kv)
+        vf = _repeat_kv(vf, h // kv)
+        qf = q.astype(jnp.float32) * (cfg.hd ** -0.5)
+        s = jnp.einsum("bqhd,bshd->bhqs", qf, kf, preferred_element_type=jnp.float32)
+        kv_pos = jnp.arange(gather_len)
+        s = jnp.where((kv_pos[None, :] <= pos[:, None])[:, None, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bhqs,bshd->bqhd", p, vf, preferred_element_type=jnp.float32)
     out = dense(
         params["wo"], out.reshape(b, 1, -1).astype(x.dtype), cfg,
         site="attn.wo", layer=layer,

@@ -10,6 +10,7 @@ never drift apart.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Any, Dict, Optional, Tuple
@@ -374,30 +375,32 @@ def dense(
     bias = params.get("b")
     ep = Epilogue(EpilogueSpec(bias=bias is not None, activation=activation), bias)
     eng = engine_from_model_config(cfg)
-    y = tp.maybe_tp_matmul(
-        eng,
-        params,
-        x,
-        cfg,
-        site=site,
-        fold=layer,
-        prng_key=prng_key,
-        epilogue=ep,
-        slicing=slicing,
-    )
-    if y is None:
-        y = _single_device_matmul(
+    # The site names the GEMM's ops on the device too (``op_name`` metadata).
+    with jax.named_scope(site) if site else contextlib.nullcontext():
+        y = tp.maybe_tp_matmul(
             eng,
             params,
-            w,
             x,
             cfg,
             site=site,
-            layer=layer,
+            fold=layer,
             prng_key=prng_key,
             epilogue=ep,
             slicing=slicing,
         )
+        if y is None:
+            y = _single_device_matmul(
+                eng,
+                params,
+                w,
+                x,
+                cfg,
+                site=site,
+                layer=layer,
+                prng_key=prng_key,
+                epilogue=ep,
+                slicing=slicing,
+            )
     return y
 
 
@@ -499,9 +502,10 @@ def embed(params, ids: jax.Array, cfg: ModelConfig) -> jax.Array:
 def unembed(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Logits head (optionally tied to the embedding table)."""
     w = params["table"] if "table" in params else params["w"]
-    if "table" in params:
-        return x @ w.astype(x.dtype).T
-    return x @ w.astype(x.dtype)
+    with jax.named_scope("lm_head"):
+        if "table" in params:
+            return x @ w.astype(x.dtype).T
+        return x @ w.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

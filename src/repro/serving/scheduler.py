@@ -24,6 +24,10 @@ Correctness contracts (tested in ``tests/test_serving.py``):
   replay (or be influenced by) a previous occupant's sample stream;
 * **no stale KV** — blocks zero at (re)allocation; see ``kv_cache``.
 
+Every step records host spans with their counts (``repro.serving.spans``):
+on the profiler's clock when one runs, and in ``spans.LOG`` always; the
+counts also add up in ``Scheduler.stats``.
+
 Tensor parallel: pass the PR-4 ``mesh``/``tp_axis`` and every model call
 runs under ``repro.photonic.sharded.tensor_parallel`` with shard-local
 prepacked banks, exactly like the legacy engine.
@@ -42,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serving import kv_cache as kvc
+from repro.serving import spans
 
 
 @dataclasses.dataclass
@@ -55,6 +60,7 @@ class Request:
     done: bool = False
     # latency bookkeeping, in units of the scheduler's clock
     t_submit: Optional[float] = None
+    t_admit: Optional[float] = None  # queue wait: t_admit - t_submit
     t_first: Optional[float] = None
     t_done: Optional[float] = None
     # per-emitted-token logits rows, only with ServingConfig.record_logits
@@ -152,6 +158,7 @@ class Scheduler:
             else 1
         )
         self._clock = clock
+        self._spans = spans.Recorder(clock)
 
         self.photonic, self.params = prepack_serving_params(
             arch, model_cfg, params, mesh=mesh, tp_axis=tp_axis
@@ -179,16 +186,20 @@ class Scheduler:
         self.slots: List[Optional[_Slot]] = [None] * b
         self._prefill_fifo: List[int] = []  # slot ids, admission order
         self.queue: collections.deque = collections.deque()
+        # Cumulative counts of the spans (``prefills``: requests admitted).
         self.stats = {
-            "prefills": 0, "prefill_chunks": 0, "decode_steps": 0, "completed": 0
+            "prefills": 0, "blocks_zeroed": 0, "prefill_chunks": 0,
+            "prefill_tokens": 0, "decode_steps": 0, "decode_rows": 0,
+            "kv_rows_attended": 0, "kv_rows_gathered": 0, "completed": 0,
         }
 
         self._base_key = jax.random.PRNGKey(cfg.seed)
+        self._gather_len = cfg.max_seq  # KV rows each decode row gathers
 
         def decode_fn(p, tok, pool, table, pos, active):
             return lm.lm_decode_paged(
                 p, tok, pool, table, pos, active, self._trash, model_cfg,
-                gather_len=cfg.max_seq, block_size=cfg.block_size,
+                gather_len=self._gather_len, block_size=cfg.block_size,
             )
 
         def prefill_fn(p, toks, pool, table_row, t0, t_full, with_logits):
@@ -283,26 +294,33 @@ class Scheduler:
         """Fill free slots FCFS.  All-or-nothing block reservation for
         prompt + max_new_tokens: if the pool cannot cover the queue head's
         worst case, admission waits (no preemption path exists)."""
-        for slot in range(self.cfg.batch_size):
-            if not self.queue:
-                return
-            if self.slots[slot] is not None:
-                continue
-            req = self.queue[0]
-            need = self.allocator.blocks_needed(len(req.prompt) + req.max_new_tokens)
-            blocks = self.allocator.alloc(need)
-            if blocks is None:
-                return
-            self.queue.popleft()
-            # Stale-KV admission contract: recycled blocks zero here, before
-            # any table entry can reach them.
-            self.kv_pool = kvc.zero_blocks(self.kv_pool, blocks)
-            self._table[slot, :] = kvc.NULL_BLOCK
-            self._table[slot, : len(blocks)] = blocks
-            self._pos[slot] = 0
-            self.slots[slot] = _Slot(req, blocks)
-            self._prefill_fifo.append(slot)
-            self.stats["prefills"] += 1
+        admitted = zeroed = 0
+        with self._spans.span("serve.admit") as add:
+            for slot in range(self.cfg.batch_size):
+                if not self.queue:
+                    break
+                if self.slots[slot] is not None:
+                    continue
+                req = self.queue[0]
+                total = len(req.prompt) + req.max_new_tokens
+                blocks = self.allocator.alloc(self.allocator.blocks_needed(total))
+                if blocks is None:
+                    break
+                self.queue.popleft()
+                req.t_admit = self._clock()
+                # Stale-KV admission contract: recycled blocks zero here,
+                # before any table entry can reach them.
+                self.kv_pool = kvc.zero_blocks(self.kv_pool, blocks)
+                self._table[slot, :] = kvc.NULL_BLOCK
+                self._table[slot, : len(blocks)] = blocks
+                self._pos[slot] = 0
+                self.slots[slot] = _Slot(req, blocks)
+                self._prefill_fifo.append(slot)
+                admitted += 1
+                zeroed += len(blocks)
+            add(admitted=admitted, blocks_zeroed=zeroed)
+        self.stats["prefills"] += admitted
+        self.stats["blocks_zeroed"] += zeroed
 
     def _prefill_phase(self) -> None:
         budget = self.cfg.chunk_tokens
@@ -314,7 +332,11 @@ class Scheduler:
             tc = min(budget, t_full - s.prefill_done)
             toks = jnp.asarray(prompt[s.prefill_done : s.prefill_done + tc][None, :])
             final = s.prefill_done + tc == t_full
-            with self._tp_scope():
+            uid = s.req.uid
+            with self._spans.span(
+                "serve.prefill", uid=uid, t0=s.prefill_done, tc=tc, t_full=t_full,
+                final=int(final),
+            ), self._tp_scope():
                 logits, self.kv_pool = self._prefill(
                     self.params, toks, self.kv_pool,
                     jnp.asarray(self._table[slot]),
@@ -323,16 +345,19 @@ class Scheduler:
             budget -= tc
             s.prefill_done += tc
             self.stats["prefill_chunks"] += 1
+            self.stats["prefill_tokens"] += tc
             if final:
                 self._prefill_fifo.pop(0)
                 row = logits[:, -1, : self.model_cfg.vocab_size]
-                tok = int(
-                    np.asarray(self._pick(row, [s.req.uid], [len(s.req.output)]))[0]
-                )
-                self._pos[slot] = t_full
-                self._tokens[slot, 0] = tok
-                s.decoding = True
-                self._emit(slot, tok, logits_row=row[0])
+                with self._spans.span("serve.wait", uid=uid):
+                    tok = int(
+                        np.asarray(self._pick(row, [uid], [len(s.req.output)]))[0]
+                    )
+                with self._spans.span("serve.emit", uid=uid, tokens=1):
+                    self._pos[slot] = t_full
+                    self._tokens[slot, 0] = tok
+                    s.decoding = True
+                    self._emit(slot, tok, logits_row=row[0])
 
     def _decode_phase(self) -> None:
         decoding = [
@@ -343,28 +368,41 @@ class Scheduler:
         b = self.cfg.batch_size
         active = np.zeros((b,), bool)
         active[decoding] = True
-        with self._tp_scope():
+        # A live row attends its pos + 1 cached rows (``kv_pos <= pos``) of
+        # the ``gather_len`` rows the program gathers for every slot.
+        counts = dict(
+            rows=len(decoding),
+            kv_rows_attended=int(self._pos[decoding].sum()) + len(decoding),
+            kv_rows_gathered=b * self._gather_len,
+        )
+        with self._spans.span("serve.decode", **counts), self._tp_scope():
             logits, self.kv_pool = self._decode(
                 self.params, jnp.asarray(self._tokens), self.kv_pool,
                 jnp.asarray(self._table), jnp.asarray(self._pos),
                 jnp.asarray(active),
             )
         self.stats["decode_steps"] += 1
+        self.stats["decode_rows"] += counts["rows"]
+        self.stats["kv_rows_attended"] += counts["kv_rows_attended"]
+        self.stats["kv_rows_gathered"] += counts["kv_rows_gathered"]
         rows = logits[:, -1, : self.model_cfg.vocab_size]
         uids = [self.slots[i].req.uid if active[i] else 0 for i in range(b)]
         ns = [len(self.slots[i].req.output) if active[i] else 0 for i in range(b)]
-        toks = np.asarray(self._pick(rows, uids, ns))
-        for i in decoding:
-            tok = int(toks[i])
-            self._pos[i] += 1
-            self._tokens[i, 0] = tok
-            self._emit(i, tok, logits_row=rows[i])
+        with self._spans.span("serve.wait"):
+            toks = np.asarray(self._pick(rows, uids, ns))
+        with self._spans.span("serve.emit", tokens=len(decoding)):
+            for i in decoding:
+                tok = int(toks[i])
+                self._pos[i] += 1
+                self._tokens[i, 0] = tok
+                self._emit(i, tok, logits_row=rows[i])
 
     # -- one engine iteration ------------------------------------------------
     def step(self) -> None:
-        self._admit()
-        self._prefill_phase()
-        self._decode_phase()
+        with self._spans.span("serve.step"):
+            self._admit()
+            self._prefill_phase()
+            self._decode_phase()
 
     @property
     def pending(self) -> bool:

@@ -15,6 +15,7 @@ and every pytest worker imports this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,7 +126,7 @@ def test_photonic_gemm_compiles_for_v5e(one_chip, org, n, k, c, r, entry, xdtype
     w = sds((kp, cp), jnp.int8)
     seed = sds((1,), jnp.int32) if noisy else None
     if entry == "int":
-        _compile(
+        compiled = _compile(
             lambda x, w, seed: photonic_gemm_pallas(
                 x, w, seed, tile_r=tile_r, **stages
             ),
@@ -134,7 +135,7 @@ def test_photonic_gemm_compiles_for_v5e(one_chip, org, n, k, c, r, entry, xdtype
             seed,
         )
     else:
-        _compile(
+        compiled = _compile(
             lambda x, w, sx, ws, b, seed: photonic_gemm_fused_pallas(
                 x, w, sx, ws, b, seed, tile_r=tile_r, activation="silu", **stages
             ),
@@ -145,6 +146,10 @@ def test_photonic_gemm_compiles_for_v5e(one_chip, org, n, k, c, r, entry, xdtype
             sds((cp,), jnp.float32),
             seed,
         )
+    # The device shows the kernel under this instruction name, which the
+    # benchmark's configuration matches (``device_names.kernel``).
+    name = "photonic_gemm_pallas" if entry == "int" else "photonic_gemm_fused_pallas"
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call", compiled.as_text())
 
 
 def test_flash_attention_compiles_for_v5e(one_chip):
