@@ -17,8 +17,7 @@ from repro.models import common as cm
 from repro.models.common import P, ModelConfig, apply_rope, dense, qdense_def
 
 # Device-side name (``named_scope``) of the paged attention core: the KV
-# scatter and gather, the KV-head repeat, scores, softmax and P·V, up to
-# ``wo``.
+# scatter and gather, scores, softmax and P·V, up to ``wo``.
 ATTN_CORE = "attn.core"
 
 
@@ -246,6 +245,33 @@ def _quantize_kv(x):
     return q, scale
 
 
+def _gqa_decode_core(q, kf, vf, valid) -> jax.Array:
+    """One query token per row against its float32 KV slab, each group of
+    ``R = H // KV`` query heads contracted with its own KV head.
+
+    ``q`` (B, 1, H, hd); ``kf``/``vf`` (B, S, KV, hd) float32; ``valid``
+    (B or 1, S) bool.  Query head ``h`` reads KV head ``h // R``, the head
+    order ``jnp.repeat(k, R, axis=2)`` gives, but K/V are never widened to
+    H heads: the slab is read once per group, not once per query head.
+    Both contractions run at ``Precision.HIGHEST``: with R rows per group
+    a DEFAULT-precision float32 dot may take a single bf16 MXU pass.
+    Returns (B, 1, H, hd) float32.
+    """
+    b, _, h, hd = q.shape
+    kvh = kf.shape[2]
+    hi = jax.lax.Precision.HIGHEST
+    qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(b, kvh, h // kvh, hd)
+    s = jnp.einsum(
+        "bkrd,bskd->bkrs", qg, kf, precision=hi, preferred_element_type=jnp.float32
+    )
+    s = jnp.where(valid[:, None, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum(
+        "bkrs,bskd->bkrd", p, vf, precision=hi, preferred_element_type=jnp.float32
+    )
+    return out.reshape(b, 1, h, vf.shape[-1])
+
+
 def gqa_decode(
     params,
     x: jax.Array,  # (B, 1, D)
@@ -255,7 +281,6 @@ def gqa_decode(
     *,
     layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    h, kv = cfg.n_q_heads, cfg.num_kv_heads
     b = x.shape[0]
     q, k1, v1 = _qkv_proj(params, x, cfg, layer=layer)
     posv = pos[None] if pos.ndim == 0 else pos
@@ -284,15 +309,8 @@ def gqa_decode(
     ck = cm.with_logical(ck, ("batch", "kv_seq", "kv_heads", None))
     cv = cm.with_logical(cv, ("batch", "kv_seq", "kv_heads", None))
 
-    s_max = ck.shape[1]
-    kf = _repeat_kv(kf, h // kv)
-    vf = _repeat_kv(vf, h // kv)
-    qf = q.astype(jnp.float32) * (cfg.hd ** -0.5)
-    s = jnp.einsum("bqhd,bshd->bhqs", qf, kf, preferred_element_type=jnp.float32)
-    kv_pos = jnp.arange(s_max)
-    s = jnp.where((kv_pos <= pos)[None, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqs,bshd->bqhd", p, vf, preferred_element_type=jnp.float32)
+    kv_pos = jnp.arange(ck.shape[1])
+    out = _gqa_decode_core(q, kf, vf, (kv_pos <= pos)[None, :])
     out = dense(
         params["wo"], out.reshape(b, 1, -1).astype(x.dtype), cfg,
         site="attn.wo", layer=layer,
@@ -411,12 +429,12 @@ def gqa_decode_paged(
     """``gqa_decode`` generalized to per-request positions over the paged
     pool: scatter the new token's K/V through the block table, gather a
     contiguous ``(B, gather_len)`` view, and attend under a per-row causal
-    mask ``kv_pos <= pos[b]``.  With uniform ``pos`` this is bitwise the
-    legacy decode (same shapes, same masked softmax, same int8 round-trip).
+    mask ``kv_pos <= pos[b]``.  The core is :func:`_gqa_decode_core`, the
+    one the legacy ``gqa_decode`` calls too, so with uniform ``pos`` both
+    compute the same thing (same masked softmax, same int8 round-trip).
     """
     from repro.serving import kv_cache as kvc
 
-    h, kv = cfg.n_q_heads, cfg.num_kv_heads
     b = x.shape[0]
     q, k1, v1 = _qkv_proj(params, x, cfg, layer=layer)
     q = apply_rope(q, pos[:, None], cfg.rope_theta)
@@ -425,15 +443,9 @@ def gqa_decode_paged(
     with jax.named_scope(ATTN_CORE):
         kv_pool = kvc.scatter_kv(kv_pool, blocks, offsets, _kv_rows(k1, v1, cfg, 1))
         kf, vf = _kv_view(kvc.gather_kv(kv_pool, block_table, gather_len), cfg)
-
-        kf = _repeat_kv(kf, h // kv)
-        vf = _repeat_kv(vf, h // kv)
-        qf = q.astype(jnp.float32) * (cfg.hd ** -0.5)
-        s = jnp.einsum("bqhd,bshd->bhqs", qf, kf, preferred_element_type=jnp.float32)
         kv_pos = jnp.arange(gather_len)
-        s = jnp.where((kv_pos[None, :] <= pos[:, None])[:, None, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bhqs,bshd->bqhd", p, vf, preferred_element_type=jnp.float32)
+        valid = kv_pos[None, :] <= pos[:, None]
+        out = _gqa_decode_core(q, kf, vf, valid)
     out = dense(
         params["wo"], out.reshape(b, 1, -1).astype(x.dtype), cfg,
         site="attn.wo", layer=layer,
