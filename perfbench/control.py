@@ -40,7 +40,7 @@ def readings(cell, seed: int, seconds: float) -> dict:
     """Program and control widest gaps of one seed's run of ``cell``."""
     import jax
 
-    from perfbench import reference_qwen2, weights
+    from perfbench import weights
 
     sched = harness.build_scheduler(cell, seed, harness.pool_sizes(cell.mix, seconds))
     inst = harness.Instrument(sched)
@@ -52,12 +52,12 @@ def readings(cell, seed: int, seconds: float) -> dict:
     )
     del sched, inst, res
     gc.collect()
-    w = weights.published_weights(cell.config["sizes"], seed)
+    w = weights.published_weights(cell.arch, cell.config["sizes"], seed)
     out = {"seed": seed, "requests": len(sample)}
     for key, bits in (("program", None), ("control", check["control_bits"])):
         gaps = np.concatenate(
             [
-                reference_qwen2.served_gaps(w, p, s, cell.config["sizes"], control_bits=bits)
+                cell.arch.served_gaps(w, p, s, cell.config["sizes"], control_bits=bits)
                 for p, s, _ in sample
             ]
         )

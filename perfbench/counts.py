@@ -5,82 +5,84 @@ implements it.  No slice pass, DPE chunk slot, tile padding or idle decode
 row is counted, so a later implementation that does the same work in
 fewer passes reads as faster and not as less work.
 
+The shapes come from the configuration's architecture module (``arch``):
+
+* ``gemm_sites(sizes)``: (site, K, C, passes, banks, layers) of every GEMM
+  through the photonic kernel.  A token row passes ``passes`` times through
+  a K x C weight, on average (a share of held experts), and a call reads
+  ``banks`` such weights; the site runs in ``layers`` layers;
+* ``attention_widths(sizes)``: (QK width, PV width, layers), the widths one
+  query token contracts against each position it attends;
+* ``unembed_shape(sizes)``: (K, C) of the unembedding.
+
 ``sizes`` is a configuration file's ``sizes`` group (published key names).
 """
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Tuple
+from typing import Dict, Iterable, List
 
 
-def head_dim(sizes: dict) -> int:
-    return sizes.get("head_dim") or sizes["hidden_size"] // sizes["num_attention_heads"]
+def routed_params(arch, sizes: dict):
+    """Weights a token row passes through in the photonic kernel, all
+    layers: each site's K x C times its passes and its layers."""
+    sites = arch.gemm_sites(sizes)
+    return sum(layers * passes * k * c for _, k, c, passes, _, layers in sites)
 
 
-def gemm_sites(sizes: dict) -> List[Tuple[str, int, int]]:
-    """(site, K, C) of every GEMM of one layer that runs through the
-    photonic kernel: Q, K, V, O, the fused gate/up and down projections."""
-    d, f = sizes["hidden_size"], sizes["intermediate_size"]
-    hq = sizes["num_attention_heads"] * head_dim(sizes)
-    hkv = sizes["num_key_value_heads"] * head_dim(sizes)
-    return [
-        ("attn.wq", d, hq),
-        ("attn.wk", d, hkv),
-        ("attn.wv", d, hkv),
-        ("attn.wo", hq, d),
-        ("ffn.wi", d, 2 * f),
-        ("ffn.wo", f, d),
-    ]
-
-
-def routed_params(sizes: dict) -> int:
-    """Weights that pass through the photonic kernel, all layers."""
-    per_layer = sum(k * c for _, k, c in gemm_sites(sizes))
-    return sizes["num_hidden_layers"] * per_layer
-
-
-def gemm_call_bound_s(rows: int, k: int, c: int, peaks: Dict[str, float]) -> float:
-    """Least time one GEMM of ``rows`` x ``k`` x ``c`` can take on the chip:
-    int8 operations at the int8 peak, or int8 weights plus bf16
+def gemm_call_bound_s(
+    rows: int, k: int, c: int, peaks: Dict[str, float], passes=1, banks=1
+) -> float:
+    """Least time one GEMM call over ``rows`` token rows can take on the
+    chip: its int8 operations (``passes`` per row through a ``k`` x ``c``
+    weight) at the int8 peak, or its ``banks`` int8 weights plus bf16
     activations in and out at the HBM bandwidth, whichever is longer."""
-    ops = 2.0 * rows * k * c
-    nbytes = k * c + 2.0 * rows * k + 2.0 * rows * c
+    ops = 2.0 * rows * passes * k * c
+    nbytes = banks * k * c + 2.0 * rows * passes * k + 2.0 * rows * passes * c
     return max(ops / peaks["int8_ops"], nbytes / peaks["hbm_bytes_per_s"])
 
 
-def gemm_bound_s(sizes: dict, rows: int, peaks: Dict[str, float]) -> float:
-    """Least time of every routed GEMM of one model call over ``rows``."""
+def gemm_bound_s(arch, sizes: dict, rows: int, peaks: Dict[str, float]) -> float:
+    """Least time of every routed GEMM of one model call over ``rows``:
+    the sites that share a layer count are summed, then multiplied by it."""
     if rows <= 0:
         return 0.0
-    per_layer = sum(gemm_call_bound_s(rows, k, c, peaks) for _, k, c in gemm_sites(sizes))
-    return sizes["num_hidden_layers"] * per_layer
+    by_layers: Dict[int, List[float]] = {}
+    for _, k, c, passes, banks, layers in arch.gemm_sites(sizes):
+        by_layers.setdefault(layers, []).append(
+            gemm_call_bound_s(rows, k, c, peaks, passes, banks)
+        )
+    return sum(layers * sum(bounds) for layers, bounds in by_layers.items())
 
 
-def attention_ops(sizes: dict, contexts: Iterable[int]) -> float:
+def attention_ops(arch, sizes: dict, contexts: Iterable[int]) -> float:
     """Q.K and P.V operations of one query token per entry of ``contexts``
     (the number of positions it attends to), over all layers."""
-    per_pos = 4.0 * sizes["num_attention_heads"] * head_dim(sizes)
-    return sizes["num_hidden_layers"] * per_pos * float(sum(contexts))
+    widths = arch.attention_widths(sizes)
+    per_pos = sum(layers * (2.0 * qk + 2.0 * pv) for qk, pv, layers in widths)
+    return per_pos * float(sum(contexts))
 
 
-def unembed_ops(sizes: dict, rows: int) -> float:
-    return 2.0 * rows * sizes["vocab_size"] * sizes["hidden_size"]
+def unembed_ops(arch, sizes: dict, rows: int) -> float:
+    k, c = arch.unembed_shape(sizes)
+    return 2.0 * rows * c * k
 
 
-def prefill_ops(sizes: dict, t0: int, tc: int, final: bool) -> float:
+def prefill_ops(arch, sizes: dict, t0: int, tc: int, final: bool) -> float:
     """A chunk of ``tc`` prompt tokens from position ``t0``: routed GEMMs,
     causal attention at the real context, and the head for the last
     token of a final chunk."""
-    attn = attention_ops(sizes, range(t0 + 1, t0 + tc + 1))
-    return 2.0 * routed_params(sizes) * tc + attn + (unembed_ops(sizes, 1) if final else 0.0)
+    attn = attention_ops(arch, sizes, range(t0 + 1, t0 + tc + 1))
+    head = unembed_ops(arch, sizes, 1) if final else 0.0
+    return 2.0 * routed_params(arch, sizes) * tc + attn + head
 
 
-def decode_ops(sizes: dict, contexts: List[int]) -> float:
+def decode_ops(arch, sizes: dict, contexts: List[int]) -> float:
     """One decode step of ``len(contexts)`` live rows; ``contexts`` holds
     each row's positions attended (its cache length plus the new token)."""
     rows = len(contexts)
     return (
-        2.0 * routed_params(sizes) * rows
-        + unembed_ops(sizes, rows)
-        + attention_ops(sizes, contexts)
+        2.0 * routed_params(arch, sizes) * rows
+        + unembed_ops(arch, sizes, rows)
+        + attention_ops(arch, sizes, contexts)
     )

@@ -17,11 +17,11 @@
    afterwards, and reports ``ttft_p95_ms`` and ``tpot_p95_ms``.  With
    ``--trace 1`` a stretch in the middle of the window is traced and the
    cell's per-layer metrics are read from it instead;
-4. frees the program's state and runs the float32 reference
-   (``perfbench/reference_qwen2.py``) over a seeded sample of the finished
-   requests, the longest among them: ``correct`` holds when no served token
-   lies further below the reference's best logit than the configuration's
-   limit.
+4. frees the program's state and runs the float32 reference of the
+   configuration's architecture module (the file its ``"reference"`` key
+   names) over a seeded sample of the finished requests, the longest among
+   them: ``correct`` holds when no served token lies further below the
+   reference's best logit than the configuration's limit.
 """
 
 from __future__ import annotations
@@ -35,6 +35,7 @@ import shutil
 import sys
 import tempfile
 import time
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -380,6 +381,7 @@ class Context:
     hi: int
     steps: List[Step]  # steps wholly inside the traced window
     sizes: dict
+    arch: ModuleType  # the configuration's architecture module: its work counts
     peaks: Dict[str, float]
     kernel: List[str]  # names under which the device shows the GEMM kernel
     prefill_program: List[str]
@@ -394,8 +396,8 @@ def traced_context(cell, inst, tracer, peaks) -> Context:
     steps = [s for s in inst.steps if s.t0 >= tracer.lo and s.t1 <= tracer.hi]
     names = cell.config["device_names"]
     return Context(
-        events=events, lo=lo, hi=hi, steps=steps, sizes=cell.config["sizes"], peaks=peaks,
-        kernel=names["kernel"], prefill_program=names["prefill_program"],
+        events=events, lo=lo, hi=hi, steps=steps, sizes=cell.config["sizes"], arch=cell.arch,
+        peaks=peaks, kernel=names["kernel"], prefill_program=names["prefill_program"],
         decode_program=names["decode_program"],
     )
 
@@ -420,18 +422,19 @@ def check_sample(requests, seed: int, served_target: int, max_requests: int):
     return [(np.asarray(r.prompt, np.int32), list(r.output), r.max_new_tokens) for r in sample]
 
 
-def reference_check(config: dict, seed: int, sample) -> Dict[str, float]:
+def reference_check(cell, seed: int, sample) -> Dict[str, float]:
     """Widest gap by which a served token's reference logit lies below
     the reference's best, over the sample; and the served-token count."""
     import jax
 
-    from perfbench import reference_qwen2, weights
+    from perfbench import weights
 
-    w = weights.published_weights(config["sizes"], seed)
+    sizes = cell.config["sizes"]
+    w = weights.published_weights(cell.arch, sizes, seed)
     gaps, short = [], 0
     for prompt, served, want in sample:
         short += len(served) != want
-        gaps.append(reference_qwen2.served_gaps(w, prompt, served, config["sizes"]))
+        gaps.append(cell.arch.served_gaps(w, prompt, served, sizes))
     del w
     jax.clear_caches()
     if not gaps:
@@ -500,11 +503,11 @@ def build_scheduler(cell, seed: int, sizes_pool):
     from perfbench import model, weights
     from repro.serving import Scheduler, ServingConfig
 
-    arch, cfg = model.build(cell.config, seed)
-    params = weights.program_weights(cell.config["sizes"], seed)
-    model.check_layout(arch, cfg, params)
+    program_arch, cfg = model.build(cell.config, seed, cell.arch)
+    params = weights.program_weights(cell.arch, cell.config["sizes"], seed)
+    model.check_layout(program_arch, cfg, params)
     scfg = ServingConfig(**cell.mix["serving"], greedy=True, record_logits=False)
-    sched = Scheduler(arch, cfg, params, scfg, clock=time.perf_counter)
+    sched = Scheduler(program_arch, cfg, params, scfg, clock=time.perf_counter)
     del params
     warm_up(sched, cell.mix, sizes_pool)
     return sched
@@ -575,7 +578,7 @@ def run_cell(cell, args, devices, peaks, *, t_start: float, fault=None) -> dict:
     attempted, failed = res["attempted"], res["failed"]
     del sched, inst, res
     gc.collect()
-    got = reference_check(cell.config, seed, sample)
+    got = reference_check(cell, seed, sample)
     limit = check["widest_gap_limit"]
     correct = bool(
         got["served"] >= 1 and got["short"] == 0 and got["widest_gap"] <= limit

@@ -2,8 +2,9 @@
 
 The configuration file names the program's model module and states the
 published sizes and the photonic operating point; this module turns them
-into the program's ``ModelConfig``.  The weights are the benchmark's own
-(``perfbench/weights.py``).
+into the program's ``ModelConfig``, through the size map of the
+configuration's architecture module (``arch.SIZE_FIELDS``).  The weights
+are the benchmark's own (``perfbench/weights.py``).
 """
 
 from __future__ import annotations
@@ -13,35 +14,34 @@ import importlib
 
 import jax
 
-# Published key -> the program's ModelConfig field.
-_SIZE_FIELDS = {
-    "num_hidden_layers": "num_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-}
-
 
 def noise_seed(seed: int) -> int:
     """The analog channel's noise seed, derived from ``--seed``."""
     return (seed * 2654435761 + 97) % (1 << 31)
 
 
-def build(config: dict, seed: int):
-    """``(arch, ModelConfig)`` of a configuration file at ``seed``."""
+def program_fields(arch, sizes: dict) -> dict:
+    """The ``ModelConfig`` fields that the published ``sizes`` set.  A key
+    that ``arch`` neither maps to a field nor lists as shaping nothing the
+    program takes is an error: dropped, it would leave the program at its
+    preset's value under the published name."""
+    unknown = sorted(k for k in sizes if k not in arch.SIZE_FIELDS and k not in arch.UNSHAPED)
+    if unknown:
+        raise ValueError(
+            f"published keys {unknown} are neither mapped to a ModelConfig field "
+            f"nor listed as unshaped by {arch.__file__}"
+        )
+    return {f: sizes[k] for k, f in arch.SIZE_FIELDS.items() if k in sizes}
+
+
+def build(config: dict, seed: int, arch):
+    """``(program arch, ModelConfig)`` of a configuration file at ``seed``;
+    ``arch`` is the configuration's architecture module."""
     from repro.core.dpu import DPUConfig
     from repro.noise import build_channel_model
 
     mod = importlib.import_module(config["model"])
-    sizes = config["sizes"]
-    cfg = dataclasses.replace(
-        mod.FULL, **{f: sizes[k] for k, f in _SIZE_FIELDS.items() if k in sizes}
-    )
+    cfg = dataclasses.replace(mod.FULL, **program_fields(arch, config["sizes"]))
     ph = config["photonic"]
     dpu = DPUConfig(
         organization=ph["organization"],

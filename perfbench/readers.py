@@ -31,16 +31,17 @@ def idle_share(ctx):
 
 def gemm_roofline(ctx):
     """Least time of the window's routed GEMMs (``counts.gemm_bound_s``
-    over the real rows of each call) over the kernel's device time, per cent."""
+    over the real rows of each call, from the architecture module's sites)
+    over the kernel's device time, per cent."""
     kernel_ns = trace.time_ns(ctx.events, ctx.lo, ctx.hi, trace.OPS_LINE, _op_named(ctx.kernel))
     if kernel_ns <= 0 or ctx.peaks is None:
         return None
     bound = 0.0
     for s in ctx.steps:
         for _, tc, _, _ in s.prefill:
-            bound += counts.gemm_bound_s(ctx.sizes, tc, ctx.peaks)
+            bound += counts.gemm_bound_s(ctx.arch, ctx.sizes, tc, ctx.peaks)
         if s.decode:
-            bound += counts.gemm_bound_s(ctx.sizes, len(s.decode), ctx.peaks)
+            bound += counts.gemm_bound_s(ctx.arch, ctx.sizes, len(s.decode), ctx.peaks)
     return 100.0 * bound / (kernel_ns / 1e9)
 
 
@@ -73,9 +74,9 @@ def mfu(ctx):
     ops, wall = 0.0, 0.0
     for s in ctx.steps:
         for t0, tc, _, final in s.prefill:
-            ops += counts.prefill_ops(ctx.sizes, t0, tc, final)
+            ops += counts.prefill_ops(ctx.arch, ctx.sizes, t0, tc, final)
         if s.decode:
-            ops += counts.decode_ops(ctx.sizes, s.decode)
+            ops += counts.decode_ops(ctx.arch, ctx.sizes, s.decode)
         wall += s.t1 - s.t0
     if wall <= 0 or ops <= 0 or ctx.peaks is None:
         return None

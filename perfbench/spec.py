@@ -6,6 +6,11 @@ that is found by that name alone, so a later cell, mix or metric is added
 by adding files and a ``workloads`` entry:
 
 * configuration: ``perfbench/configs/<config>.json``
+* architecture module: the path the configuration's ``"reference"`` names,
+  from the root of the checkout: the float32 reference, the published
+  weight layout and its map to the program's tree, the size-key map and
+  the work counts of that architecture.  No other file of the benchmark
+  names an architecture, so a new one comes as a new module.
 * traffic mix: ``perfbench/mixes/<traffic>.json``
 * per-layer metric: ``perfbench/metrics/<metric>.py`` with ``read(ctx)``
 * peaks: ``perfbench/peaks.json``, keyed by ``device_kind``
@@ -14,9 +19,11 @@ by adding files and a ``workloads`` entry:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parent
@@ -40,6 +47,7 @@ class Metric:
 class Cell:
     name: str
     config: dict
+    arch: ModuleType  # the configuration's architecture module
     mix: dict
     chips: int
     end_to_end: List[Metric]
@@ -86,6 +94,7 @@ def find_cell(name: str, root: Path = ROOT) -> Cell:
     return Cell(
         name=name,
         config=config,
+        arch=architecture(config, root),
         mix=mix,
         chips=int(w["chips"]),
         end_to_end=[m for m in _metrics(bench["end_to_end"]) if m.applies_to(name)],
@@ -93,16 +102,34 @@ def find_cell(name: str, root: Path = ROOT) -> Cell:
     )
 
 
-def metric_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
-    """``read(ctx)`` of ``metrics/<name>.py``; the file name is the metric's
-    name, dots included, so it is loaded by path and not imported."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"perfbench_metric_{name}", path)
+def _load(module_name: str, path: Path) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(module_name, path)
     if spec is None or spec.loader is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _architecture(path: Path) -> ModuleType:
+    return _load(f"perfbench_arch_{path.stem}", path)
+
+
+def architecture(config: dict, root: Path = ROOT) -> ModuleType:
+    """The architecture module that ``config["reference"]`` names, a path
+    from ``root``; loaded once per process, so its compiled programs are
+    shared by every caller."""
+    path = (root / config["reference"]).resolve()
+    if not path.is_file():
+        raise FileNotFoundError(f"architecture module {path} (the configuration's reference)")
+    return _architecture(path)
+
+
+def metric_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
+    """``read(ctx)`` of ``metrics/<name>.py``; the file name is the metric's
+    name, dots included, so it is loaded by path and not imported."""
+    return _load(f"perfbench_metric_{name}", bench_dir / "metrics" / f"{name}.py").read
 
 
 def peaks_for(device_kind: str, bench_dir: Path = BENCH_DIR) -> Dict[str, float]:

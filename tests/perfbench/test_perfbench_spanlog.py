@@ -43,7 +43,7 @@ TRACED = [harness.Step(t0=-0.0005, t1=0.0995), harness.Step(t0=0.0995, t1=0.1995
 
 def ctx(steps):
     return harness.Context(
-        events=[], lo=0, hi=1, steps=steps, sizes={}, peaks=None,
+        events=[], lo=0, hi=1, steps=steps, sizes={}, arch=None, peaks=None,
         kernel=["photonic_gemm"], prefill_program=["prefill_fn"],
         decode_program=["decode_fn"],
     )

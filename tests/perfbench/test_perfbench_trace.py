@@ -10,7 +10,9 @@ import pytest
 from perfbench import harness, readers, spec, trace
 
 ROOT = Path(__file__).resolve().parents[2]
-SIZES = json.loads((ROOT / "perfbench/configs/qwen2_0_5b-ideal.json").read_text())["sizes"]
+CONFIG = json.loads((ROOT / "perfbench/configs/qwen2_0_5b-ideal.json").read_text())
+SIZES = CONFIG["sizes"]
+ARCH = spec.architecture(CONFIG)
 PEAKS = spec.peaks_for("TPU v5 lite")
 DEV = "/device:TPU:0"
 HOST = "/host:CPU"
@@ -42,7 +44,7 @@ EVENTS = [
 def ctx(events, steps):
     lo, hi = trace.window(events)
     return harness.Context(
-        events=events, lo=lo, hi=hi, steps=steps, sizes=SIZES, peaks=PEAKS,
+        events=events, lo=lo, hi=hi, steps=steps, sizes=SIZES, arch=ARCH, peaks=PEAKS,
         kernel=["photonic_gemm"], prefill_program=["prefill_fn"],
         decode_program=["decode_fn"],
     )
@@ -70,12 +72,12 @@ def test_kernel_and_program_time():
 
 def test_roofline_and_mfu_from_counts():
     c = ctx(EVENTS, STEPS)
-    bound = readers.counts.gemm_bound_s(SIZES, 512, PEAKS) + readers.counts.gemm_bound_s(
-        SIZES, 64, PEAKS
+    bound = readers.counts.gemm_bound_s(ARCH, SIZES, 512, PEAKS) + readers.counts.gemm_bound_s(
+        ARCH, SIZES, 64, PEAKS
     )
     assert readers.gemm_roofline(c) == pytest.approx(100 * bound / 0.038)
-    ops = readers.counts.prefill_ops(SIZES, 0, 512, False) + readers.counts.decode_ops(
-        SIZES, [100] * 64
+    ops = readers.counts.prefill_ops(ARCH, SIZES, 0, 512, False) + readers.counts.decode_ops(
+        ARCH, SIZES, [100] * 64
     )
     assert readers.mfu(c) == pytest.approx(100 * ops / (0.09 * 393e12))
 
@@ -153,3 +155,33 @@ def test_recorded_top_ops_use_self_time():
     assert total < (hi - lo) / 1e9
     gaps = trace.idle_gaps(events, lo, hi)
     assert gaps and all(name.startswith("pb.") for name, _ in gaps)
+
+
+# What every reader of ``perfbench/metrics/`` gave on the recorded step
+# before the work counts moved into the architecture module; the span-log
+# readers find no log here.
+RECORDED = {
+    "decode_kv_useful.tps": None,
+    "decode_step_ms.tpot": 154.058818,
+    "decode_step_ms.tps": 154.058818,
+    "gemm_roofline.tpot": 8.754380853192185,
+    "gemm_roofline.tps": 8.754380853192185,
+    "host_idle_ms.tps": None,
+    "idle_share.tpot": 4.8137302746596555,
+    "idle_share.tps": 4.8137302746596555,
+    "mfu.tpot": 0.4644477814837012,
+    "mfu.tps": 0.4644477814837012,
+    "prefill_ms_per_ktok.tps": 88.744103515625,
+    "prefill_ms_per_ktok.ttft": 88.744103515625,
+}
+
+
+def test_every_reader_reads_the_recorded_step_exactly_as_before(monkeypatch):
+    from perfbench import spanlog
+
+    monkeypatch.setattr(spanlog, "program_log", lambda: [])
+    events, step = recorded()
+    c = ctx(events, [step])
+    names = sorted(p.name[: -len(".py")] for p in (ROOT / "perfbench/metrics").glob("*.py"))
+    assert names == sorted(RECORDED)
+    assert {n: spec.metric_reader(n)(c) for n in names} == RECORDED
