@@ -57,7 +57,7 @@ def active_params(arch_name: str) -> float:
             cfg.num_experts,
             cfg.num_experts_per_tok,
         )
-        n_moe_layers = cfg.num_layers - (1 if (cfg.mla and cfg.num_experts) else 0)
+        n_moe_layers = cfg.num_layers - cfg.first_dense_layers
         routed = n_moe_layers * e * 3 * d * f
         total -= routed * (1.0 - k / e)
     return float(total)

@@ -533,3 +533,159 @@ def photonic_gemm_fused_pallas(
         interpret=interpret,
         name="photonic_gemm_fused_pallas",
     )(*args)
+
+
+def _grouped_kernel(tile_expert_ref, *refs, analog: bool, **stages):
+    """One row tile of the grouped GEMM: the fused kernel's body against
+    the weight bank of the tile's expert (``tile_expert_ref``, prefetched
+    into SMEM, is read only by the index maps)."""
+    del tile_expert_ref
+    refs = list(refs)
+    seed_ref = refs.pop(0) if analog else None
+    xs_ref, x_ref, w_ref, wscale_ref, out_ref, acc_ref = refs
+    operand_bits = stages.pop("operand_bits")
+    out_dtype = stages.pop("out_dtype")
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    qmax = float(2 ** (operand_bits - 1) - 1)
+    tile_seed = None
+    if analog:
+        tile_seed = fold_seed(
+            seed_ref[0].astype(jnp.uint32),
+            pl.program_id(0),
+            pl.program_id(1),
+            pl.program_id(2),
+        )
+    acc_ref[...] += _accumulate(
+        quantize_tile(x_ref[...], xs_ref[0], qmax),
+        w_ref[...].astype(jnp.int32),
+        tile_seed,
+        **stages,
+    )
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _epilogue():
+        y = apply_epilogue(acc_ref[...], xs_ref[0], wscale_ref[...], None, EpilogueSpec())
+        out_ref[...] = y.astype(out_dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "operand_bits",
+        "out_dtype",
+        "slice_bits",
+        "num_slices",
+        "chunk_lanes",
+        "adc_bits",
+        "noise_sigma",
+        "filter_alpha",
+        "intermod_eps",
+        "crossweight_eps",
+        "valid_chunks",
+        "tile_r",
+        "tile_c",
+        "tile_k",
+        "interpret",
+    ),
+)
+def photonic_gemm_grouped_pallas(
+    x: jax.Array,  # (M, K) f32 rows, grouped by expert in tile_r-row tiles
+    wq: jax.Array,  # (E, K, C) int8 banks, C % tile_c == 0
+    x_scale: jax.Array,  # () or (1,) f32 activation quantization scale
+    w_scale: jax.Array,  # (E, C) f32 per-expert per-column dequant scale
+    tile_expert: jax.Array,  # (M // tile_r,) int32: the expert of each row tile
+    n_tiles: jax.Array,  # () int32: row tiles in use, 1 .. M // tile_r
+    seed: Optional[jax.Array] = None,  # int32 (1,), required if noisy
+    *,
+    operand_bits: int = 8,
+    out_dtype=jnp.float32,
+    slice_bits: int = 4,
+    num_slices: int = 2,
+    chunk_lanes: int = 128,
+    adc_bits: Optional[int] = None,
+    noise_sigma: float = 0.0,
+    filter_alpha: float = 0.0,
+    intermod_eps: float = 0.0,
+    crossweight_eps: float = 0.0,
+    valid_chunks: Optional[int] = None,
+    tile_r: int = 128,
+    tile_c: int = 128,
+    tile_k: int = 512,
+    interpret: bool = False,
+) -> jax.Array:
+    """The fused hot path over many weight banks in one call (a grouped, or
+    ragged, GEMM): row tile ``i`` of ``x`` is quantized in-kernel, runs the
+    DPU datapath against bank ``tile_expert[i]`` and is rescaled by that
+    bank's column scales.  Only the first ``n_tiles`` row tiles are
+    computed: the grid's row extent is ``n_tiles`` itself, so tiles past it
+    cost nothing, and their output rows are left unwritten."""
+    m, k = x.shape
+    e, _, c = wq.shape
+    assert m % tile_r == 0 and c % tile_c == 0 and k % tile_k == 0, (
+        x.shape,
+        wq.shape,
+        (tile_r, tile_c, tile_k),
+    )
+    assert tile_k % chunk_lanes == 0 and chunk_lanes % 128 == 0, (tile_k, chunk_lanes)
+    analog = (
+        noise_sigma > 0.0
+        or filter_alpha > 0.0
+        or intermod_eps > 0.0
+        or crossweight_eps > 0.0
+    )
+    if noise_sigma > 0.0 and seed is None:
+        raise ValueError("noise_sigma > 0 requires a seed")
+    kernel = functools.partial(
+        _grouped_kernel,
+        analog=analog,
+        operand_bits=operand_bits,
+        out_dtype=out_dtype,
+        slice_bits=slice_bits,
+        num_slices=num_slices,
+        chunk_lanes=chunk_lanes,
+        adc_bits=adc_bits,
+        noise_sigma=noise_sigma,
+        filter_alpha=filter_alpha,
+        intermod_eps=intermod_eps,
+        crossweight_eps=crossweight_eps,
+        valid_chunks=valid_chunks,
+    )
+    in_specs = [
+        pl.BlockSpec(memory_space=pltpu.SMEM),  # x_scale
+        pl.BlockSpec((tile_r, tile_k), lambda i, j, kk, te: (i, kk)),
+        pl.BlockSpec((None, tile_k, tile_c), lambda i, j, kk, te: (te[i], kk, j)),
+        pl.BlockSpec((None, 1, tile_c), lambda i, j, kk, te: (te[i], 0, j)),
+    ]
+    args = [
+        jnp.asarray(x_scale, jnp.float32).reshape(1),
+        x,
+        wq,
+        w_scale.astype(jnp.float32).reshape(e, 1, c),
+    ]
+    if analog:
+        if seed is None:
+            seed = jnp.zeros((1,), jnp.int32)
+        in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.insert(0, jnp.asarray(seed, jnp.int32).reshape(1))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(jnp.asarray(n_tiles, jnp.int32), c // tile_c, k // tile_k),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((tile_r, tile_c), lambda i, j, kk, te: (i, j)),
+        scratch_shapes=[pltpu.VMEM((tile_r, tile_c), jnp.int32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, c), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        name="photonic_gemm_grouped_pallas",
+    )(tile_expert.astype(jnp.int32), *args)

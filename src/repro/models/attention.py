@@ -41,12 +41,20 @@ def cross_attn_def(cfg: ModelConfig) -> Dict[str, Any]:
 
 
 def mla_def(cfg: ModelConfig) -> Dict[str, Any]:
+    """DeepSeek-V2's latent attention without query compression: ``wq``,
+    the joint down-projection ``wdkv`` (latent ``c_kv`` then the shared
+    rope key), the latent's RMSNorm ``kv_norm``, the up-projections
+    ``wuk``/``wuv`` (the published ``kv_b_proj`` split by head part) and
+    ``wo``.  No projection has a bias."""
+    if cfg.qkv_bias:
+        raise ValueError("MLA projections with a bias are not implemented")
     d, h = cfg.d_model, cfg.n_q_heads
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     r = cfg.kv_lora_rank
     return {
         "wq": qdense_def(cfg, d, h * (nope + rope), (None, "heads")),
         "wdkv": qdense_def(cfg, d, r + rope, (None, None)),
+        "kv_norm": cm.rmsnorm_def(r),
         "wuk": qdense_def(cfg, r, h * nope, (None, "heads")),
         "wuv": qdense_def(cfg, r, h * vd, (None, "heads")),
         "wo": qdense_def(cfg, h * vd, d, ("heads", None)),
@@ -503,19 +511,30 @@ def _mla_up_weight(p: Dict[str, Any]) -> jax.Array:
     return w
 
 
+def _mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: the query head's ``(nope + rope) ** -0.5`` times
+    YaRN's attention factor (1 without rope scaling)."""
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return qk**-0.5 * cm.rope_attention_factor(cfg.rope_scaling)
+
+
 def _mla_qkv(params, x, cfg: ModelConfig, positions, layer=None):
+    """Queries split into their nope and rope parts, the RMSNorm'd latent
+    ``c_kv`` and the shared rope key ``(B, T, 1, rope)``, rope applied."""
     h = cfg.n_q_heads
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     b, t, _ = x.shape
+    inv_freq = cm.rope_inv_freq(rope, cfg.rope_theta, cfg.rope_scaling)
     q = dense(params["wq"], x, cfg, site="attn.wq", layer=layer)
     q = q.reshape(b, t, h, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, inv_freq)
 
     dkv = dense(params["wdkv"], x, cfg, site="attn.wdkv", layer=layer)  # (B,T,r+rope)
     c_kv, k_rope = dkv[..., : cfg.kv_lora_rank], dkv[..., cfg.kv_lora_rank:]
+    c_kv = cm.rmsnorm(params["kv_norm"], c_kv, cfg.norm_eps)
     k_rope = apply_rope(
-        k_rope[:, :, None, :], positions, cfg.rope_theta
+        k_rope[:, :, None, :], positions, cfg.rope_theta, inv_freq
     )  # (B,T,1,rope)
     return q_nope, q_rope, c_kv, k_rope
 
@@ -541,9 +560,8 @@ def mla_attention(
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(params, x, cfg, positions, layer)
     k, v = _mla_expand_kv(params, c_kv, k_rope, cfg, layer)
     q = jnp.concatenate([q_nope, q_rope], -1)
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
     out = chunked_attention(
-        q, k, v, causal=causal, scale=scale,
+        q, k, v, causal=causal, scale=_mla_scale(cfg),
         chunk=cfg.attn_chunk, unroll=cfg.unroll_scans,
         acc_dtype=jnp.float32 if cfg.attn_f32 else jnp.bfloat16,
     )
@@ -555,9 +573,8 @@ def mla_prefill(params, x, cfg: ModelConfig, *, positions, max_seq: int, layer=N
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(params, x, cfg, positions, layer)
     k, v = _mla_expand_kv(params, c_kv, k_rope, cfg, layer)
     q = jnp.concatenate([q_nope, q_rope], -1)
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
     out = chunked_attention(
-        q, k, v, causal=True, scale=scale,
+        q, k, v, causal=True, scale=_mla_scale(cfg),
         chunk=cfg.attn_chunk, unroll=cfg.unroll_scans,
         acc_dtype=jnp.float32 if cfg.attn_f32 else jnp.bfloat16,
     )
@@ -570,17 +587,39 @@ def mla_prefill(params, x, cfg: ModelConfig, *, positions, max_seq: int, layer=N
     return out, cache
 
 
+def _mla_absorbed_core(params, q_nope, q_rope, c, kr, valid, cfg: ModelConfig):
+    """Attention against the latent cache with the up-projections absorbed
+    into the query and output paths (DeepSeek-V2's serving form): no
+    ``(B, S, H, head_dim)`` key or value is ever formed.
+
+    ``q_nope`` (B, 1, H, nope), ``q_rope`` (B, 1, H, rope); ``c`` (B, S, r)
+    and ``kr`` (B, S, rope) the latent rows; ``valid`` (B or 1, S) bool.
+    Every contraction runs in float32 at ``Precision.HIGHEST``.  Equals
+    the expanded form (keys ``c @ wuk`` beside the shared rope key, values
+    ``c @ wuv``) by linearity.  Returns (B, 1, H, v_head_dim) float32."""
+    h = cfg.n_q_heads
+    nope, vd, r = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    hi = jax.lax.Precision.HIGHEST
+    f32 = jnp.float32
+    w_uk = _mla_up_weight(params["wuk"]).astype(f32).reshape(r, h, nope)
+    w_uv = _mla_up_weight(params["wuv"]).astype(f32).reshape(r, h, vd)
+    # absorb W_uk into q:  q_abs[b,h,r] = sum_n q_nope[b,1,h,n] W_uk[r,h,n]
+    q_abs = jnp.einsum("bqhn,rhn->bqhr", q_nope.astype(f32), w_uk, precision=hi)
+    s = jnp.einsum("bqhr,bsr->bhqs", q_abs, c.astype(f32), precision=hi)
+    s = s + jnp.einsum(
+        "bqhe,bse->bhqs", q_rope.astype(f32), kr.astype(f32), precision=hi
+    )
+    s = jnp.where(valid[:, None, None, :], s * _mla_scale(cfg), -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    ctx = jnp.einsum("bhqs,bsr->bqhr", p, c.astype(f32), precision=hi)
+    return jnp.einsum("bqhr,rhv->bqhv", ctx, w_uv, precision=hi)  # absorb W_uv
+
+
 def mla_decode_absorbed(params, x, cache, pos, cfg: ModelConfig, layer=None):
     """MLA decode with the up-projections ABSORBED into the query/output
-    paths (DeepSeek-V2 serving trick): attention runs directly against the
-    compressed c_kv cache — no (B, S, H, head_dim) K/V expansion, cutting
-    per-step traffic by ~H*head_dim/kv_lora_rank (4x for these configs).
-    Exactly equals mla_decode (linear identity; tested)."""
+    paths against the dense compressed cache (:func:`_mla_absorbed_core`);
+    equals :func:`mla_decode` by linearity (tested)."""
     b = x.shape[0]
-    h = cfg.n_q_heads
-    nope, rope, vd, r = (
-        cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
-    )
     posv = pos[None] if pos.ndim == 0 else pos
     q_nope, q_rope, c_kv1, k_rope1 = _mla_qkv(params, x, cfg, posv, layer)
     c = jax.lax.dynamic_update_slice_in_dim(
@@ -591,23 +630,8 @@ def mla_decode_absorbed(params, x, cache, pos, cfg: ModelConfig, layer=None):
     )
     c = cm.with_logical(c, ("batch", "kv_seq", None))
     kr = cm.with_logical(kr, ("batch", "kv_seq", None))
-
-    w_uk = _mla_up_weight(params["wuk"]).astype(jnp.float32).reshape(r, h, nope)
-    w_uv = _mla_up_weight(params["wuv"]).astype(jnp.float32).reshape(r, h, vd)
-    # absorb W_uk into q:  q_abs[b,h,r] = sum_n q_nope[b,1,h,n] W_uk[r,h,n]
-    q_abs = jnp.einsum("bqhn,rhn->bqhr", q_nope.astype(jnp.float32), w_uk)
-    cf = c.astype(jnp.float32)
-    s_nope = jnp.einsum("bqhr,bsr->bhqs", q_abs, cf)
-    s_rope = jnp.einsum(
-        "bqhe,bse->bhqs", q_rope.astype(jnp.float32), kr.astype(jnp.float32)
-    )
-    scale = (nope + rope) ** -0.5
-    s = (s_nope + s_rope) * scale
-    s_max = c.shape[1]
-    s = jnp.where((jnp.arange(s_max) <= pos)[None, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhqs,bsr->bqhr", p, cf)          # attention over c_kv
-    out = jnp.einsum("bqhr,rhv->bqhv", ctx, w_uv)      # absorb W_uv
+    valid = (jnp.arange(c.shape[1]) <= pos)[None, :]
+    out = _mla_absorbed_core(params, q_nope, q_rope, c, kr, valid, cfg)
     out = dense(
         params["wo"], out.reshape(b, 1, -1).astype(x.dtype), cfg,
         site="attn.wo", layer=layer,
@@ -632,8 +656,7 @@ def mla_decode(params, x, cache, pos, cfg: ModelConfig, layer=None):
     kr = cm.with_logical(kr, ("batch", "kv_seq", None))
     k, v = _mla_expand_kv(params, c, kr[:, :, None, :], cfg, layer)
     q = jnp.concatenate([q_nope, q_rope], -1)
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-    qf = q.astype(jnp.float32) * scale
+    qf = q.astype(jnp.float32) * _mla_scale(cfg)
     s = jnp.einsum("bqhd,bshd->bhqs", qf, k.astype(jnp.float32))
     s_max = k.shape[1]
     s = jnp.where((jnp.arange(s_max) <= pos)[None, None, None, :], s, -1e30)
@@ -655,3 +678,92 @@ def mla_cache_def(cfg: ModelConfig, batch: int, max_seq: int, dtype):
             dtype,
         ),
     }
+
+
+# ---------------------------------------------------------------------------
+# Paged MLA (repro.serving): the latent pool, every layer's leaves stacked
+# ---------------------------------------------------------------------------
+def _latent_rows(c_kv, k_rope):
+    """Pool rows of computed latents: ``c_kv`` (N, r), ``k_rope`` (N, 1, rope)."""
+    return {"c_kv": c_kv, "k_rope": k_rope[:, 0]}
+
+
+def mla_prefill_chunk(
+    params,
+    x: jax.Array,  # (1, tc, D) — one request's chunk
+    pool,  # latent pool, leaves (layers, num_blocks, bs, width)
+    li: jax.Array,  # scalar int32 — this layer's index in the pool
+    block_table: jax.Array,  # (W,) int32
+    t0: jax.Array,  # scalar int32 — chunk start
+    cfg: ModelConfig,
+    *,
+    t_full: int,
+    block_size: int,
+    positions,  # (tc,) int32
+    layer=None,
+):
+    """One chunk of a chunked MLA prefill: scatter the chunk's latent rows
+    (``c_kv``, ``k_rope``) into layer ``li`` of the pool, expand the
+    prompt's rows ``[0, t_full)`` to keys and values through ``wuk`` /
+    ``wuv`` and attend causally, as :func:`gqa_prefill_chunk` does; a chunk
+    that covers the whole prompt uses its own rows directly."""
+    from repro.serving import kv_cache as kvc
+
+    tc = x.shape[1]
+    q_nope, q_rope, c_kv, k_rope = _mla_qkv(params, x, cfg, positions, layer)
+    with jax.named_scope(ATTN_CORE):
+        blocks, offsets = kvc.chunk_dest(block_table, t0, tc, block_size)
+        pool = kvc.scatter_kv(
+            pool, blocks, offsets, _latent_rows(c_kv[0], k_rope[0]), layer=li
+        )
+    if t_full == tc:
+        c, kr, q_offset = c_kv, k_rope, 0
+    else:
+        g = kvc.gather_kv(pool, block_table[None], t_full, layer=li)
+        c, kr, q_offset = g["c_kv"], g["k_rope"][:, :, None, :], t0
+    k, v = _mla_expand_kv(params, c, kr, cfg, layer)
+    with jax.named_scope(ATTN_CORE):
+        out = chunked_attention(
+            jnp.concatenate([q_nope, q_rope], -1), k, v, causal=True,
+            q_offset=q_offset, scale=_mla_scale(cfg),
+            chunk=cfg.attn_chunk, unroll=cfg.unroll_scans,
+            acc_dtype=jnp.float32 if cfg.attn_f32 else jnp.bfloat16,
+        )
+    out = dense(params["wo"], out.reshape(1, tc, -1), cfg, site="attn.wo", layer=layer)
+    return out, pool
+
+
+def mla_decode_paged(
+    params,
+    x: jax.Array,  # (B, 1, D)
+    pool,  # latent pool, leaves (layers, num_blocks, bs, width)
+    li: jax.Array,  # scalar int32 — this layer's index in the pool
+    block_table: jax.Array,  # (B, W) int32
+    pos: jax.Array,  # (B,) int32
+    blocks: jax.Array,  # (B,) int32 — write destinations
+    offsets: jax.Array,  # (B,) int32
+    cfg: ModelConfig,
+    *,
+    gather_len: int,
+    layer=None,
+):
+    """One MLA decode step over the paged latent pool: scatter each row's
+    new latent, gather a ``(B, gather_len)`` latent slab through the block
+    table and attend in the absorbed form (:func:`_mla_absorbed_core`)
+    under the per-row mask ``kv_pos <= pos[b]``."""
+    from repro.serving import kv_cache as kvc
+
+    b = x.shape[0]
+    q_nope, q_rope, c1, kr1 = _mla_qkv(params, x, cfg, pos[:, None], layer)
+    with jax.named_scope(ATTN_CORE):
+        pool = kvc.scatter_kv(
+            pool, blocks, offsets, _latent_rows(c1[:, 0], kr1[:, 0]), layer=li
+        )
+        g = kvc.gather_kv(pool, block_table, gather_len, layer=li)
+        valid = jnp.arange(gather_len)[None, :] <= pos[:, None]
+        out = _mla_absorbed_core(params, q_nope, q_rope, g["c_kv"], g["k_rope"], valid, cfg)
+    out = dense(
+        params["wo"], out.reshape(b, 1, -1).astype(x.dtype), cfg,
+        site="attn.wo", layer=layer,
+    )
+    return out, pool

@@ -17,6 +17,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.dpu import DPUConfig
 
@@ -42,18 +43,30 @@ class ModelConfig:
     ffn_act: str = "swiglu"  # swiglu | gelu
 
     # MoE ------------------------------------------------------------------
-    num_experts: int = 0           # routed experts (0 = dense FFN)
+    # The router scores num_experts * ep_size experts; a layer holds
+    # num_experts of them (expert parallelism over ep_size chips, this
+    # chip's share first) and computes their part of the result alone.
+    num_experts: int = 0           # routed experts held (0 = dense FFN)
+    ep_size: int = 1               # chips that share each MoE layer
     num_experts_per_tok: int = 2
     num_shared_experts: int = 0
     moe_d_ff: Optional[int] = None  # per-expert hidden (defaults to d_ff)
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True    # renormalize the top-k weights, else
+    routed_scaling_factor: float = 1.0  # scale them by this
+    router_scoring: str = "softmax"  # the only scoring implemented
+    router_topk: str = "greedy"      # the only top-k method implemented
+    moe_layer_freq: int = 1          # every layer after the dense ones is MoE
+    first_dense_layers: int = 0      # leading dense-FFN layers (0 or 1)
 
     # MLA (deepseek-v2) ------------------------------------------------------
     mla: bool = False
     kv_lora_rank: int = 512
+    q_lora_rank: Optional[int] = None  # query compression: not implemented
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # YaRN rope scaling as published (a dict, kept as sorted items), or None
+    rope_scaling: Any = None
 
     # Vision cross-attention (llama-3.2-vision) ------------------------------
     cross_attn_every: int = 0      # 0 = no cross-attn layers
@@ -126,6 +139,24 @@ class ModelConfig:
             raise ValueError(
                 f"attn_impl={self.attn_impl!r} is not one of {impls}"
             )
+        for name, allowed in _IMPLEMENTED_ONLY.items():
+            if getattr(self, name) not in allowed:
+                raise ValueError(
+                    f"{name}={getattr(self, name)!r} is not implemented; the "
+                    f"program implements {allowed}"
+                )
+        if self.first_dense_layers not in (0, 1):
+            raise ValueError(
+                f"first_dense_layers={self.first_dense_layers} is not 0 or 1"
+            )
+        if isinstance(self.rope_scaling, dict):
+            if self.rope_scaling.get("type") != "yarn":
+                raise ValueError(
+                    f"rope_scaling {self.rope_scaling!r}: only YaRN is implemented"
+                )
+            object.__setattr__(
+                self, "rope_scaling", tuple(sorted(self.rope_scaling.items()))
+            )
         # Eager normalization through THE slicing resolution point
         # (unknown plane widths raise here, not at first GEMM).
         from repro.photonic.slicing import resolve_slicing
@@ -175,6 +206,16 @@ class ModelConfig:
         if self.vocab_size % model_axis:
             changes["padded_vocab"] = _round_up(self.vocab_size, model_axis)
         return dataclasses.replace(self, **changes) if changes else self
+
+
+# Settings the program takes from a published configuration but implements
+# for one value only: any other value is refused, never ignored.
+_IMPLEMENTED_ONLY = {
+    "router_scoring": ("softmax",),
+    "router_topk": ("greedy",),
+    "moe_layer_freq": (1,),
+    "q_lora_rank": (None,),
+}
 
 
 def _round_up(x: int, m: int) -> int:
@@ -404,6 +445,35 @@ def dense(
     return y
 
 
+def grouped_dense(
+    params: Dict[str, Any],
+    x: jax.Array,
+    cfg: ModelConfig,
+    tile_expert: jax.Array,
+    n_tiles: jax.Array,
+    *,
+    tile_r: int,
+    site: str,
+) -> jax.Array:
+    """Linear layer over a stack of weight banks (``params["w"]``, leading
+    bank axis): row tile ``i`` of ``x`` against bank ``tile_expert[i]``,
+    for the first ``n_tiles`` tiles; later rows are undefined.  Routes
+    through the photonic engine's grouped GEMM when enabled, else the
+    digital grouped product.  No bias, no fused activation."""
+    from repro.photonic.engine import grouped_digital
+    from repro.photonic.packing import PackedDense
+
+    w = params["w"]
+    eng = engine_from_model_config(cfg)
+    with jax.named_scope(site):
+        if eng is None:
+            wf = w.dequant() if isinstance(w, PackedDense) else w
+            return grouped_digital(x, wf, tile_expert, n_tiles, tile_r)
+        return eng.grouped_matmul(
+            x, w, tile_expert, n_tiles, tile_r=tile_r, site=site
+        ).astype(x.dtype)
+
+
 def _digital_epilogue(y, ep):
     """Bias/activation for fully digital matmuls — the historical op order
     (bias added in the output dtype, activation from the engine's shared
@@ -515,10 +585,64 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """Rotary inverse frequencies, with YaRN's (arXiv:2309.00071) when
+    ``scaling`` holds its published items: dimensions that turn fewer than
+    ``beta_slow`` times over the original context are interpolated by
+    ``factor``, those that turn more than ``beta_fast`` times are kept, and
+    a linear ramp joins the two (DeepSeek-V2's ``yarn_find_correction_range``
+    and ``yarn_linear_ramp_mask``)."""
+    base = np.arange(0, head_dim, 2, dtype=np.float32) / head_dim
+    extra = 1.0 / (theta**base)
+    if scaling is None:
+        return jnp.asarray(extra, jnp.float32)
+    s = dict(scaling)
+    factor, orig = s["factor"], s["original_max_position_embeddings"]
+
+    def dim(rotations):
+        return (head_dim * math.log(orig / (rotations * 2 * math.pi))) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(dim(s["beta_fast"])), 0)
+    high = min(math.ceil(dim(s["beta_slow"])), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip(
+        (np.arange(head_dim // 2, dtype=np.float32) - low) / (high - low), 0, 1
+    )
+    keep = 1.0 - ramp
+    inter = 1.0 / (factor * theta**base)
+    return jnp.asarray(inter * (1 - keep) + extra * keep, jnp.float32)
+
+
+def rope_attention_factor(scaling) -> float:
+    """Factor YaRN puts on the softmax scale (DeepSeek-V2 multiplies its
+    ``q_head_dim ** -0.5`` by ``mscale(factor, mscale_all_dim) ** 2``), and
+    1 without scaling.  The rotary cos/sin factor, ``mscale(factor,
+    mscale) / mscale(factor, mscale_all_dim)``, must be 1: it is when the
+    two published values are equal, and any other value is refused."""
+    if scaling is None:
+        return 1.0
+    s = dict(scaling)
+    m_all = _yarn_mscale(s["factor"], s.get("mscale_all_dim", 0))
+    if _yarn_mscale(s["factor"], s.get("mscale", 1)) != m_all:
+        raise ValueError(f"YaRN with a rotary cos/sin factor other than 1: {s}")
+    return m_all * m_all
+
+
+def apply_rope(
+    x: jax.Array, positions: jax.Array, theta: float, inv_freq=None
+) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq).  Rotates the
+    two halves of each head; ``inv_freq`` overrides the plain frequencies
+    (YaRN, :func:`rope_inv_freq`)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)
+    freqs = rope_freqs(hd, theta) if inv_freq is None else inv_freq
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]

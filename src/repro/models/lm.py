@@ -52,10 +52,9 @@ def _n_cross(cfg: ModelConfig) -> int:
 
 
 def _n_self(cfg: ModelConfig) -> int:
-    n = cfg.num_layers - _n_cross(cfg)
-    if cfg.mla and cfg.num_experts:  # deepseek: first layer has dense FFN
-        n -= 1
-    return n
+    """Scanned self-attention layers: all but the cross-attention layers
+    and the leading dense-FFN layer (DeepSeek's first)."""
+    return cfg.num_layers - _n_cross(cfg) - cfg.first_dense_layers
 
 
 def lm_def(cfg: ModelConfig) -> Dict[str, Any]:
@@ -64,7 +63,7 @@ def lm_def(cfg: ModelConfig) -> Dict[str, Any]:
         "layers": stack_defs(block_def(cfg, "self"), _n_self(cfg)),
         "final_norm": cm.rmsnorm_def(cfg.d_model),
     }
-    if cfg.mla and cfg.num_experts:
+    if cfg.first_dense_layers:
         defs["first_block"] = block_def(cfg, "dense_ffn")
     if cfg.cross_attn_every:
         defs["cross"] = stack_defs(block_def(cfg, "cross"), _n_cross(cfg))
@@ -142,7 +141,7 @@ def lm_logits(params, tokens, cfg: ModelConfig, vision: Optional[jax.Array] = No
     aux = jnp.zeros((), jnp.float32)
 
     base = 0
-    if cfg.mla and cfg.num_experts:
+    if cfg.first_dense_layers:
         x, a = self_block(params["first_block"], x, cfg, positions, layer=0)
         aux += a
         base = 1
@@ -248,7 +247,7 @@ def lm_prefill(
     caches = {}
 
     base = 0
-    if cfg.mla and cfg.num_experts:
+    if cfg.first_dense_layers:
         x, c0 = _layer_prefill(
             params["first_block"], x, cfg, positions, max_seq, layer=0
         )
@@ -312,7 +311,7 @@ def lm_decode(params, token: jax.Array, caches, cfg: ModelConfig):
     x = cm.embed(params["embed"], token, cfg)
 
     base = 0
-    if cfg.mla and cfg.num_experts:
+    if cfg.first_dense_layers:
         x, c0 = _layer_decode(
             params["first_block"], x, caches["first"], pos, cfg, layer=0
         )
@@ -385,13 +384,27 @@ def lm_decode(params, token: jax.Array, caches, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 # Serving over the paged pool (repro.serving): chunked prefill, paged decode
 # ---------------------------------------------------------------------------
-def _check_paged_support(cfg: ModelConfig) -> None:
-    if cfg.mla or cfg.cross_attn_every:
+def paged_pool_def(cfg: ModelConfig, num_blocks: int, block_size: int, dtype):
+    """``(shape, axes, dtype)`` leaves of the paged pool: one cache leaf
+    per kind of row, every attention layer stacked in front of
+    ``(num_blocks, block_size, ...)``.  GQA pools hold K/V of the scanned
+    layers; MLA pools hold the latent ``c_kv`` and ``k_rope`` of every
+    layer, the leading dense one first.  Cross-attention memory has no
+    paged form."""
+    if cfg.cross_attn_every or (cfg.first_dense_layers and not cfg.mla):
         raise ValueError(
-            "paged serving covers the GQA self-attention stack only "
-            "(no MLA latent caches / vision cross-attention); serve these "
-            "families through the legacy fixed-slot engine"
+            "the paged pool holds the caches of a GQA stack or of an MLA stack "
+            "with its leading dense layer; serve cross-attention families "
+            "through runtime.serve.LegacyEngine"
         )
+    if cfg.mla:
+        layer = attn.mla_cache_def(cfg, num_blocks, block_size, dtype)
+    else:
+        layer = attn.gqa_cache_def(cfg, num_blocks, block_size, dtype)
+    n = _n_self(cfg) + (cfg.first_dense_layers if cfg.mla else 0)
+    return {
+        k: ((n,) + shape, (None,) + axes, dt) for k, (shape, axes, dt) in layer.items()
+    }
 
 
 def _paged_head(params, x, cfg: ModelConfig):
@@ -399,6 +412,48 @@ def _paged_head(params, x, cfg: ModelConfig):
     if cfg.tie_embeddings:
         return cm.unembed(params["embed"], x, cfg)
     return cm.dense(params["lm_head"], x, cfg, site="lm_head")
+
+
+def _paged_ffn(p, x, cfg: ModelConfig, idx):
+    """The FFN half of a paged block: ``(output, counts)``, counts the
+    MoE layer's (expert rows, expert rows computed), ``None`` when dense."""
+    h = cm.rmsnorm(p["ln2"], x, cfg.norm_eps)
+    if "router" in p["ffn"]:
+        return ffn.moe_layer(p["ffn"], h, cfg, layer=idx)
+    return ffn.mlp(p["ffn"], h, cfg, layer=idx), None
+
+
+def _latent_stack(params, x, pool, cfg: ModelConfig, attend):
+    """Run the MLA stack over the paged latent pool: the leading dense
+    layer (pool layer 0), then one scan over the rest.  The pool rides the
+    scan's carry, each layer reading and writing its own rows in place.
+    ``attend(p, h, pool, li)`` is the attention half.  Returns ``(x, pool,
+    counts)``, counts summed over the MoE layers (``None`` without any)."""
+
+    def block(p, x, pool, li):
+        h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        a, pool = attend(p["attn"], h, pool, li)
+        f, c = _paged_ffn(p, x + a, cfg, li)
+        return x + a + f, pool, jnp.zeros((2,), jnp.int32) if c is None else c
+
+    counts = jnp.zeros((2,), jnp.int32)
+    if cfg.first_dense_layers:
+        x, pool, counts = block(params["first_block"], x, pool, jnp.int32(0))
+
+    def body(carry, inp):
+        x, pool, counts = carry
+        p, li = inp
+        x, pool, c = block(p, x, pool, li)
+        return (x, pool, counts + c), None
+
+    n = _stack_len(params["layers"])
+    idx = cfg.first_dense_layers + jnp.arange(n, dtype=jnp.int32)
+    (x, pool, counts), _ = jax.lax.scan(body, (x, pool, counts), (params["layers"], idx))
+    return x, pool, counts if cfg.num_experts else None
+
+
+def _sum_counts(counts):
+    return None if counts is None else counts.sum(axis=0)
 
 
 def lm_prefill_chunk(
@@ -414,35 +469,42 @@ def lm_prefill_chunk(
     with_logits: bool,
 ):
     """One chunked-prefill step: run chunk tokens ``[t0, t0 + tc)`` of a
-    single prompt, scattering each layer's K/V into the paged pool.  Only
-    the prompt-final chunk pays for the LM head (``with_logits``); earlier
-    chunks return ``None`` logits.  Returns ``(logits, kv_pool)``."""
-    _check_paged_support(cfg)
+    single prompt, scattering each layer's K/V (or MLA latents) into the
+    paged pool.  Only the prompt-final chunk pays for the LM head
+    (``with_logits``); earlier chunks return ``None`` logits.  Returns
+    ``(logits, kv_pool, counts)``: ``counts`` int32 (expert rows, expert
+    rows computed) over the MoE layers, ``None`` for a dense stack."""
     tc = tokens.shape[1]
     x = cm.embed(params["embed"], tokens, cfg)
     positions = t0 + jnp.arange(tc, dtype=jnp.int32)
 
-    def body(x, inp):
-        p, pc, idx = inp
-        h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        a, pc = attn.gqa_prefill_chunk(
-            p["attn"], h, pc, block_table, t0, cfg,
-            t_full=t_full, block_size=block_size, positions=positions, layer=idx,
-        )
-        x = x + a
-        h = cm.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        if "router" in p["ffn"]:
-            f, _ = ffn.moe(p["ffn"], h, cfg, layer=idx)
-        else:
-            f = ffn.mlp(p["ffn"], h, cfg, layer=idx)
-        return cm.with_logical(x + f, ("batch", "seq_sp", None)), pc
+    if cfg.mla:
+        def attend(p, h, pool, li):
+            return attn.mla_prefill_chunk(
+                p, h, pool, li, block_table, t0, cfg,
+                t_full=t_full, block_size=block_size, positions=positions, layer=li,
+            )
 
-    n = _stack_len(params["layers"])
-    x, kv_pool = jax.lax.scan(
-        body, x, (params["layers"], kv_pool, jnp.arange(n))
-    )
+        x, kv_pool, counts = _latent_stack(params, x, kv_pool, cfg, attend)
+    else:
+        def body(x, inp):
+            p, pc, idx = inp
+            h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
+            a, pc = attn.gqa_prefill_chunk(
+                p["attn"], h, pc, block_table, t0, cfg,
+                t_full=t_full, block_size=block_size, positions=positions, layer=idx,
+            )
+            x = x + a
+            f, c = _paged_ffn(p, x, cfg, idx)
+            return cm.with_logical(x + f, ("batch", "seq_sp", None)), (pc, c)
+
+        n = _stack_len(params["layers"])
+        x, (kv_pool, counts) = jax.lax.scan(
+            body, x, (params["layers"], kv_pool, jnp.arange(n))
+        )
+        counts = _sum_counts(counts)
     logits = _paged_head(params, x[:, -1:, :], cfg) if with_logits else None
-    return logits, kv_pool
+    return logits, kv_pool, counts
 
 
 def lm_decode_paged(
@@ -462,33 +524,39 @@ def lm_decode_paged(
     the continuous-batching decode: rows mid-prefill or without a live
     request redirect their K/V write to a private trash block and their
     (discarded) output attends only to the zero null block.  Returns
-    ``(logits (B, 1, V), kv_pool)``."""
-    _check_paged_support(cfg)
+    ``(logits (B, 1, V), kv_pool, counts)`` (counts as in
+    :func:`lm_prefill_chunk`)."""
     from repro.serving import kv_cache as kvc
 
     blocks, offsets = kvc.token_dest(block_table, pos, active, trash_blocks, block_size)
     x = cm.embed(params["embed"], token, cfg)
 
-    def body(x, inp):
-        p, pc, idx = inp
-        h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        a, pc = attn.gqa_decode_paged(
-            p["attn"], h, pc, block_table, pos, blocks, offsets, cfg,
-            gather_len=gather_len, layer=idx,
-        )
-        x = x + a
-        h = cm.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        if "router" in p["ffn"]:
-            f, _ = ffn.moe(p["ffn"], h, cfg, layer=idx)
-        else:
-            f = ffn.mlp(p["ffn"], h, cfg, layer=idx)
-        return x + f, pc
+    if cfg.mla:
+        def attend(p, h, pool, li):
+            return attn.mla_decode_paged(
+                p, h, pool, li, block_table, pos, blocks, offsets, cfg,
+                gather_len=gather_len, layer=li,
+            )
 
-    n = _stack_len(params["layers"])
-    x, kv_pool = jax.lax.scan(
-        body, x, (params["layers"], kv_pool, jnp.arange(n))
-    )
-    return _paged_head(params, x, cfg), kv_pool
+        x, kv_pool, counts = _latent_stack(params, x, kv_pool, cfg, attend)
+    else:
+        def body(x, inp):
+            p, pc, idx = inp
+            h = cm.rmsnorm(p["ln1"], x, cfg.norm_eps)
+            a, pc = attn.gqa_decode_paged(
+                p["attn"], h, pc, block_table, pos, blocks, offsets, cfg,
+                gather_len=gather_len, layer=idx,
+            )
+            x = x + a
+            f, c = _paged_ffn(p, x, cfg, idx)
+            return x + f, (pc, c)
+
+        n = _stack_len(params["layers"])
+        x, (kv_pool, counts) = jax.lax.scan(
+            body, x, (params["layers"], kv_pool, jnp.arange(n))
+        )
+        counts = _sum_counts(counts)
+    return _paged_head(params, x, cfg), kv_pool, counts
 
 
 # ---------------------------------------------------------------------------
@@ -508,7 +576,7 @@ def lm_cache_def(cfg: ModelConfig, batch: int, max_seq: int, dtype) -> Dict[str,
         },
         "pos": ((), (), jnp.int32),
     }
-    if cfg.mla and cfg.num_experts:
+    if cfg.first_dense_layers:
         out["first"] = layer_cache
     if cfg.cross_attn_every:
         n_cross = _n_cross(cfg)

@@ -92,7 +92,7 @@ class Arch:
                 ("zero", {**u, "num_layers": 0}, 1.0 - g),
                 ("grp1", {**u, "num_layers": cfg.attn_every}, float(g)),
             ]
-        if cfg.mla and cfg.num_experts:  # deepseek-v2: unscanned first block
+        if cfg.first_dense_layers:  # deepseek-v2: unscanned first block
             return [
                 ("l1", {**u, "num_layers": 1}, 2.0 - L),
                 ("l2", {**u, "num_layers": 2}, L - 1.0),

@@ -62,6 +62,7 @@ from repro.kernels.photonic_gemm.epilogue import (
 )
 from repro.kernels.photonic_gemm.kernel import (
     photonic_gemm_fused_pallas,
+    photonic_gemm_grouped_pallas,
     photonic_gemm_pallas,
 )
 from repro.kernels.photonic_gemm.ref import exact_int_gemm, photonic_gemm_ref
@@ -675,6 +676,93 @@ class PhotonicEngine:
         meta = (eng, site, packed.k, packed.c, packed.tiling, spec)
         return _packed_matmul(meta, x, packed.wq, packed.w_scale, bias, fold, prng_key)
 
+    def grouped_matmul(
+        self,
+        x: jax.Array,  # (M, K) float rows, grouped by bank in tile_r-row tiles
+        w,  # PackedDense with a leading bank axis, or float (E, K, C)
+        tile_expert: jax.Array,  # (M // tile_r,) int32: each row tile's bank
+        n_tiles: jax.Array,  # () int32: row tiles in use
+        *,
+        tile_r: int,
+        site: Optional[str] = None,
+    ) -> jax.Array:
+        """Float GEMM of each row tile against its own weight bank: the
+        held experts of a mixture-of-experts layer in one call (a grouped
+        GEMM).  Row tile ``i`` runs the DPU datapath against bank
+        ``tile_expert[i]``; the activation is quantized per tensor, each
+        bank per column, as in :meth:`matmul`.  Only the first ``n_tiles``
+        row tiles are defined in the result; the caller masks the rest.
+
+        Float weights quantize per call, bitwise as their prepack would,
+        with the straight-through gradient of the digital product.
+        Non-routed sites run the digital product.  Noisy channels and
+        bit-sliced execution have no grouped path (``NotImplementedError``).
+        """
+        from repro.photonic.packing import PackedDense
+
+        if not self.routes(site):
+            wf = w.dequant() if isinstance(w, PackedDense) else w
+            return grouped_digital(x, wf, tile_expert, n_tiles, tile_r)
+        ch = self.dpu.effective_channel()
+        if self.slicing is not None or (
+            ch is not None and ch.analog and ch.detector_sigma_lsb > 0.0
+        ):
+            raise NotImplementedError(
+                "grouped GEMMs run unsliced, on channels without detector noise"
+            )
+        if isinstance(w, PackedDense):
+            return self._grouped_int(x, w, tile_expert, n_tiles, tile_r)
+        return _grouped_float((self, tile_r), x, w, tile_expert, n_tiles)
+
+    def _grouped_int(self, x, packed, tile_expert, n_tiles, tile_r):
+        """The grouped forward over a packed bank stack, f32 (M, C)."""
+        cfg = self.dpu
+        k, c = packed.k, packed.c
+        xr = x.astype(jnp.float32)
+        sx = quant_scale(xr, cfg.operand_bits)
+        if self.backend == "pallas":
+            ch = cfg.effective_channel()
+            analog = ch is not None and ch.analog
+            tiling = packed.tiling or pallas_tiling(cfg, k, c)
+            wq = packed.wq
+            if packed.tiling is None:
+                from repro.photonic.packing import _kernel_layout
+
+                wq = _kernel_layout(wq, k, tiling)
+            n_chunk, tile_k, tile_c = tiling
+            cp = wq.shape[-1]
+            out = photonic_gemm_grouped_pallas(
+                to_chunk_slots(xr, k, tiling, axis=1),
+                wq,
+                sx,
+                jnp.pad(packed.w_scale.astype(jnp.float32), ((0, 0), (0, cp - c))),
+                tile_expert,
+                n_tiles,
+                operand_bits=cfg.operand_bits,
+                slice_bits=cfg.bits,
+                num_slices=cfg.num_slices,
+                chunk_lanes=chunk_lanes(n_chunk),
+                adc_bits=ch.adc_bits if ch is not None else cfg.adc_bits,
+                filter_alpha=ch.filter_alpha if analog else 0.0,
+                intermod_eps=ch.intermod_eps if analog else 0.0,
+                crossweight_eps=ch.crossweight_eps if analog else 0.0,
+                tile_r=tile_r,
+                tile_c=tile_c,
+                tile_k=tile_k,
+                interpret=pallas_interpret(),
+            )
+            return out[:, :c]
+        # ref / exact: every bank over every row, each row keeping its own.
+        xq = quantize_with_scale(xr, sx, cfg.operand_bits)
+        wq = packed.logical_wq()
+        row_bank = jnp.repeat(tile_expert, tile_r)[:, None]
+        acc = jnp.zeros((xr.shape[0], c), jnp.int32)
+        for e in range(wq.shape[0]):
+            part = self.int_gemm(xq, wq[e], slicing="none")
+            acc = jnp.where(row_bank == e, part, acc)
+        ws = packed.w_scale.astype(jnp.float32)[row_bank[:, 0]]
+        return apply_epilogue(acc, sx, ws, None, EpilogueSpec())
+
 
 @functools.lru_cache(maxsize=None)
 def _plane_engine(engine: PhotonicEngine, mode: SlicingSpec) -> PhotonicEngine:
@@ -870,3 +958,51 @@ def _packed_bwd(meta, res, g):
 
 
 _packed_matmul.defvjp(_packed_fwd, _packed_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Grouped GEMMs (held experts): digital product and photonic entry points
+# ---------------------------------------------------------------------------
+def grouped_digital(x, w, tile_expert, n_tiles, tile_r: int) -> jax.Array:
+    """``x`` row tile ``i`` times float bank ``w[tile_expert[i]]`` for the
+    first ``n_tiles`` tiles, zero rows after them (``lax.ragged_dot``)."""
+    e = w.shape[0]
+    used = jnp.arange(tile_expert.shape[0]) < n_tiles
+    sizes = tile_r * jnp.sum(
+        (tile_expert[None, :] == jnp.arange(e)[:, None]) & used[None, :], axis=1
+    )
+    return jax.lax.ragged_dot(x, w.astype(x.dtype), sizes.astype(jnp.int32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _grouped_float(meta, x, w, tile_expert, n_tiles):
+    from repro.photonic.packing import PackedDense
+
+    eng, tile_r = meta
+    wq, s = quantize_symmetric(w, eng.dpu.operand_bits, axis=-2)
+    packed = PackedDense(wq, jnp.squeeze(s, axis=-2), w.shape[-2], w.shape[-1])
+    return eng._grouped_int(x, packed, tile_expert, n_tiles, tile_r)
+
+
+def _grouped_float_fwd(meta, x, w, tile_expert, n_tiles):
+    y = _grouped_float(meta, x, w, tile_expert, n_tiles)
+    return y, (x, w, tile_expert, n_tiles)
+
+
+def _grouped_float_bwd(meta, res, g):
+    x, w, tile_expert, n_tiles = res
+    _, vjp = jax.vjp(
+        lambda a, b: grouped_digital(a, b, tile_expert, n_tiles, meta[1]),
+        x.astype(g.dtype),
+        w.astype(g.dtype),
+    )
+    dx, dw = vjp(g)
+    return (
+        dx.astype(x.dtype),
+        dw.astype(w.dtype),
+        key_zero_cotangent(tile_expert),
+        key_zero_cotangent(n_tiles),
+    )
+
+
+_grouped_float.defvjp(_grouped_float_fwd, _grouped_float_bwd)

@@ -212,8 +212,16 @@ def pack_dense(
         scale = params["w_scale"].astype(jnp.float32)
     else:
         # No dtype cast: bitwise-identical to the per-call
-        # quantize_symmetric(w, operand_bits, axis=0) it replaces.
-        wq, s = quantize_symmetric(w, engine.dpu.operand_bits, axis=-2)
+        # quantize_symmetric(w, operand_bits, axis=0) it replaces.  A
+        # stacked weight quantizes one leading slice at a time (the scale
+        # reduces over K only, so the bits are the same), which bounds the
+        # float temporaries by a slice: a layer's experts, not the stack.
+        parts = [
+            quantize_symmetric(wi, engine.dpu.operand_bits, axis=-2)
+            for wi in (w if w.ndim > 2 else [w])
+        ]
+        wq = jnp.stack([q for q, _ in parts]) if w.ndim > 2 else parts[0][0]
+        s = jnp.stack([sc for _, sc in parts]) if w.ndim > 2 else parts[0][1]
         scale = jnp.squeeze(s, axis=-2)
     k, c = wq.shape[-2], wq.shape[-1]
     if shards > 1 and k % shards:

@@ -1,11 +1,12 @@
 """Serving entry point (compatibility wrapper over ``repro.serving``).
 
 ``Engine`` keeps the original constructor/run surface but routes the dense
-GQA LM families onto the paged-KV continuous-batching scheduler
-(``repro.serving.Scheduler``, DESIGN.md §13): block-granular KV memory,
-chunked prefill interleaved with batched decode, and per-request sampling
-streams.  Families the paged path does not cover (MLA latent caches,
-vision cross-attention, SSM/hybrid/audio) fall back to ``LegacyEngine`` —
+and MoE LM families (GQA, or MLA over a latent pool) onto the paged-KV
+continuous-batching scheduler (``repro.serving.Scheduler``, DESIGN.md
+§13): block-granular KV memory, chunked prefill interleaved with batched
+decode, and per-request sampling streams.  Families the paged path does
+not cover (vision cross-attention, SSM/hybrid/audio) fall back to
+``LegacyEngine`` —
 the original fixed-slot loop, kept verbatim as the baseline the serving
 tests and the ``serve_latency`` benchmark compare against.
 
@@ -67,8 +68,7 @@ class Engine:
         self.model_cfg = model_cfg
         self.cfg = cfg
         paged = (
-            getattr(arch, "family", None) == "dense"
-            and not model_cfg.mla
+            getattr(arch, "family", None) in ("dense", "moe")
             and not model_cfg.cross_attn_every
         )
         if paged:

@@ -148,21 +148,23 @@ def chunk_dest(
 # Gather / scatter (the only code that indexes pool leaves)
 # ---------------------------------------------------------------------------
 def gather_kv(
-    kv_pool: Dict[str, jax.Array],  # per-layer: leaves (num_blocks, bs, ...)
+    kv_pool: Dict[str, jax.Array],  # per-layer leaves (num_blocks, bs, ...)
     block_table: jax.Array,  # (B, W) int32
     length: int,
+    layer: Optional[jax.Array] = None,
 ) -> Dict[str, jax.Array]:
     """Contiguous ``(B, length, ...)`` view of the first ``length`` cache
     rows of each request, read through the block table.  Unallocated table
     entries point at the null block, so rows past a request's allocation
-    read as exact zeros."""
+    read as exact zeros.  With ``layer`` the leaves are the whole stacked
+    pool ``(layers, num_blocks, bs, ...)`` and that layer is read."""
 
     def leaf(p):
-        bs = p.shape[1]
+        bs = p.shape[1] if layer is None else p.shape[2]
         n = -(-length // bs)
         t = block_table[:, :n]
-        out = p[t]  # (B, n, bs, ...)
-        out = out.reshape((t.shape[0], n * bs) + p.shape[2:])
+        out = p[t] if layer is None else p[layer, t]  # (B, n, bs, ...)
+        out = out.reshape((t.shape[0], n * bs) + out.shape[3:])
         return out[:, :length]
 
     return jax.tree.map(leaf, kv_pool)
@@ -173,12 +175,18 @@ def scatter_kv(
     blocks: jax.Array,  # (N,) int32
     offsets: jax.Array,  # (N,) int32
     rows: Dict[str, jax.Array],  # per-layer: leaves (N, ...)
+    layer: Optional[jax.Array] = None,
 ) -> Dict[str, jax.Array]:
-    """Write N rows at ``(blocks[i], offsets[i])`` in every leaf.  Callers
-    guarantee destinations are distinct (distinct block tables per request;
+    """Write N rows at ``(blocks[i], offsets[i])`` in every leaf (of layer
+    ``layer`` of the whole stacked pool, when given).  Callers guarantee
+    destinations are distinct (distinct block tables per request;
     per-slot trash blocks), keeping the scatter order-free."""
+    if layer is None:
+        return jax.tree.map(
+            lambda p, r: p.at[blocks, offsets].set(r.astype(p.dtype)), kv_pool, rows
+        )
     return jax.tree.map(
-        lambda p, r: p.at[blocks, offsets].set(r.astype(p.dtype)), kv_pool, rows
+        lambda p, r: p.at[layer, blocks, offsets].set(r.astype(p.dtype)), kv_pool, rows
     )
 
 

@@ -26,7 +26,12 @@ Correctness contracts (tested in ``tests/test_serving.py``):
 
 Every step records host spans with their counts (``repro.serving.spans``):
 on the profiler's clock when one runs, and in ``spans.LOG`` always; the
-counts also add up in ``Scheduler.stats``.
+counts also add up in ``Scheduler.stats``.  A MoE program's counts
+(``expert_rows``: token-expert pairs routed to held experts, summed over
+the MoE layers; ``expert_rows_computed``: rows its grouped GEMMs computed,
+padding included) come back from the device beside its logits and join
+their ``serve.prefill`` / ``serve.decode`` span at the step's next
+transfer.
 
 Tensor parallel: pass the PR-4 ``mesh``/``tp_axis`` and every model call
 runs under ``repro.photonic.sharded.tensor_parallel`` with shard-local
@@ -93,9 +98,10 @@ def prepack_serving_params(arch, model_cfg, params, *, mesh=None, tp_axis="model
     if engine is None:
         return None, params
     pack_engine = engine
-    if getattr(model_cfg, "mla_absorb", False):
-        # Absorbed MLA decode consumes wuk/wuv as raw floats in its einsums
-        # (never through the quantizing dense path); keep them float.
+    if model_cfg.mla:
+        # Absorbed MLA decode (the paged path's, and the legacy one under
+        # ``mla_absorb``) consumes wuk/wuv as raw floats in its einsums;
+        # keep them float.  Prefill quantizes them per call instead.
         pol = dataclasses.replace(
             pack_engine.policy, exclude=pack_engine.policy.exclude + ("wuk", "wuv")
         )
@@ -137,11 +143,6 @@ class Scheduler:
     ):
         from repro.models import lm
 
-        if model_cfg.mla or model_cfg.cross_attn_every:
-            raise ValueError(
-                "paged serving covers the GQA self-attention LM stack; use "
-                "runtime.serve.LegacyEngine for MLA / cross-attention families"
-            )
         if cfg.max_seq % cfg.block_size:
             raise ValueError(
                 f"block_size={cfg.block_size} must divide max_seq={cfg.max_seq}"
@@ -173,11 +174,11 @@ class Scheduler:
         self.allocator = kvc.BlockAllocator(
             num_blocks, cfg.block_size, reserved=reserved
         )
-        pool_def = arch.cache_def(
-            model_cfg, num_blocks, cfg.block_size,
-            {"enc_seq": cfg.block_size}, model_cfg.compute_dtype,
+        self.kv_pool = kvc.init_pool(
+            lm.paged_pool_def(
+                model_cfg, num_blocks, cfg.block_size, model_cfg.compute_dtype
+            )
         )
-        self.kv_pool = kvc.init_pool(pool_def["layers"])
         self._trash = jnp.arange(1, b + 1, dtype=jnp.int32)
 
         self._table = np.full((b, table_width), kvc.NULL_BLOCK, np.int32)
@@ -191,7 +192,10 @@ class Scheduler:
             "prefills": 0, "blocks_zeroed": 0, "prefill_chunks": 0,
             "prefill_tokens": 0, "decode_steps": 0, "decode_rows": 0,
             "kv_rows_attended": 0, "kv_rows_gathered": 0, "completed": 0,
+            "expert_rows": 0, "expert_rows_computed": 0,
         }
+        # (span, device counts) of MoE programs not yet read on the host.
+        self._unread: List[tuple] = []
 
         self._base_key = jax.random.PRNGKey(cfg.seed)
         self._gather_len = cfg.max_seq  # KV rows each decode row gathers
@@ -209,8 +213,28 @@ class Scheduler:
             )
 
         self._decode_fn = decode_fn
-        self._decode = jax.jit(decode_fn)
-        self._prefill = jax.jit(prefill_fn, static_argnums=(5, 6))
+        # The latent pool rides the MLA programs' scan carry and is written
+        # in place: donated, the pool is never held twice.
+        donate = (2,) if model_cfg.mla else ()
+        self._decode_program = jax.jit(decode_fn, donate_argnums=donate)
+        self._prefill_program = jax.jit(
+            prefill_fn, static_argnums=(5, 6), donate_argnums=donate
+        )
+
+        # Each program returns (logits, pool, counts); callers get (logits,
+        # pool), and a MoE program's counts wait, still on the device, for
+        # the next transfer the step makes (``_fetch``).
+        def prefill(*args):
+            logits, pool, counts = self._prefill_program(*args)
+            self._hold(counts)
+            return logits, pool
+
+        def decode(*args):
+            logits, pool, counts = self._decode_program(*args)
+            self._hold(counts)
+            return logits, pool
+
+        self._prefill, self._decode = prefill, decode
         self._argmax = jax.jit(lambda rows: jnp.argmax(rows, axis=-1))
 
         def sample_fn(rows, uids, ns):
@@ -244,6 +268,25 @@ class Scheduler:
             jnp.asarray(np.asarray(uids, np.int32)),
             jnp.asarray(np.asarray(ns, np.int32)),
         )
+
+    def _hold(self, counts) -> None:
+        """Keep a MoE program's (expert rows, expert rows computed) with
+        the span it ran in (none in a warm-up outside any span)."""
+        span = self._spans.current()
+        if counts is not None and span is not None:
+            self._unread.append((span, counts))
+
+    def _fetch(self, picks) -> np.ndarray:
+        """``picks`` on the host, with the held counts in the same
+        transfer: the step waits for its last program here anyway, so the
+        counts cost no sync of their own."""
+        picks, counts = jax.device_get((picks, [c for _, c in self._unread]))
+        for (span, _), (rows, computed) in zip(self._unread, counts):
+            span.counts.update(expert_rows=int(rows), expert_rows_computed=int(computed))
+            self.stats["expert_rows"] += int(rows)
+            self.stats["expert_rows_computed"] += int(computed)
+        self._unread.clear()
+        return np.asarray(picks)
 
     def _emit(self, slot: int, tok: int, logits_row=None) -> None:
         s = self.slots[slot]
@@ -350,9 +393,7 @@ class Scheduler:
                 self._prefill_fifo.pop(0)
                 row = logits[:, -1, : self.model_cfg.vocab_size]
                 with self._spans.span("serve.wait", uid=uid):
-                    tok = int(
-                        np.asarray(self._pick(row, [uid], [len(s.req.output)]))[0]
-                    )
+                    tok = int(self._fetch(self._pick(row, [uid], [len(s.req.output)]))[0])
                 with self._spans.span("serve.emit", uid=uid, tokens=1):
                     self._pos[slot] = t_full
                     self._tokens[slot, 0] = tok
@@ -389,7 +430,7 @@ class Scheduler:
         uids = [self.slots[i].req.uid if active[i] else 0 for i in range(b)]
         ns = [len(self.slots[i].req.output) if active[i] else 0 for i in range(b)]
         with self._spans.span("serve.wait"):
-            toks = np.asarray(self._pick(rows, uids, ns))
+            toks = self._fetch(self._pick(rows, uids, ns))
         with self._spans.span("serve.emit", tokens=len(decoding)):
             for i in decoding:
                 tok = int(toks[i])

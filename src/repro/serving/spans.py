@@ -58,6 +58,13 @@ class Recorder:
         self._step: Step = []
         self._open: List[int] = []  # indices in ``_step`` of the open spans
 
+    def current(self):
+        """The innermost open span, or ``None`` outside every span.  Counts
+        the host learns only after a span has closed (read in a later
+        transfer) go into its ``counts`` here; they reach ``LOG`` and
+        ``Scheduler.stats``, not the profiler's already-written event."""
+        return self._step[self._open[-1]] if self._open else None
+
     @contextlib.contextmanager
     def span(self, name: str, **counts: int) -> Iterator[Callable[..., None]]:
         """Record ``name`` around the ``with`` body.  The body may add

@@ -510,6 +510,7 @@ def test_prepack_site_names_agree_with_call_time_sites(name):
 
     recorded = set()
     orig_float, orig_packed = PhotonicEngine.matmul_float, PhotonicEngine.matmul
+    orig_grouped = PhotonicEngine.grouped_matmul
 
     def rec_float(self, x, w, *, site=None, **kw):
         recorded.add(site)
@@ -519,8 +520,13 @@ def test_prepack_site_names_agree_with_call_time_sites(name):
         recorded.add(site)
         return orig_packed(self, x, packed, site=site, **kw)
 
+    def rec_grouped(self, x, w, *args, site=None, **kw):
+        recorded.add(site)
+        return orig_grouped(self, x, w, *args, site=site, **kw)
+
     PhotonicEngine.matmul_float = rec_float
     PhotonicEngine.matmul = rec_packed
+    PhotonicEngine.grouped_matmul = rec_grouped
     try:
         rng = np.random.default_rng(0)
         params = init_tree(arch.param_defs(cfg), jax.random.PRNGKey(0), cfg.param_dtype)
@@ -542,6 +548,7 @@ def test_prepack_site_names_agree_with_call_time_sites(name):
     finally:
         PhotonicEngine.matmul_float = orig_float
         PhotonicEngine.matmul = orig_packed
+        PhotonicEngine.grouped_matmul = orig_grouped
 
     recorded.discard(None)
     assert recorded == derived, (

@@ -372,11 +372,13 @@ class TestAdmission:
             sch.submit(Request(uid=0, prompt=np.zeros(9, np.int32), max_new_tokens=2))
 
     def test_scheduler_rejects_unsupported_families(self):
-        mla_arch = registry.get("deepseek-v2-lite-16b")
-        mla_cfg = dataclasses.replace(mla_arch.smoke_config, remat=False)
+        """Vision cross-attention has no paged cache: the pool refuses it
+        (MLA, which it used to refuse too, now has its latent pool)."""
+        vlm_arch = registry.get("llama-3.2-vision-90b")
+        vlm_cfg = dataclasses.replace(vlm_arch.smoke_config, remat=False)
         with pytest.raises(ValueError, match="LegacyEngine"):
             Scheduler(
-                mla_arch, mla_cfg, {}, ServingConfig(batch_size=1, max_seq=16)
+                vlm_arch, vlm_cfg, {}, ServingConfig(batch_size=1, max_seq=16)
             )
 
 
@@ -392,6 +394,8 @@ class TestEngineRouting:
         assert isinstance(eng.impl, Scheduler)
 
     def test_mla_family_falls_back_to_legacy(self):
+        """The MLA + MoE family no longer falls back: it routes to the paged
+        scheduler over its latent pool (only cross-attention stays legacy)."""
         arch = registry.get("deepseek-v2-lite-16b")
         cfg = dataclasses.replace(arch.smoke_config, remat=False)
         params = init_tree(
@@ -400,4 +404,4 @@ class TestEngineRouting:
         eng = serve.Engine(
             arch, cfg, params, serve.ServeConfig(batch_size=1, max_seq=16)
         )
-        assert isinstance(eng.impl, serve.LegacyEngine)
+        assert isinstance(eng.impl, Scheduler)

@@ -159,7 +159,7 @@ def test_traced_decode_names_the_attention_core_and_each_gemm_site():
     params = init_tree(ARCH.param_defs(cfg), jax.random.PRNGKey(1), cfg.param_dtype)
     sched = Scheduler(ARCH, cfg, params, SERVING)
     b = SERVING.batch_size
-    text = sched._decode.lower(
+    text = sched._decode_program.lower(
         sched.params, jnp.asarray(sched._tokens), sched.kv_pool,
         jnp.asarray(sched._table), jnp.asarray(sched._pos), jnp.ones((b,), bool),
     ).as_text(debug_info=True)
