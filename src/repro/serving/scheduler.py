@@ -31,7 +31,9 @@ counts also add up in ``Scheduler.stats``.  A MoE program's counts
 the MoE layers; ``expert_rows_computed``: rows its grouped GEMMs computed,
 padding included) come back from the device beside its logits and join
 their ``serve.prefill`` / ``serve.decode`` span at the step's next
-transfer.
+transfer.  Each ``serve.wait`` counts the ``logits_rows`` it brings to the
+host for ``ServingConfig.record_logits`` (none when that is off); they come
+in the pick's own transfer, so the emit loop indexes no device array.
 
 Tensor parallel: pass the PR-4 ``mesh``/``tp_axis`` and every model call
 runs under ``repro.photonic.sharded.tensor_parallel`` with shard-local
@@ -192,7 +194,7 @@ class Scheduler:
             "prefills": 0, "blocks_zeroed": 0, "prefill_chunks": 0,
             "prefill_tokens": 0, "decode_steps": 0, "decode_rows": 0,
             "kv_rows_attended": 0, "kv_rows_gathered": 0, "completed": 0,
-            "expert_rows": 0, "expert_rows_computed": 0,
+            "expert_rows": 0, "expert_rows_computed": 0, "logits_rows_fetched": 0,
         }
         # (span, device counts) of MoE programs not yet read on the host.
         self._unread: List[tuple] = []
@@ -276,26 +278,32 @@ class Scheduler:
         if counts is not None and span is not None:
             self._unread.append((span, counts))
 
-    def _fetch(self, picks) -> np.ndarray:
-        """``picks`` on the host, with the held counts in the same
+    def _fetch(self, picks, rows=None):
+        """``(picks, rows)`` on the host, with the held counts in the same
         transfer: the step waits for its last program here anyway, so the
-        counts cost no sync of their own."""
-        picks, counts = jax.device_get((picks, [c for _, c in self._unread]))
-        for (span, _), (rows, computed) in zip(self._unread, counts):
-            span.counts.update(expert_rows=int(rows), expert_rows_computed=int(computed))
-            self.stats["expert_rows"] += int(rows)
+        counts and the logits ``rows`` to record (``None``: none) cost no
+        sync of their own."""
+        picks, rows, counts = jax.device_get(
+            (picks, rows, [c for _, c in self._unread])
+        )
+        for (span, _), (n, computed) in zip(self._unread, counts):
+            span.counts.update(expert_rows=int(n), expert_rows_computed=int(computed))
+            self.stats["expert_rows"] += int(n)
             self.stats["expert_rows_computed"] += int(computed)
         self._unread.clear()
-        return np.asarray(picks)
+        return np.asarray(picks), rows
 
     def _emit(self, slot: int, tok: int, logits_row=None) -> None:
+        """Append ``tok`` to the slot's request and retire it when done.
+        ``logits_row`` is the host row recorded with ``record_logits``."""
         s = self.slots[slot]
         req = s.req
         req.output.append(tok)
         if req.t_first is None:
             req.t_first = self._clock()
         if self.cfg.record_logits and logits_row is not None:
-            req.logits.append(np.asarray(logits_row))
+            # A copy: the row is a view of the whole fetched batch.
+            req.logits.append(np.array(logits_row))
         done = len(req.output) >= req.max_new_tokens or (
             req.eos_id is not None and tok == req.eos_id
         )
@@ -392,13 +400,19 @@ class Scheduler:
             if final:
                 self._prefill_fifo.pop(0)
                 row = logits[:, -1, : self.model_cfg.vocab_size]
-                with self._spans.span("serve.wait", uid=uid):
-                    tok = int(self._fetch(self._pick(row, [uid], [len(s.req.output)]))[0])
+                record = self.cfg.record_logits
+                with self._spans.span("serve.wait", uid=uid, logits_rows=int(record)):
+                    picks, host_rows = self._fetch(
+                        self._pick(row, [uid], [len(s.req.output)]),
+                        row if record else None,
+                    )
+                tok = int(picks[0])
+                self.stats["logits_rows_fetched"] += int(record)
                 with self._spans.span("serve.emit", uid=uid, tokens=1):
                     self._pos[slot] = t_full
                     self._tokens[slot, 0] = tok
                     s.decoding = True
-                    self._emit(slot, tok, logits_row=row[0])
+                    self._emit(slot, tok, logits_row=host_rows[0] if record else None)
 
     def _decode_phase(self) -> None:
         decoding = [
@@ -429,14 +443,19 @@ class Scheduler:
         rows = logits[:, -1, : self.model_cfg.vocab_size]
         uids = [self.slots[i].req.uid if active[i] else 0 for i in range(b)]
         ns = [len(self.slots[i].req.output) if active[i] else 0 for i in range(b)]
-        with self._spans.span("serve.wait"):
-            toks = self._fetch(self._pick(rows, uids, ns))
+        record = self.cfg.record_logits
+        kept = len(decoding) if record else 0
+        with self._spans.span("serve.wait", logits_rows=kept):
+            toks, host_rows = self._fetch(
+                self._pick(rows, uids, ns), rows if record else None
+            )
+        self.stats["logits_rows_fetched"] += kept
         with self._spans.span("serve.emit", tokens=len(decoding)):
             for i in decoding:
                 tok = int(toks[i])
                 self._pos[i] += 1
                 self._tokens[i, 0] = tok
-                self._emit(i, tok, logits_row=rows[i])
+                self._emit(i, tok, logits_row=host_rows[i] if record else None)
 
     # -- one engine iteration ------------------------------------------------
     def step(self) -> None:

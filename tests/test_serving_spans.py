@@ -5,6 +5,10 @@ it; the counts on the spans are the program's own arguments (prompt tokens,
 the decode positions it passes, the rows it gathers); requests carry their
 admission time; and the traced decode program names the attention core and
 each GEMM site in its ``op_name`` metadata.
+
+The emit loop is host work: with ``record_logits`` off a step indexes one
+device array per pick, however many slots decode, and with it on the rows
+recorded are, bit for bit, the rows the program returned.
 """
 
 import collections
@@ -12,6 +16,7 @@ import dataclasses
 import re
 
 import jax
+import jax._src.array as jax_array
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -170,3 +175,105 @@ def test_traced_decode_names_the_attention_core_and_each_gemm_site():
     # The core holds the KV gather and the scores; it holds no GEMM site.
     assert any("attn.core" in n and "dot_general" in n for n in names)
     assert not any("attn.core" in n and set(n.split("/")) & sites for n in names)
+
+
+def _count_indexing(monkeypatch):
+    """Count ``__getitem__`` calls on device arrays from here on."""
+    calls = [0]
+    getitem = jax_array.ArrayImpl.__getitem__
+
+    def counted(self, idx):
+        calls[0] += 1
+        return getitem(self, idx)
+
+    monkeypatch.setattr(jax_array.ArrayImpl, "__getitem__", counted)
+    return calls
+
+
+@pytest.mark.parametrize("batch_size", [2, 4])
+def test_a_step_indexes_one_device_array_per_pick(batch_size, monkeypatch):
+    """With ``record_logits`` off, a step slices the logits once per pick
+    (each final prefill chunk, the decode) and takes no per-token slice:
+    a decode-only step indexes one device array at 2 live slots and at 4."""
+    params = init_tree(ARCH.param_defs(CFG), jax.random.PRNGKey(0), CFG.param_dtype)
+    scfg = dataclasses.replace(SERVING, batch_size=batch_size)
+    sched = Scheduler(ARCH, CFG, params, scfg)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, CFG.vocab_size, 3).astype(np.int32)] * batch_size
+
+    def requests():  # one wave: every slot prefills in the first step
+        return [Request(uid=10 + i, prompt=p, max_new_tokens=6)
+                for i, p in enumerate(prompts)]
+
+    sched.run(requests())  # compiles every program the measured run calls
+    calls = _count_indexing(monkeypatch)
+    for r in requests():
+        sched.submit(r)
+    per_step, decode_only, widest = [], [], 0
+    while sched.pending:
+        before = calls[0]
+        sched.step()
+        step = spans.LOG[-1]
+        names = [sp.name for sp in step]
+        waits = [sp for sp in step if sp.name == "serve.wait"]
+        assert all(sp.counts["logits_rows"] == 0 for sp in waits)
+        per_step.append((calls[0] - before, len(waits)))
+        if "serve.decode" in names and "serve.prefill" not in names:
+            decode_only.append(calls[0] - before)
+            rows = next(sp for sp in step if sp.name == "serve.decode").counts["rows"]
+            widest = max(widest, rows)
+    assert all(n == picks for n, picks in per_step)
+    assert decode_only and set(decode_only) == {1}
+    assert widest == batch_size  # every slot decoded in one of those steps
+    assert sched.stats["logits_rows_fetched"] == 0
+
+
+def _tiny(kind):
+    if kind == "dense":
+        return ARCH, CFG
+    ds = registry.get("deepseek-v2-lite-16b")
+    return ds, dataclasses.replace(
+        ds.smoke_config, remat=False, param_dtype=jnp.float32,
+        compute_dtype=jnp.float32,
+    )
+
+
+@pytest.mark.parametrize("kind", ["dense", "moe_mla"])
+def test_recorded_rows_are_the_programs_rows_bit_for_bit(kind):
+    """With ``record_logits`` on, each emitted token's recorded row is the
+    row the program returned for it, and the outputs are those of a run
+    with recording off."""
+    arch, cfg = _tiny(kind)
+    params = init_tree(arch.param_defs(cfg), jax.random.PRNGKey(2), cfg.param_dtype)
+    outputs = {}
+    for record in (True, False):
+        sched = Scheduler(
+            arch, cfg, params, dataclasses.replace(SERVING, record_logits=record)
+        )
+        returned = {}
+        pick = sched._pick
+
+        def pick_call(rows, uids, ns):
+            host = np.asarray(rows)
+            for row, uid, n in zip(host, uids, ns):
+                if uid:  # idle decode rows carry uid 0; requests start at 10
+                    returned[(uid, n)] = row.copy()
+            return pick(rows, uids, ns)
+
+        sched._pick = pick_call
+        reqs, kept = _requests(), 0
+        for r in reqs:
+            sched.submit(r)
+        while sched.pending:
+            sched.step()
+            waits = [sp for sp in spans.LOG[-1] if sp.name == "serve.wait"]
+            kept += sum(sp.counts["logits_rows"] for sp in waits)
+        outputs[record] = [r.output for r in reqs]
+        emitted = sum(len(r.output) for r in reqs)
+        assert kept == sched.stats["logits_rows_fetched"] == (emitted if record else 0)
+        for r in reqs:
+            assert len(r.logits) == (len(r.output) if record else 0)
+            for n, row in enumerate(r.logits):
+                want = returned[(r.uid, n)]
+                assert row.dtype == want.dtype and row.tobytes() == want.tobytes()
+    assert outputs[True] == outputs[False]
