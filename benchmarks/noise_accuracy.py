@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.dpu import DPUConfig, noise_sigma_from_snr, photonic_matmul
+from repro.core.dpu import DPUConfig, noise_sigma_from_snr
+from repro.photonic import engine_for
 
 from benchmarks.run import register_benchmark
 
@@ -67,8 +68,9 @@ def run(smoke=False):
             cfg = DPUConfig(
                 organization=org, bits=4, datarate_gs=dr, noise_sigma_lsb=sigma
             )
-            mm = lambda a, b: photonic_matmul(  # noqa: E731
-                a, b, cfg, prng_key=jax.random.PRNGKey(3)
+            eng = engine_for(cfg, "ref")
+            mm = lambda a, b: eng.matmul_float(  # noqa: E731
+                a, b, prng_key=jax.random.PRNGKey(3)
             )
             pred = jnp.argmax(forward(params, x, mm), -1)
             acc = float((pred == y).mean())

@@ -7,15 +7,16 @@ photonic GEMM accelerators but never connects its circuit-level analysis
 
 1. **CNN proxy** — a small im2col conv net (conv3x3 -> relu -> pool ->
    linear readout) on synthetic 10-class images, every GEMM routed through
-   ``photonic_matmul`` under each organization's ``ChannelModel`` at each N.
+   the engine's ``ref`` backend (``matmul_float``) under each
+   organization's ``ChannelModel`` at each N.
    Reports classification accuracy vs the float model.
 2. **CNN workload GEMM fidelity** — for each paper CNN workload
    (GoogleNet/ResNet50/MobileNetV2/ShuffleNetV2), the largest-MAC layer's
    GEMM is run through the channel and reported as SQNR [dB] vs the exact
    int8 GEMM.
-3. **LM config** — qwen2-0.5b (smoke config) served with photonic int8
-   weights under each organization's channel; reports top-1 logit agreement
-   with the float model.
+3. **LM config** — qwen2-0.5b (smoke config) served with prepacked int8
+   weights (``prepack_params``) under each organization's channel; reports
+   top-1 logit agreement with the float model.
 
 Expected structure (asserted): SMWA — the "hitless" organization with the
 smallest loss/penalty chain and no inter-modulation / cross-weight
@@ -30,11 +31,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cnn_workloads import WORKLOADS
-from repro.core.dpu import DPUConfig, photonic_matmul
-from repro.kernels.photonic_gemm.ops import photonic_gemm_int
+from repro.core.dpu import DPUConfig
 from repro.kernels.photonic_gemm.ref import exact_int_gemm
 from repro.noise import build_channel_model
 from repro.orgs import ORGANIZATIONS
+from repro.photonic import engine_for
 
 from benchmarks.run import register_benchmark
 
@@ -105,7 +106,7 @@ def cnn_proxy_accuracy(n_sweep, samples=512):
             cfg = DPUConfig(
                 organization=org, bits=4, dpe_size=n, channel=ch, noise_seed=7
             )
-            mm = lambda a, b: photonic_matmul(a, b, cfg)  # noqa: E731
+            mm = engine_for(cfg, "ref").matmul_float
             pred = jnp.argmax(_cnn_forward(params, imgs, mm), -1)
             table[(org, n)] = float((pred == labels).mean())
     return acc_float, table
@@ -139,7 +140,7 @@ def workload_gemm_sqnr(n_sweep, max_rows=32, max_cols=64, max_k=512):
                     organization=org, bits=4, dpe_size=n, channel=ch,
                     noise_seed=3,
                 )
-                noisy = np.asarray(photonic_gemm_int(xq, wq, cfg, backend="ref"))
+                noisy = np.asarray(engine_for(cfg, "ref").int_gemm(xq, wq))
                 out[(wname, layer.name, org, n)] = _sqnr_db(gold, noisy)
     return out
 
@@ -163,7 +164,8 @@ def _lm_setup(tokens=16, batch=2):
 
 def _lm_fidelity(setup, channel, seed, n, slicing=None):
     """(rel logit err, top-1 agreement) of photonic int8 serving vs float."""
-    from repro.models.common import quantize_params
+    from repro.models.common import engine_from_model_config
+    from repro.photonic import prepack_params
 
     arch, cfg, params, toks, tokens, ref_logits = setup
     dpu = DPUConfig(
@@ -177,10 +179,11 @@ def _lm_fidelity(setup, channel, seed, n, slicing=None):
         cfg,
         photonic=dpu,
         photonic_backend="ref",
-        photonic_scope="weights_int8",
         photonic_slicing=slicing,
     )
-    params_q = quantize_params(params, arch.param_defs(cfg_q))
+    params_q = prepack_params(
+        params, arch.param_defs(cfg_q), engine_from_model_config(cfg_q)
+    )
     logits, _ = arch.prefill(params_q, {"tokens": toks}, cfg_q, tokens)
     rel = float(jnp.linalg.norm(logits - ref_logits) / jnp.linalg.norm(ref_logits))
     top1 = float(

@@ -13,9 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import scalability as sc
-from repro.core.dpu import DPUConfig, noise_sigma_from_snr, photonic_matmul
-from repro.kernels.photonic_gemm.ops import photonic_gemm
+from repro.core.dpu import DPUConfig, noise_sigma_from_snr
 from repro.noise import build_channel_model
+from repro.photonic import engine_for, pack_dense
 
 
 def main():
@@ -40,15 +40,12 @@ def main():
         f"{cfg.num_slices} slices x {cfg.num_slices} = {cfg.passes} passes, "
         f"{cfg.num_chunks(256)} psum chunks for k=256"
     )
-    y = photonic_matmul(x, w, cfg)
+    y = engine_for(cfg, "ref").matmul_float(x, w)
     rel = float(jnp.linalg.norm(y - exact) / jnp.linalg.norm(exact))
     print(f"  ideal datapath rel-error vs float GEMM: {rel:.4f} (int8 quantization)")
 
-    y_pallas = photonic_gemm(x, w, cfg, "pallas")  # interpret mode on CPU
-    print(
-        f"  pallas kernel == ref: "
-        f"{bool(jnp.allclose(y_pallas, photonic_gemm(x, w, cfg, 'ref')))}"
-    )
+    y_pallas = engine_for(cfg, "pallas").matmul_float(x, w)  # interpret on CPU
+    print(f"  pallas kernel == ref: {bool(jnp.allclose(y_pallas, y))}")
 
     print("\n=== 3. analog noise at the scalability budget ===")
     for mult in (1.0, 4.0):
@@ -56,7 +53,9 @@ def main():
         ncfg = DPUConfig(
             organization="SMWA", bits=4, datarate_gs=5.0, noise_sigma_lsb=sigma
         )
-        yn = photonic_matmul(x, w, ncfg, prng_key=jax.random.PRNGKey(0))
+        yn = engine_for(ncfg, "ref").matmul_float(
+            x, w, prng_key=jax.random.PRNGKey(0)
+        )
         rel = float(jnp.linalg.norm(yn - exact) / jnp.linalg.norm(exact))
         print(
             f"  noise {mult:>3.0f}x budget (sigma={sigma:.1f} LSB): rel-error {rel:.4f}"
@@ -68,7 +67,7 @@ def main():
         ocfg = DPUConfig(
             organization=org, bits=4, dpe_size=17, channel=ch, noise_seed=0
         )
-        yo = photonic_matmul(x, w, ocfg)
+        yo = engine_for(ocfg, "ref").matmul_float(x, w)
         rel = float(jnp.linalg.norm(yo - exact) / jnp.linalg.norm(exact))
         print(
             f"  {org}: through-loss {ch.through_loss_db:.2f} dB, "
@@ -79,8 +78,6 @@ def main():
         )
 
     print("\n=== 5. the execution engine: prepacked weight-stationary GEMM ===")
-    from repro.photonic import engine_for, pack_dense  # noqa: E402
-
     eng = engine_for(cfg, "ref")
     print(f"  {eng.describe()}")
     packed = pack_dense({"w": w}, eng)["w"]

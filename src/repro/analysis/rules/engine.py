@@ -1,11 +1,11 @@
 """RPR003 — models/runtime route every GEMM through the engine surface.
 
 The sanctioned entry points are ``repro.models.common.dense(site=...)``,
-``engine.matmul``/``matmul_float``/``matmul_int`` and the
-``repro.photonic.sharded`` contexts. Direct calls into the kernel backends
-(Pallas kernel, reference int GEMM, the raw ops wrappers) from model or
-runtime code bypass routing policy, seed derivation, and prepacking — the
-exact machinery the PR-3/PR-4 results depend on.
+``engine.matmul``/``matmul_float`` and the ``repro.photonic.sharded``
+contexts. Direct calls into the kernel backends (Pallas kernel, reference
+int GEMM) from model or runtime code bypass routing policy, seed
+derivation, and prepacking — the exact machinery the PR-3/PR-4 results
+depend on.
 """
 
 from __future__ import annotations
@@ -21,8 +21,6 @@ _BACKEND_NAMES = frozenset(
         "photonic_gemm_pallas",
         "photonic_gemm_ref",
         "exact_int_gemm",
-        "photonic_gemm_int",
-        "photonic_gemm",
         "int_gemm",
         "psum_int_gemm",
         "_packed_matmul",

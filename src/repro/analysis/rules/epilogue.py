@@ -8,9 +8,8 @@ afterwards re-introduces the materialized intermediate the fusion
 removed — and silently double-applies the shoulder if the epilogue was
 also requested.  The blessed spelling is
 ``dense(..., epilogue=EpilogueSpec(...))`` /
-``engine.matmul(..., epilogue=Epilogue(spec, bias))`` (PR-9 unified
-surface; the legacy ``bias=``/``activation=`` keywords survive only as
-deprecation shims).
+``engine.matmul(..., epilogue=Epilogue(spec, bias))``, the one spelling
+of the GEMM surface.
 
 Only *engine* matmul results are tracked, by the receiver spelling:
 ``jnp.matmul`` / ``np.matmul`` and arithmetic on :func:`dense` outputs

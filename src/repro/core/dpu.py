@@ -30,16 +30,21 @@ either ``prng_key`` (same key => bitwise-identical result) or the
 no key can be threaded, e.g. the model serving stack).  A noisy call with
 neither raises ``ValueError`` rather than silently drawing fresh noise.
 
-This module is the pure-jnp oracle; ``repro.kernels.photonic_gemm`` provides
-the TPU Pallas kernel with identical semantics (fused slicing + chunked
-accumulation in VMEM).
+This module holds the DPU's configuration and its digital shoulders
+(quantization, bit-slicing, the straight-through gradient).  The datapath
+itself runs through :class:`repro.photonic.PhotonicEngine`: the pure-jnp
+oracle is its ``ref`` backend (``repro.kernels.photonic_gemm.ref``) and the
+TPU Pallas kernel its ``pallas`` backend, with identical semantics (fused
+slicing + chunked accumulation in VMEM)::
+
+    engine_for(cfg, "ref").int_gemm(xq, wq)     # int32 (R, C)
+    engine_for(cfg, "ref").matmul_float(x, w)   # float, STE-differentiable
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -48,17 +53,8 @@ import jax.numpy as jnp
 from repro import platforms as _platforms
 from repro.core import scalability
 from repro.core.params import PhotonicParams
-from repro.noise.channel import (
-    ChannelModel,
-    analog_pass_psums,
-    shard_local_channel,
-)
-from repro.noise.stages import (
-    data_tweak,
-    fold_seed,
-    key_zero_cotangent,
-    seed_from_key,
-)
+from repro.noise.channel import ChannelModel, shard_local_channel
+from repro.noise.stages import seed_from_key
 from repro.orgs import OrgSpec, resolve
 
 
@@ -301,125 +297,8 @@ def bit_slices(q: jax.Array, slice_bits: int, num_slices: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# The DPU integer GEMM (slice passes x psum chunks)
+# Straight-through gradient of the engine's float entry points
 # ---------------------------------------------------------------------------
-def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
-    size = x.shape[axis]
-    pad = (-size) % multiple
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
-
-
-def dpu_int_gemm(
-    xq: jax.Array,  # (R, K) int8 — quantized inputs
-    wq: jax.Array,  # (K, C) int8 — quantized weights
-    cfg: DPUConfig,
-    *,
-    prng_key: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Integer GEMM through the DPU datapath. Returns int32 (R, C).
-
-    Exactly equals ``xq.astype(i32) @ wq.astype(i32)`` when the effective
-    channel is ideal (no analog stages, no ADC saturation).  With an analog
-    channel, each slice-pair pass routes its per-chunk psums through the
-    full signal chain (:func:`repro.noise.analog_pass_psums`); the noise
-    stream derives from ``prng_key`` or ``cfg.noise_seed`` (same source =>
-    bitwise-identical output).
-    """
-    r, k = xq.shape
-    k2, c = wq.shape
-    assert k == k2, (xq.shape, wq.shape)
-    n = cfg.n
-    s = cfg.num_slices
-    channel = cfg.effective_channel()
-    analog = channel is not None and channel.analog
-    adc_bits = channel.adc_bits if channel is not None else cfg.adc_bits
-    seed = None
-    if analog and channel.detector_sigma_lsb > 0.0:
-        # Operand-content tweak decorrelates same-seed, same-shape calls
-        # (layers of one model / QAT steps) without losing determinism.
-        seed = data_tweak(cfg.noise_seed_array(prng_key, what="detector noise"), xq, wq)
-
-    # psum chunking of the contraction dimension (electronic reduction).
-    xq = _pad_to(xq, 1, n)
-    wq = _pad_to(wq, 0, n)
-    kp = xq.shape[1]
-    chunks = kp // n
-    x_c = xq.reshape(r, chunks, n)
-    w_c = wq.reshape(chunks, n, c)
-
-    x_sl = bit_slices(x_c, cfg.bits, s)      # (S, R, chunks, N)
-    w_sl = bit_slices(w_c, cfg.bits, s)      # (S, chunks, N, C)
-
-    out = jnp.zeros((r, c), jnp.int32)
-    for si in range(s):
-        for ti in range(s):
-            shift = cfg.bits * (si + ti)
-            if analog:
-                # Full signal chain: crosstalk -> filter -> detector noise
-                # -> ADC, one optical pass per slice pair.
-                pass_seed = fold_seed(
-                    seed if seed is not None else jnp.uint32(0), si * s + ti
-                )
-                psum = analog_pass_psums(x_sl[si], w_sl[ti], channel, pass_seed)
-            else:
-                # Exact integer route (ideal or ADC-saturation-only).
-                psum = jnp.einsum(
-                    "rgn,gnc->rgc",
-                    x_sl[si].astype(jnp.int32),
-                    w_sl[ti].astype(jnp.int32),
-                    preferred_element_type=jnp.int32,
-                )  # (R, chunks, C) — per-chunk psums, pre-ADC
-                if adc_bits is not None:
-                    lim = 2 ** (adc_bits - 1) - 1
-                    psum = jnp.clip(psum, -lim, lim)
-            out = out + (psum.sum(axis=1) << shift)
-    return out
-
-
-def photonic_matmul(
-    x: jax.Array,  # (..., K) float
-    w: jax.Array,  # (K, C) float
-    cfg: DPUConfig = DPUConfig(),
-    *,
-    prng_key: Optional[jax.Array] = None,
-    w_scale_axis: Optional[int] = 0,
-    channel: Optional[ChannelModel] = None,
-) -> jax.Array:
-    """Float-in / float-out GEMM executed through the photonic DPU model.
-
-    ``channel`` overrides ``cfg.channel`` for one call (convenient for
-    sweeping organizations / stage ablations over a fixed config).
-    """
-    if channel is not None:
-        cfg = dataclasses.replace(cfg, channel=channel)
-    lead = x.shape[:-1]
-    k = x.shape[-1]
-    xr = x.reshape(-1, k)
-    xq, sx = quantize_symmetric(xr, cfg.operand_bits)
-    wq, sw = quantize_symmetric(w, cfg.operand_bits, axis=w_scale_axis)
-    out = dpu_int_gemm(xq, wq, cfg, prng_key=prng_key)
-    y = out.astype(jnp.float32) * sx * sw  # sw broadcasts (1, C) per-channel
-    return y.reshape(*lead, w.shape[1]).astype(x.dtype)
-
-
-# ---------------------------------------------------------------------------
-# Straight-through estimator for training through the photonic path
-# ---------------------------------------------------------------------------
-@partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _photonic_matmul_ste(
-    x: jax.Array, w: jax.Array, cfg: DPUConfig, prng_key
-) -> jax.Array:
-    return photonic_matmul(x, w, cfg, prng_key=prng_key)
-
-
-def _ste_fwd(x, w, cfg, prng_key):
-    return photonic_matmul(x, w, cfg, prng_key=prng_key), (x, w, prng_key)
-
-
 def dense_vjp(x: jax.Array, w: jax.Array, g: jax.Array):
     """``(dx, dw)`` of ``x @ w`` at cotangent ``g``: the straight-through
     gradient, computed as the dense matmul's own VJP in ``g``'s dtype and
@@ -428,31 +307,6 @@ def dense_vjp(x: jax.Array, w: jax.Array, g: jax.Array):
     _, vjp = jax.vjp(lambda a, b: a @ b, x.astype(g.dtype), w.astype(g.dtype))
     dx, dw = vjp(g)
     return dx.astype(x.dtype), dw.astype(w.dtype)
-
-
-def _ste_bwd(cfg, res, g):
-    x, w, prng_key = res
-    dx, dw = dense_vjp(x, w, g)
-    return dx, dw, key_zero_cotangent(prng_key)
-
-
-_photonic_matmul_ste.defvjp(_ste_fwd, _ste_bwd)
-
-
-def photonic_matmul_ste(
-    x: jax.Array,
-    w: jax.Array,
-    cfg: DPUConfig,
-    prng_key: Optional[jax.Array] = None,
-) -> jax.Array:
-    """QAT-style forward through the (optionally noisy) photonic datapath;
-    backward is the straight-through dense-matmul gradient.
-
-    With ``cfg.channel`` set (or ``noise_sigma_lsb``), the forward pass sees
-    the organization's analog perturbations — pass ``prng_key`` (or set
-    ``cfg.noise_seed``) so the noise draw is explicit and reproducible.
-    """
-    return _photonic_matmul_ste(x, w, cfg, prng_key)
 
 
 # ---------------------------------------------------------------------------

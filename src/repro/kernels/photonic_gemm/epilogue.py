@@ -103,35 +103,20 @@ class Epilogue(NamedTuple):
     bias: Optional[jax.Array] = None
 
 
-def as_epilogue(
-    epilogue=None,
-    *,
-    bias: Optional[jax.Array] = None,
-    activation: Optional[str] = None,
-) -> "tuple[EpilogueSpec, Optional[jax.Array]]":
-    """Normalize the unified ``epilogue=`` surface to ``(spec, bias)``.
+def as_epilogue(epilogue=None) -> "tuple[EpilogueSpec, Optional[jax.Array]]":
+    """Normalize an ``epilogue=`` request to ``(spec, bias)`` — the one
+    resolution point of the GEMM surface:
 
-    The one resolution point for the GEMM surface's epilogue request:
+    * ``EpilogueSpec(...)`` — bias-free spec (``spec.bias`` must be
+      False: the spec alone carries no bias operand);
+    * ``Epilogue(spec, bias)`` — spec + bias operand;
+    * ``None`` — the no-epilogue spec.
 
-    * ``epilogue=EpilogueSpec(...)`` — bias-free spec (``spec.bias`` must
-      be False: the spec alone carries no bias operand);
-    * ``epilogue=Epilogue(spec, bias)`` — spec + bias operand;
-    * legacy ``bias=`` / ``activation=`` keywords (deprecation shims on
-      the engine surface) — folded into a spec exactly as the historical
-      call sites did, so shimmed calls stay bitwise-identical;
-    * nothing — the no-epilogue spec.
-
-    Mixing ``epilogue=`` with the legacy keywords raises ``TypeError``
-    eagerly (one spelling per call site; RPR008's blessed form is
-    ``epilogue=``).
+    Anything else, or a spec that disagrees with its bias operand,
+    raises ``TypeError`` eagerly.
     """
     if epilogue is None:
-        return EpilogueSpec(bias=bias is not None, activation=activation), bias
-    if bias is not None or activation is not None:
-        raise TypeError(
-            "pass either epilogue= or the legacy bias=/activation= "
-            "keywords, not both"
-        )
+        return EpilogueSpec(), None
     if isinstance(epilogue, Epilogue):
         spec, b = epilogue
         if not isinstance(spec, EpilogueSpec):

@@ -1,15 +1,17 @@
-"""Pure-jnp oracle for the photonic GEMM kernel.
+"""Pure-jnp oracle for the photonic GEMM kernel — the repo's one jnp
+implementation of the DPU datapath.
 
 Implements exactly the kernel's semantics — signed-magnitude bit-slicing,
 DPE-size (N) psum chunking with optional ADC saturation, shift-add recombine —
-with no Pallas, no tiling.  Used by tests as the gold reference and by the
-models as the portable fallback backend.
+with no Pallas, no tiling.  It is the ``ref`` backend of
+:class:`repro.photonic.PhotonicEngine` (``engine_for(cfg, "ref")``), the
+gold reference of the tests and the portable fallback of the models.
 
 With a :class:`repro.noise.ChannelModel` the oracle applies the full analog
-signal chain per slice-pair pass, using the same seed/stream derivation as
-``repro.core.dpu.dpu_int_gemm`` (the two are bitwise equal under noise); the
-Pallas kernel draws its noise from tile-local streams and agrees with the
-oracle *statistically* (mean/variance), not bitwise.
+signal chain per slice-pair pass, seeded by the engine's stream seed
+(:meth:`repro.photonic.PhotonicEngine.stream_seed`); the Pallas kernel draws
+its noise from tile-local streams and agrees with the oracle
+*statistically* (mean/variance), not bitwise.
 """
 
 from __future__ import annotations

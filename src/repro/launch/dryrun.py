@@ -440,7 +440,7 @@ def main():
     ap.add_argument(
         "--photonic-scope",
         default="weights",
-        choices=["none", "weights", "weights_int8"],
+        choices=["none", "weights"],
         help="which weight GEMMs the engine routes (with --photonic)",
     )
     ap.add_argument(

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import common as cm
-from repro.models.common import P, ModelConfig, apply_rope, dense, qdense_def
+from repro.models.common import P, ModelConfig, apply_rope, dense, dense_def
 
 # Device-side name (``named_scope``) of the paged attention core: the KV
 # scatter and gather, scores, softmax and P·V, up to ``wo``.
@@ -27,10 +27,10 @@ ATTN_CORE = "attn.core"
 def gqa_def(cfg: ModelConfig) -> Dict[str, Any]:
     d, h, kv, hd = cfg.d_model, cfg.n_q_heads, cfg.num_kv_heads, cfg.hd
     return {
-        "wq": qdense_def(cfg, d, h * hd, (None, "heads"), bias=cfg.qkv_bias),
-        "wk": qdense_def(cfg, d, kv * hd, (None, "kv_heads"), bias=cfg.qkv_bias),
-        "wv": qdense_def(cfg, d, kv * hd, (None, "kv_heads"), bias=cfg.qkv_bias),
-        "wo": qdense_def(cfg, h * hd, d, ("heads", None)),
+        "wq": dense_def(d, h * hd, (None, "heads"), bias=cfg.qkv_bias),
+        "wk": dense_def(d, kv * hd, (None, "kv_heads"), bias=cfg.qkv_bias),
+        "wv": dense_def(d, kv * hd, (None, "kv_heads"), bias=cfg.qkv_bias),
+        "wo": dense_def(h * hd, d, ("heads", None)),
     }
 
 
@@ -52,12 +52,12 @@ def mla_def(cfg: ModelConfig) -> Dict[str, Any]:
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     r = cfg.kv_lora_rank
     return {
-        "wq": qdense_def(cfg, d, h * (nope + rope), (None, "heads")),
-        "wdkv": qdense_def(cfg, d, r + rope, (None, None)),
+        "wq": dense_def(d, h * (nope + rope), (None, "heads")),
+        "wdkv": dense_def(d, r + rope, (None, None)),
         "kv_norm": cm.rmsnorm_def(r),
-        "wuk": qdense_def(cfg, r, h * nope, (None, "heads")),
-        "wuv": qdense_def(cfg, r, h * vd, (None, "heads")),
-        "wo": qdense_def(cfg, h * vd, d, ("heads", None)),
+        "wuk": dense_def(r, h * nope, (None, "heads")),
+        "wuv": dense_def(r, h * vd, (None, "heads")),
+        "wo": dense_def(h * vd, d, ("heads", None)),
     }
 
 
@@ -500,15 +500,11 @@ def cross_kv(params, memory: jax.Array, cfg: ModelConfig, layer=None):
 # ---------------------------------------------------------------------------
 def _mla_up_weight(p: Dict[str, Any]) -> jax.Array:
     """Float view of an MLA up-projection weight for the absorbed-decode
-    einsums (dequantizing a prepacked / int8-stored layout if needed)."""
+    einsums (dequantizing a prepacked weight if needed)."""
     from repro.photonic.packing import PackedDense
 
     w = p["w"]
-    if isinstance(w, PackedDense):
-        return w.dequant()
-    if "w_scale" in p:
-        return w.astype(jnp.float32) * p["w_scale"].astype(jnp.float32)[None, :]
-    return w
+    return w.dequant() if isinstance(w, PackedDense) else w
 
 
 def _mla_scale(cfg: ModelConfig) -> float:

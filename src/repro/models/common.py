@@ -98,14 +98,14 @@ class ModelConfig:
     logical_rules: Any = None    # per-arch sharding-rule overrides (dict)
     kv_cache_int8: bool = False  # int8 KV cache w/ per-token-head scales
     mla_absorb: bool = False     # MLA decode with absorbed up-projections
-    seq_shard_residual: bool = True  # sequence-parallel residual stream
     photonic: Optional[DPUConfig] = None
     photonic_backend: str = "ref"    # ref | pallas | exact
     # Which weights execute photonically (when `photonic` is set):
-    #   "none"         — photonic config carried but no GEMM routed;
-    #   "weights"      — float-stored weights, quantized per call (QAT/train);
-    #   "weights_int8" — int8-stored weights (photonic serving layout).
-    photonic_scope: str = "weights"  # none | weights | weights_int8
+    #   "none"    — photonic config carried but no GEMM routed;
+    #   "weights" — every routed GEMM: float weights quantize per call,
+    #               prepacked ones (repro.photonic.prepack_params) are the
+    #               int8 serving layout.
+    photonic_scope: str = "weights"  # none | weights
     # Per-site routing policy (repro.photonic.SitePolicy patterns, matched
     # against dotted site names like "ffn.router" and their last component).
     # The MoE router stays digital by default — expert-routing decisions are
@@ -123,7 +123,7 @@ class ModelConfig:
     padded_vocab: Optional[int] = None
 
     def __post_init__(self):
-        scopes = ("none", "weights", "weights_int8")
+        scopes = ("none", "weights")
         if self.photonic_scope not in scopes:
             raise ValueError(
                 f"photonic_scope={self.photonic_scope!r} is not one of {scopes}"
@@ -303,36 +303,11 @@ def dense_def(
     axes: Tuple[Optional[str], Optional[str]],
     bias: bool = False,
     init: str = "fan_in",
-    quantized: bool = False,
 ) -> Dict[str, P]:
-    if quantized:
-        # int8-stored weights + per-column dequant scale (photonic serving:
-        # the DPU weight banks hold B-bit slices of int8 weights — weights
-        # live in HBM at 1 byte, halving weight streaming traffic vs bf16).
-        d: Dict[str, P] = {
-            "w": P((d_in, d_out), axes, init=init, dtype="int8"),
-            "w_scale": P((d_out,), (axes[1],), init="ones", dtype="float32"),
-        }
-    else:
-        d = {"w": P((d_in, d_out), axes, init=init)}
+    d = {"w": P((d_in, d_out), axes, init=init)}
     if bias:
         d["b"] = P((d_out,), (axes[1],), init="zeros")
     return d
-
-
-def qdense_def(
-    cfg: ModelConfig,
-    d_in: int,
-    d_out: int,
-    axes: Tuple[Optional[str], Optional[str]],
-    bias: bool = False,
-    init: str = "fan_in",
-) -> Dict[str, P]:
-    """dense_def that stores int8 weights when ``photonic_scope`` is
-    ``"weights_int8"`` (accepted scopes: ``none | weights | weights_int8``,
-    validated by :class:`ModelConfig`)."""
-    quantized = cfg.photonic is not None and cfg.photonic_scope == "weights_int8"
-    return dense_def(d_in, d_out, axes, bias=bias, init=init, quantized=quantized)
 
 
 def engine_from_model_config(cfg: ModelConfig):
@@ -362,7 +337,6 @@ def dense(
     prng_key: Optional[jax.Array] = None,
     epilogue: Any = None,
     slicing: Any = None,
-    activation: Optional[str] = None,
 ) -> jax.Array:
     """Linear layer; routes through the photonic engine when enabled.
 
@@ -376,14 +350,13 @@ def dense(
 
     ``epilogue=`` takes a bias-free :class:`EpilogueSpec` — the bias
     operand always comes from the param def (``params["b"]``), so the
-    spec only selects the activation; the legacy ``activation=`` keyword
-    remains as a bitwise-identical shim.  Either way the bias and
-    activation are *not* applied here as separate ops: they ride the
-    engine's fused epilogue (DESIGN.md §14) so routed GEMMs never
-    materialize the unrescaled or pre-activation intermediate (RPR008
-    enforces this).  Digital fallbacks keep the historical op order
-    bit-for-bit.  ``slicing=`` overrides ``cfg.photonic_slicing`` for
-    this GEMM (bit-sliced execution, DESIGN.md §15).
+    spec only selects the activation.  The bias and activation are *not*
+    applied here as separate ops: they ride the engine's fused epilogue
+    (DESIGN.md §14) so routed GEMMs never materialize the unrescaled or
+    pre-activation intermediate (RPR008 enforces this).  Digital
+    fallbacks keep the historical op order bit-for-bit.  ``slicing=``
+    overrides ``cfg.photonic_slicing`` for this GEMM (bit-sliced
+    execution, DESIGN.md §15).
 
     Under an active tensor-parallel scope
     (``repro.photonic.sharded.tensor_parallel`` / ``manual_tp``) routed
@@ -394,12 +367,8 @@ def dense(
     from repro.photonic import Epilogue, EpilogueSpec
     from repro.photonic import sharded as tp
 
+    activation = None
     if epilogue is not None:
-        if activation is not None:
-            raise TypeError(
-                "pass either epilogue= or the legacy activation= keyword, "
-                "not both"
-            )
         if not isinstance(epilogue, EpilogueSpec):
             raise TypeError(
                 f"dense() takes a bias-free EpilogueSpec (the bias operand "
@@ -412,7 +381,6 @@ def dense(
             )
         activation = epilogue.activation
 
-    w = params["w"]
     bias = params.get("b")
     ep = Epilogue(EpilogueSpec(bias=bias is not None, activation=activation), bias)
     eng = engine_from_model_config(cfg)
@@ -420,9 +388,8 @@ def dense(
     with jax.named_scope(site) if site else contextlib.nullcontext():
         y = tp.maybe_tp_matmul(
             eng,
-            params,
+            params["w"],
             x,
-            cfg,
             site=site,
             fold=layer,
             prng_key=prng_key,
@@ -432,10 +399,8 @@ def dense(
         if y is None:
             y = _single_device_matmul(
                 eng,
-                params,
-                w,
+                params["w"],
                 x,
-                cfg,
                 site=site,
                 layer=layer,
                 prng_key=prng_key,
@@ -488,9 +453,10 @@ def _digital_epilogue(y, ep):
 
 
 def _single_device_matmul(
-    eng, params, w, x, cfg, *, site, layer, prng_key, epilogue, slicing=None
+    eng, w, x, *, site, layer, prng_key, epilogue, slicing=None
 ):
-    """The non-sharded product of :func:`dense` (every weight layout)."""
+    """The non-sharded product of :func:`dense`: a prepacked weight
+    (:class:`~repro.photonic.PackedDense`) or a float one."""
     from repro.photonic.packing import PackedDense
 
     if isinstance(w, PackedDense):
@@ -500,53 +466,12 @@ def _single_device_matmul(
             x, w, site=site, fold=layer, prng_key=prng_key,
             epilogue=epilogue, slicing=slicing,
         )
-    if "w_scale" in params:
-        # int8-stored weights through the DPU integer datapath (legacy
-        # layout; the engine wraps them as an unpadded pack on the fly).
-        if eng is None:
-            from repro.core.dpu import DPUConfig
-            from repro.photonic.engine import engine_for
-
-            eng = engine_for(DPUConfig(), cfg.photonic_backend)
-        packed = PackedDense(
-            w, params["w_scale"], w.shape[-2], w.shape[-1], tiling=None
-        )
-        return eng.matmul(
-            x, packed, site=site, fold=layer, prng_key=prng_key,
-            epilogue=epilogue, slicing=slicing,
-        )
-    if eng is not None and cfg.photonic_scope == "weights":
+    if eng is not None:
         return eng.matmul_float(
             x, w, site=site, fold=layer, prng_key=prng_key,
             epilogue=epilogue, slicing=slicing,
         )
     return _digital_epilogue(x @ w.astype(x.dtype), epilogue)
-
-
-def quantize_params(params: Any, defs: Any) -> Any:
-    """Convert a float checkpoint to the int8-stored layout (per-column
-    symmetric quantization) for photonic serving."""
-    if isinstance(defs, dict) and "w_scale" in defs:
-        # w: (..., d_in, d_out) — per-(leading dims, column) symmetric scale,
-        # reducing the contraction axis only.
-        w = params["w"].astype(jnp.float32)
-        amax = jnp.max(jnp.abs(w), axis=-2)
-        # Reciprocal multiply: bitwise-stable across eager/compiled contexts
-        # (see quantize_symmetric).
-        scale = jnp.maximum(amax, 1e-12) * (1.0 / 127.0)
-        q = jnp.clip(
-            jnp.round(w / jnp.expand_dims(scale, -2)), -127, 127
-        ).astype(jnp.int8)
-        out = dict(params)
-        out["w"] = q
-        out["w_scale"] = scale
-        return out
-    if isinstance(defs, dict):
-        return {
-            k: quantize_params(params[k], v) if isinstance(v, dict) else params[k]
-            for k, v in defs.items()
-        }
-    return params
 
 
 def rmsnorm_def(d: int) -> Dict[str, P]:

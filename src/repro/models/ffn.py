@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import common as cm
-from repro.models.common import P, ModelConfig, dense, qdense_def
+from repro.models.common import P, ModelConfig, dense, dense_def
 from repro.photonic import EpilogueSpec
 
 
@@ -29,12 +29,12 @@ def mlp_def(cfg: ModelConfig, d_ff: int | None = None) -> Dict[str, Any]:
     d, f = cfg.d_model, d_ff or cfg.d_ff
     if cfg.ffn_act == "swiglu":
         return {
-            "wi": qdense_def(cfg, d, 2 * f, (None, "d_ff")),
-            "wo": qdense_def(cfg, f, d, ("d_ff", None)),
+            "wi": dense_def(d, 2 * f, (None, "d_ff")),
+            "wo": dense_def(f, d, ("d_ff", None)),
         }
     return {
-        "wi": qdense_def(cfg, d, f, (None, "d_ff")),
-        "wo": qdense_def(cfg, f, d, ("d_ff", None)),
+        "wi": dense_def(d, f, (None, "d_ff")),
+        "wo": dense_def(f, d, ("d_ff", None)),
     }
 
 
@@ -64,7 +64,7 @@ def moe_def(cfg: ModelConfig) -> Dict[str, Any]:
     a SwiGLU bank stacked on a leading expert axis."""
     d, f, e = cfg.d_model, cfg.moe_hidden, cfg.num_experts
     defs: Dict[str, Any] = {
-        "router": qdense_def(cfg, d, e * cfg.ep_size, (None, None), init="normal"),
+        "router": dense_def(d, e * cfg.ep_size, (None, None), init="normal"),
         "experts": {
             "wi": {"w": P((e, d, 2 * f), ("experts", None, None), fan_axis=1)},
             "wo": {"w": P((e, f, d), ("experts", None, None), fan_axis=1)},

@@ -68,7 +68,7 @@ def lm_def(cfg: ModelConfig) -> Dict[str, Any]:
     if cfg.cross_attn_every:
         defs["cross"] = stack_defs(block_def(cfg, "cross"), _n_cross(cfg))
     if not cfg.tie_embeddings:
-        defs["lm_head"] = cm.qdense_def(cfg, cfg.d_model, cfg.n_vocab, (None, "vocab"))
+        defs["lm_head"] = cm.dense_def(cfg.d_model, cfg.n_vocab, (None, "vocab"))
     return defs
 
 

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import common as cm
-from repro.models.common import P, ModelConfig, dense, qdense_def
+from repro.models.common import P, ModelConfig, dense, dense_def
 
 
 def _inner(cfg: ModelConfig) -> int:
@@ -36,14 +36,14 @@ def mamba_def(cfg: ModelConfig) -> Dict[str, Any]:
     conv_ch = inner + 2 * st
     return {
         "ln": cm.rmsnorm_def(d),
-        "in_proj": qdense_def(cfg, d, 2 * inner + 2 * st + h, (None, "inner")),
+        "in_proj": dense_def(d, 2 * inner + 2 * st + h, (None, "inner")),
         "conv_w": P((cfg.ssm_conv_width, conv_ch), (None, "inner")),
         "conv_b": P((conv_ch,), ("inner",), init="zeros"),
         "a_log": P((h,), ("mamba_heads",), init="zeros"),
         "dt_bias": P((h,), ("mamba_heads",), init="zeros"),
         "d_skip": P((h,), ("mamba_heads",), init="ones"),
         "out_norm": cm.rmsnorm_def(inner),
-        "out_proj": qdense_def(cfg, inner, d, ("inner", None)),
+        "out_proj": dense_def(inner, d, ("inner", None)),
     }
 
 

@@ -43,7 +43,7 @@ def whisper_def(cfg: ModelConfig) -> Dict[str, Any]:
         "embed": cm.embed_def(cfg.n_vocab, cfg.d_model),
         "dec_layers": stack_defs(_dec_block_def(cfg), cfg.num_layers),
         "final_norm": cm.rmsnorm_def(cfg.d_model),
-        "lm_head": cm.qdense_def(cfg, cfg.d_model, cfg.n_vocab, (None, "vocab")),
+        "lm_head": cm.dense_def(cfg.d_model, cfg.n_vocab, (None, "vocab")),
     }
 
 

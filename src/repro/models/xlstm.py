@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import common as cm
-from repro.models.common import P, ModelConfig, dense, dense_def, qdense_def
+from repro.models.common import P, ModelConfig, dense, dense_def
 
 
 def _inner(cfg: ModelConfig) -> int:
@@ -41,14 +41,14 @@ def mlstm_def(cfg: ModelConfig) -> Dict[str, Any]:
     d, inner, h = cfg.d_model, _inner(cfg), cfg.num_heads
     return {
         "ln": cm.rmsnorm_def(d),
-        "up": qdense_def(cfg, d, 2 * inner, (None, "inner")),
-        "wq": qdense_def(cfg, inner, inner, (None, "inner")),
-        "wk": qdense_def(cfg, inner, inner, (None, "inner")),
-        "wv": qdense_def(cfg, inner, inner, (None, "inner")),
+        "up": dense_def(d, 2 * inner, (None, "inner")),
+        "wq": dense_def(inner, inner, (None, "inner")),
+        "wk": dense_def(inner, inner, (None, "inner")),
+        "wv": dense_def(inner, inner, (None, "inner")),
         "wi": dense_def(inner, h, (None, None), init="zeros"),
         "wf": dense_def(inner, h, (None, None), init="zeros"),
         "out_norm": cm.rmsnorm_def(inner),
-        "down": qdense_def(cfg, inner, d, ("inner", None)),
+        "down": dense_def(inner, d, ("inner", None)),
     }
 
 
@@ -197,10 +197,10 @@ def slstm_def(cfg: ModelConfig) -> Dict[str, Any]:
     dh = d // h
     return {
         "ln": cm.rmsnorm_def(d),
-        "wx": qdense_def(cfg, d, 4 * d, (None, "inner")),
+        "wx": dense_def(d, 4 * d, (None, "inner")),
         "r": P((h, dh, 4 * dh), (None, None, None)),  # block-diag recurrent
         "out_norm": cm.rmsnorm_def(d),
-        "down": qdense_def(cfg, d, d, ("inner", None)),
+        "down": dense_def(d, d, ("inner", None)),
     }
 
 
@@ -284,7 +284,7 @@ def xlstm_def(cfg: ModelConfig) -> Dict[str, Any]:
         "mlstm": stack_defs(stack_defs(mlstm_def(cfg), per), n_groups),
         "slstm": stack_defs(slstm_def(cfg), n_groups),
         "final_norm": cm.rmsnorm_def(cfg.d_model),
-        "lm_head": cm.qdense_def(cfg, cfg.d_model, cfg.n_vocab, (None, "vocab")),
+        "lm_head": cm.dense_def(cfg.d_model, cfg.n_vocab, (None, "vocab")),
     }
 
 

@@ -39,7 +39,7 @@ def zamba2_def(cfg: ModelConfig) -> Dict[str, Any]:
             "ffn": ffn.mlp_def(cfg),
         },
         "final_norm": cm.rmsnorm_def(cfg.d_model),
-        "lm_head": cm.qdense_def(cfg, cfg.d_model, cfg.n_vocab, (None, "vocab")),
+        "lm_head": cm.dense_def(cfg.d_model, cfg.n_vocab, (None, "vocab")),
     }
 
 

@@ -25,9 +25,8 @@ Contracts (DESIGN.md §8/§9): with an ideal channel every backend is
 bit-identical to :func:`~repro.kernels.photonic_gemm.ref.exact_int_gemm`;
 deterministic analog stages are bitwise across backends; noisy calls need
 ``prng_key`` or ``DPUConfig.noise_seed`` (same source + same site/fold =>
-bitwise-equal).  ``site=None, fold=None`` reproduces the legacy
-pre-engine seed derivation bit-for-bit, which is what keeps
-``repro.kernels.photonic_gemm.ops`` a thin compatibility wrapper.
+bitwise-equal).  ``site=None, fold=None`` is the unfolded stream: the
+seed of the bare ``noise_seed``/``prng_key``.
 """
 
 from __future__ import annotations
@@ -54,7 +53,6 @@ from repro.core.dpu import (
 from repro.kernels import pallas_interpret
 from repro.kernels.photonic_gemm.epilogue import (
     ACTIVATIONS,
-    Epilogue,
     EpilogueArgs,
     EpilogueSpec,
     apply_epilogue,
@@ -366,8 +364,8 @@ class PhotonicEngine:
         decorrelated noise and the shard stream never collides with a
         layer-fold stream; ``plane`` is the plane-pair index of a
         bit-sliced call, folded behind its own tag the same way.
-        ``site=None, fold=None, shard=None, plane=None`` is bitwise the
-        legacy derivation.
+        ``site=None, fold=None, shard=None, plane=None`` folds nothing:
+        the bare key or ``noise_seed``, then the content tweak.
         """
         if prng_key is not None:
             key = prng_key
@@ -503,8 +501,7 @@ class PhotonicEngine:
             n_chunk, tile_k, tc = tiling  # prepacked layout is authoritative
         else:
             n_chunk, tile_k, _ = pallas_tiling(cfg, k, c)
-            # Honour the caller's tile_c bound exactly as the legacy entry
-            # point did (values above 128 are legal).
+            # Honour the caller's tile_c bound (values above 128 are legal).
             tc = min(tile_c, _round_up(c, 128))
             cp = _round_up(c, tc)
             wq = to_chunk_slots(wq[:k, :c], k, (n_chunk, tile_k, tc), axis=0)
@@ -625,20 +622,17 @@ class PhotonicEngine:
         prng_key: Optional[jax.Array] = None,
         epilogue=None,
         slicing=None,
-        bias: Optional[jax.Array] = None,
-        activation: Optional[str] = None,
     ) -> jax.Array:
         """Float GEMM, quantizing *both* operands per call (QAT/train path).
 
         ``epilogue=`` (an :class:`EpilogueSpec` or :class:`Epilogue`) is
-        the blessed spelling of the fused epilogue request (DESIGN.md
-        §14); the legacy ``bias=``/``activation=`` keywords remain as
-        bitwise-identical deprecation shims (:func:`as_epilogue` is the
-        single normalization point).  ``slicing`` overrides the engine's
-        bit-slicing mode for this call.  Non-routed sites fall back to
-        the exact digital op order.
+        the one spelling of the fused epilogue request (DESIGN.md §14;
+        :func:`as_epilogue` is the single normalization point).
+        ``slicing`` overrides the engine's bit-slicing mode for this call.
+        Non-routed sites fall back to the exact digital op order.  The
+        gradient is straight-through: the dense product's own VJP.
         """
-        spec, bias = as_epilogue(epilogue, bias=bias, activation=activation)
+        spec, bias = as_epilogue(epilogue)
         eng = self if slicing is None else self.with_slicing(slicing)
         if not eng.routes(site):
             return _digital_reference(x, w.astype(x.dtype), bias, spec)
@@ -655,20 +649,17 @@ class PhotonicEngine:
         prng_key: Optional[jax.Array] = None,
         epilogue=None,
         slicing=None,
-        bias: Optional[jax.Array] = None,
-        activation: Optional[str] = None,
     ) -> jax.Array:
         """Float GEMM against a prepacked weight — the weight-stationary
         hot path: only the activation is quantized per call, and with a
         float32 activation the quantization itself is deferred into the
         Pallas kernel prologue.
 
-        Accepts the unified ``epilogue=``/``slicing=`` surface exactly as
-        :meth:`matmul_float` (legacy ``bias=``/``activation=`` keywords
-        are bitwise-identical shims).  Non-routed sites execute the
-        dequantized digital matmul.
+        Accepts the ``epilogue=``/``slicing=`` surface exactly as
+        :meth:`matmul_float`.  Non-routed sites execute the dequantized
+        digital matmul.
         """
-        spec, bias = as_epilogue(epilogue, bias=bias, activation=activation)
+        spec, bias = as_epilogue(epilogue)
         eng = self if slicing is None else self.with_slicing(slicing)
         if not eng.routes(site):
             return _digital_reference(x, packed.dequant().astype(x.dtype), bias, spec)

@@ -5,8 +5,8 @@ inputs (paper §III-A); re-quantizing — and, for the Pallas backend,
 re-padding — the *static* weight operand on every forward call is pure
 hot-path waste.  :func:`prepack_params` walks a parameter tree against
 its definition tree, finds every dense site the engine's policy routes,
-and replaces the float (or int8-stored) weight with a
-:class:`PackedDense` leaf:
+and replaces the float weight with a :class:`PackedDense` leaf — the one
+int8 weight layout of the repo:
 
 * per-column symmetric int8 quantization (bit-identical to the per-call
   ``quantize_symmetric(w, bits, axis=0)`` it replaces — contraction-axis
@@ -185,20 +185,11 @@ def _kernel_layout(wq: jax.Array, k_local: int, tiling, shards: int = 1):
     return wq.reshape(*lead, shards * wq.shape[-2], wq.shape[-1])
 
 
-def pack_dense(
-    params: dict,
-    engine: PhotonicEngine,
-    *,
-    already_quantized: bool = False,
-    shards: int = 1,
-) -> dict:
-    """Pack one dense-layer param dict ``{"w": ..., ["w_scale"], ["b"]}``.
+def pack_dense(params: dict, engine: PhotonicEngine, *, shards: int = 1) -> dict:
+    """Pack one dense-layer param dict ``{"w": float, ["b"]}``.
 
-    ``already_quantized`` selects the int8-stored layout (``w`` int8 +
-    per-column ``w_scale``, see :func:`repro.models.common.quantize_params`)
-    — the existing quantization is reused bit-for-bit, only the layout
-    changes.  Float weights are quantized per column exactly like the
-    per-call path (``quantize_symmetric(w, operand_bits, axis=-2)``).
+    The float weight is quantized per column exactly like the per-call
+    path (``quantize_symmetric(w, operand_bits, axis=-2)``).
 
     ``shards > 1`` stores the K-sharded layout: quantization stays global
     (bit-identical scales), then the int8 bank is split into ``shards``
@@ -207,22 +198,18 @@ def pack_dense(
     the concatenated rows shard contiguously over a mesh axis.
     """
     w = params["w"]
-    if already_quantized or "w_scale" in params:
-        wq = w
-        scale = params["w_scale"].astype(jnp.float32)
-    else:
-        # No dtype cast: bitwise-identical to the per-call
-        # quantize_symmetric(w, operand_bits, axis=0) it replaces.  A
-        # stacked weight quantizes one leading slice at a time (the scale
-        # reduces over K only, so the bits are the same), which bounds the
-        # float temporaries by a slice: a layer's experts, not the stack.
-        parts = [
-            quantize_symmetric(wi, engine.dpu.operand_bits, axis=-2)
-            for wi in (w if w.ndim > 2 else [w])
-        ]
-        wq = jnp.stack([q for q, _ in parts]) if w.ndim > 2 else parts[0][0]
-        s = jnp.stack([sc for _, sc in parts]) if w.ndim > 2 else parts[0][1]
-        scale = jnp.squeeze(s, axis=-2)
+    # No dtype cast: bitwise-identical to the per-call
+    # quantize_symmetric(w, operand_bits, axis=0) it replaces.  A stacked
+    # weight quantizes one leading slice at a time (the scale reduces over
+    # K only, so the bits are the same), which bounds the float
+    # temporaries by a slice: a layer's experts, not the stack.
+    parts = [
+        quantize_symmetric(wi, engine.dpu.operand_bits, axis=-2)
+        for wi in (w if w.ndim > 2 else [w])
+    ]
+    wq = jnp.stack([q for q, _ in parts]) if w.ndim > 2 else parts[0][0]
+    s = jnp.stack([sc for _, sc in parts]) if w.ndim > 2 else parts[0][1]
+    scale = jnp.squeeze(s, axis=-2)
     k, c = wq.shape[-2], wq.shape[-1]
     if shards > 1 and k % shards:
         raise ValueError(f"K={k} is not divisible by shards={shards}")
@@ -254,11 +241,10 @@ def fuse_qkv_params(attn: dict, engine: PhotonicEngine) -> dict:
     the noise stream is seeded per site ("attn.wqkv" vs three names), a
     different but equally valid draw.
 
-    Accepts prepacked (:class:`PackedDense`, unsharded), int8-stored
-    (``w`` + per-column ``w_scale``) or float parts — mixed layouts or
-    K-sharded packs are an error.  Biases must be all present or all
-    absent (``qkv_bias``).  Leading stack dims pass through, so stacked
-    layer trees fuse in one call.
+    Accepts prepacked (:class:`PackedDense`, unsharded) or float parts —
+    mixed layouts or K-sharded packs are an error.  Biases must be all
+    present or all absent (``qkv_bias``).  Leading stack dims pass
+    through, so stacked layer trees fuse in one call.
     """
     names = ("wq", "wk", "wv")
     missing = [n for n in names if n not in attn]
@@ -266,8 +252,7 @@ def fuse_qkv_params(attn: dict, engine: PhotonicEngine) -> dict:
         raise KeyError(f"fuse_qkv_params: attention dict lacks {missing}")
     parts = [attn[n] for n in names]
     packed = [isinstance(p["w"], PackedDense) for p in parts]
-    scaled = ["w_scale" in p for p in parts]
-    if (any(packed) and not all(packed)) or (any(scaled) and not all(scaled)):
+    if any(packed) and not all(packed):
         raise ValueError("fuse_qkv_params: mixed Q/K/V weight layouts")
     with_bias = ["b" in p for p in parts]
     if any(with_bias) and not all(with_bias):
@@ -292,13 +277,6 @@ def fuse_qkv_params(attn: dict, engine: PhotonicEngine) -> dict:
             tiling = pallas_tiling(engine.dpu, k, c)
             wq = _kernel_layout(wq, k, tiling)
         fused = {"w": PackedDense(wq, scale, k, c, tiling, 1)}
-    elif all(scaled):
-        # int8-stored layout: columns (and their dequant scales) just
-        # concatenate; the engine wraps the result on the fly as before.
-        fused = {
-            "w": jnp.concatenate([p["w"] for p in parts], axis=-1),
-            "w_scale": jnp.concatenate([p["w_scale"] for p in parts], axis=-1),
-        }
     else:
         # Float weights: per-column quantization at call time is column-
         # independent, so concatenation alone preserves the contract.
@@ -367,12 +345,7 @@ def prepack_params(
             if engine.routes(site_name(path)):
                 k = p["w"].shape[-2]
                 site_shards = shards if k % shards == 0 else 1
-                packed = pack_dense(
-                    p,
-                    engine,
-                    already_quantized="w_scale" in d,
-                    shards=site_shards,
-                )
+                packed = pack_dense(p, engine, shards=site_shards)
                 return place(packed) if site_shards > 1 else packed
             return p
         if isinstance(d, dict):

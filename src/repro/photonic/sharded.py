@@ -446,29 +446,26 @@ _tp_packed_matmul.defvjp(_tp_packed_fwd, _tp_packed_bwd)
 # ---------------------------------------------------------------------------
 def maybe_tp_matmul(
     engine: Optional[PhotonicEngine],
-    params: dict,
+    w,
     x: jax.Array,
-    cfg,
     *,
     site: Optional[str] = None,
     fold=None,
     prng_key: Optional[jax.Array] = None,
     epilogue=None,
     slicing=None,
-    bias: Optional[jax.Array] = None,
-    activation: Optional[str] = None,
 ) -> Optional[jax.Array]:
     """The tensor-parallel product for ``models.common.dense``.
 
-    Returns ``None`` when TP does not apply — no active context, TP
-    degree 1, a site the policy keeps digital, a contraction K the axis
-    does not divide, or a pack layout the active mode cannot shard —
-    and the caller falls through to the single-device path.
-    ``epilogue=`` rides the fused epilogue inside the collective body
-    (replicated operands, applied after the psum); the legacy ``bias=``/
-    ``activation=`` keywords are bitwise-identical shims.  ``slicing``
-    overrides the engine's bit-slicing mode — it rides into every
-    shard-local pass through :func:`shard_local_engine`.
+    ``w`` is the dense site's weight: a :class:`PackedDense` or a float
+    ``(K, C)`` array.  Returns ``None`` when TP does not apply — no
+    active context, TP degree 1, a site the policy keeps digital, a
+    contraction K the axis does not divide, or a pack layout the active
+    mode cannot shard — and the caller falls through to the
+    single-device path.  ``epilogue=`` rides the fused epilogue inside
+    the collective body (replicated operands, applied after the psum).
+    ``slicing`` overrides the engine's bit-slicing mode — it rides into
+    every shard-local pass through :func:`shard_local_engine`.
     """
     ctx = current_tp()
     if ctx is None or engine is None or not engine.routes(site):
@@ -476,43 +473,33 @@ def maybe_tp_matmul(
     size = ctx.size()
     if size <= 1:
         return None
-    spec, bias = as_epilogue(epilogue, bias=bias, activation=activation)
+    spec, bias = as_epilogue(epilogue)
     if slicing is not None:
         engine = engine.with_slicing(slicing)
     fold = None if fold is None else jnp.asarray(fold, jnp.int32)
-    w = params["w"]
-    if isinstance(w, PackedDense):
-        packed = w
-    elif "w_scale" in params:
-        packed = PackedDense(w, params["w_scale"], w.shape[-2], w.shape[-1])
-    elif getattr(cfg, "photonic_scope", "weights") == "weights":
-        k, c = w.shape
-        if k % size:
+    if not isinstance(w, PackedDense):
+        if w.shape[0] % size:
             return None
         meta = (engine, site, ctx.axis, ctx.mesh, spec)
         return _tp_float_matmul(meta, x, w, bias, fold, prng_key)
-    else:
+    if w.k % size:
         return None
-    if packed.k % size:
-        return None
-    if packed.tiling is not None:
+    if w.tiling is not None:
         # Tile-padded banks are only shardable in the layout they were
         # packed for: GSPMD mode, pack shards == TP degree.
-        if ctx.mesh is None or packed.shards != size:
+        if ctx.mesh is None or w.shards != size:
             return None
-    elif packed.shards not in (1, size):
+    elif w.shards not in (1, size):
         return None
     meta = (
         engine,
         site,
         ctx.axis,
         ctx.mesh,
-        packed.k,
-        packed.c,
-        packed.tiling,
-        packed.shards,
+        w.k,
+        w.c,
+        w.tiling,
+        w.shards,
         spec,
     )
-    return _tp_packed_matmul(
-        meta, x, packed.wq, packed.w_scale, bias, fold, prng_key
-    )
+    return _tp_packed_matmul(meta, x, w.wq, w.w_scale, bias, fold, prng_key)

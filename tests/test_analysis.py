@@ -126,7 +126,7 @@ class TestRPR002:
 # ---------------------------------------------------------------------------
 class TestRPR003:
     def test_kernel_import_fires_in_models(self):
-        src = "from repro.kernels.photonic_gemm.ops import photonic_gemm\n"
+        src = "from repro.kernels.photonic_gemm.ref import photonic_gemm_ref\n"
         f = check_source(src, "src/repro/models/foo.py")
         assert rule_ids(f) == ["RPR003"]
         assert f[0].line == 1
@@ -352,8 +352,8 @@ class TestRPR008:
     def test_epilogue_kwargs_clean(self):
         src = (
             "def f(eng, x, pk, b):\n"
-            "    return eng.matmul(x, pk, site='s', bias=b, "
-            "activation='gelu')\n"
+            "    ep = Epilogue(EpilogueSpec(bias=True, activation='gelu'), b)\n"
+            "    return eng.matmul(x, pk, site='s', epilogue=ep)\n"
         )
         assert check_source(src, "src/repro/models/foo.py") == []
 

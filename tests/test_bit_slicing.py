@@ -9,8 +9,9 @@ shifts.  Contracts under test:
    analog backends, eager and jit;
 2. noisy channel  => deterministic per (engine, seed, site, fold, shard,
    plane) with decorrelated per-plane streams;
-3. the unified ``epilogue=`` / ``slicing=`` surface is bitwise-identical
-   to the legacy ``bias=``/``activation=`` shims it replaces.
+3. the ``epilogue=`` / ``slicing=`` surface is bitwise-identical to the
+   unfused composition it replaces: exact integer GEMM, then
+   ``apply_epilogue`` on the accumulator.
 """
 
 import dataclasses
@@ -21,9 +22,10 @@ import numpy as np
 import pytest
 
 from benchmarks import run as bench_run
-from repro.core.dpu import DPUConfig
+from repro.core.dpu import DPUConfig, quantize_symmetric
+from repro.kernels.photonic_gemm.epilogue import apply_epilogue
 from repro.kernels.photonic_gemm.ref import exact_int_gemm
-from repro.models.common import ModelConfig
+from repro.models.common import ModelConfig, dense
 from repro.noise import build_channel_model
 from repro.photonic import (
     Epilogue,
@@ -45,6 +47,17 @@ B = jnp.asarray(RNG.normal(size=(24,)), jnp.float32)
 
 def _ideal_dpu(n=16):
     return DPUConfig(organization="SMWA", bits=4, dpe_size=n)
+
+
+def _unfused_gold(spec, bias):
+    """The float GEMM of ``X @ W`` spelled out: per-tensor / per-column
+    int8 quantization, the exact integer GEMM, then ``apply_epilogue``
+    (jitted, the compilation regime every engine backend applies its
+    epilogue in)."""
+    xq, sx = quantize_symmetric(X, 8)
+    wq, sw = quantize_symmetric(W, 8, axis=0)
+    epi = jax.jit(apply_epilogue, static_argnames="spec")
+    return epi(exact_int_gemm(xq, wq), sx, sw.reshape(-1), bias, spec)
 
 
 def _noisy_dpu(n=16, platform="SIN", seed=11):
@@ -93,13 +106,13 @@ class TestIdealBitwise:
 
     @pytest.mark.parametrize("backend", ["ref", "pallas"])
     def test_sliced_matmul_with_epilogue_equals_exact_shim(self, backend):
-        """Full float surface: sliced ideal engine with the unified
-        epilogue == exact engine running the legacy keyword shim."""
+        """Full float surface: sliced ideal engine with the epilogue ==
+        the exact engine with the same epilogue."""
         eng = engine_for(_ideal_dpu(), backend, slicing=2)
         gold_eng = engine_for(_ideal_dpu(), "exact")
         ep = Epilogue(EpilogueSpec(bias=True, activation="gelu"), B)
         out = eng.matmul_float(X, W, site="s", epilogue=ep)
-        gold = gold_eng.matmul_float(X, W, site="s", bias=B, activation="gelu")
+        gold = gold_eng.matmul_float(X, W, site="s", epilogue=ep)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(gold))
 
 
@@ -147,37 +160,56 @@ class TestNoisyDeterminism:
 
 
 # ---------------------------------------------------------------------------
-# Contract 3: the unified surface == the legacy shims, bitwise
+# Contract 3: the epilogue= surface == the unfused composition, bitwise
 # ---------------------------------------------------------------------------
 class TestUnifiedSurface:
     @pytest.mark.parametrize("backend", ["ref", "pallas", "exact"])
     def test_epilogue_keyword_equals_legacy_shim(self, backend):
+        """``epilogue=`` on the per-call and prepacked float entry points
+        == exact integer GEMM + ``apply_epilogue``, bit for bit."""
+        spec = EpilogueSpec(bias=True, activation="gelu")
+        gold = np.asarray(_unfused_gold(spec, B))
         eng = engine_for(_ideal_dpu(), backend)
-        ep = Epilogue(EpilogueSpec(bias=True, activation="gelu"), B)
+        ep = Epilogue(spec, B)
         a = eng.matmul_float(X, W, site="s", epilogue=ep)
-        b = eng.matmul_float(X, W, site="s", bias=B, activation="gelu")
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), gold)
         packed = pack_dense({"w": W}, eng)["w"]
         c = eng.matmul(X, packed, site="s", epilogue=ep)
-        d = eng.matmul(X, packed, site="s", bias=B, activation="gelu")
-        np.testing.assert_array_equal(np.asarray(c), np.asarray(d))
+        np.testing.assert_array_equal(np.asarray(c), gold)
 
     def test_bias_free_spec_accepted(self):
         eng = engine_for(_ideal_dpu(), "ref")
-        a = eng.matmul_float(X, W, site="s", epilogue=EpilogueSpec(activation="gelu"))
-        b = eng.matmul_float(X, W, site="s", activation="gelu")
+        spec = EpilogueSpec(activation="gelu")
+        a = eng.matmul_float(X, W, site="s", epilogue=spec)
+        b = eng.matmul_float(X, W, site="s", epilogue=Epilogue(spec, None))
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(_unfused_gold(spec, None))
+        )
 
     def test_mixing_epilogue_and_legacy_raises(self):
-        eng = engine_for(_ideal_dpu(), "ref")
-        with pytest.raises(TypeError, match="not both"):
-            eng.matmul_float(
-                X, W, epilogue=EpilogueSpec(activation="gelu"), bias=B
+        """``dense`` takes only a bias-free spec: its bias operand comes
+        from the param def, so a spec asking for one, or an ``Epilogue``
+        tuple carrying one, is rejected eagerly."""
+        cfg = ModelConfig(photonic=_ideal_dpu(), photonic_backend="ref")
+        params = {"w": W, "b": B}
+        with pytest.raises(TypeError, match="param def"):
+            dense(params, X, cfg, site="s", epilogue=EpilogueSpec(bias=True))
+        with pytest.raises(TypeError, match="EpilogueSpec"):
+            dense(
+                params,
+                X,
+                cfg,
+                site="s",
+                epilogue=Epilogue(EpilogueSpec(bias=True), B),
             )
-        with pytest.raises(TypeError, match="not both"):
-            eng.matmul_float(
-                X, W, epilogue=EpilogueSpec(), activation="gelu"
-            )
+        # The accepted spelling: the activation from the spec, the bias
+        # from the param def.
+        y = dense(params, X, cfg, site="s", epilogue=EpilogueSpec(activation="gelu"))
+        gold = engine_for(_ideal_dpu(), "ref").matmul_float(
+            X, W, site="s", epilogue=Epilogue(EpilogueSpec(True, "gelu"), B)
+        )
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(gold))
 
     def test_epilogue_validation(self):
         eng = engine_for(_ideal_dpu(), "ref")

@@ -1,5 +1,5 @@
-"""STE backward pass of the photonic matmul (noise-aware), and the noise
-seed-determinism contract of `dpu_int_gemm`."""
+"""STE backward pass of the engine's float GEMM (noise-aware), and the
+noise seed-determinism contract of the `ref` backend's integer datapath."""
 
 import dataclasses
 
@@ -8,14 +8,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.dpu import (
-    DPUConfig,
-    dpu_int_gemm,
-    photonic_matmul,
-    photonic_matmul_ste,
-)
-from repro.kernels.photonic_gemm.ops import photonic_gemm
+from repro.core.dpu import DPUConfig
 from repro.noise import build_channel_model
+from repro.photonic import engine_for
+
+
+def _matmul(x, w, cfg, prng_key=None):
+    """Float GEMM through the `ref` backend (STE-differentiable)."""
+    return engine_for(cfg, "ref").matmul_float(x, w, prng_key=prng_key)
+
+
+def _int_gemm(xq, wq, cfg, prng_key=None):
+    """Integer GEMM through the `ref` backend's datapath."""
+    return engine_for(cfg, "ref").int_gemm(xq, wq, prng_key=prng_key)
 
 
 def _data(seed=0, b=4, s=8, k=32, c=16):
@@ -34,7 +39,7 @@ def test_ste_backward_matches_dense_matmul_grad():
     cfg = DPUConfig(dpe_size=16)
 
     def loss_ste(x, w):
-        return (photonic_matmul_ste(x, w, cfg) * g).sum()
+        return (_matmul(x, w, cfg) * g).sum()
 
     def loss_dense(x, w):
         return ((x @ w) * g).sum()
@@ -54,13 +59,13 @@ def test_ste_backward_unchanged_by_noise():
     cfg_noisy = dataclasses.replace(cfg_ideal, channel=ch)
     key = jax.random.PRNGKey(7)
 
-    y_ideal = photonic_matmul_ste(x, w, cfg_ideal)
-    y_noisy = photonic_matmul_ste(x, w, cfg_noisy, key)
+    y_ideal = _matmul(x, w, cfg_ideal)
+    y_noisy = _matmul(x, w, cfg_noisy, key)
     assert (np.asarray(y_ideal) != np.asarray(y_noisy)).any()
 
     def gset(cfg, key=None):
         gx, gw = jax.grad(
-            lambda x, w: (photonic_matmul_ste(x, w, cfg, key) * g).sum(),
+            lambda x, w: (_matmul(x, w, cfg, key) * g).sum(),
             argnums=(0, 1),
         )(x, w)
         return np.asarray(gx), np.asarray(gw)
@@ -74,16 +79,17 @@ def test_ste_backward_unchanged_by_noise():
 
 @pytest.mark.parametrize("backend", ["ref", "pallas"])
 def test_kernel_entrypoint_ste_noise_aware(backend):
-    """`photonic_gemm` (kernel entry point) takes a prng_key and keeps its
-    STE gradients exact while the forward carries channel noise."""
+    """`matmul_float` on either datapath backend takes a prng_key and keeps
+    its STE gradients exact while the forward carries channel noise."""
     x, w, g = _data(2)
     ch = build_channel_model("MASW", n=16)
     cfg = DPUConfig(organization="MASW", dpe_size=16, channel=ch)
     key = jax.random.PRNGKey(3)
 
-    y = photonic_gemm(x, w, cfg, backend, key)
+    eng = engine_for(cfg, backend)
+    y = eng.matmul_float(x, w, prng_key=key)
     assert np.isfinite(np.asarray(y)).all()
-    gx = jax.grad(lambda x: (photonic_gemm(x, w, cfg, backend, key) * g).sum())(x)
+    gx = jax.grad(lambda x: (eng.matmul_float(x, w, prng_key=key) * g).sum())(x)
     ex = jax.grad(lambda x: ((x @ w) * g).sum())(x)
     np.testing.assert_allclose(np.asarray(gx), np.asarray(ex), rtol=1e-6)
 
@@ -96,7 +102,7 @@ def test_ste_jit_and_value_and_grad():
     @jax.jit
     def vg(x, w):
         return jax.value_and_grad(
-            lambda x, w: (photonic_matmul_ste(x, w, cfg) * g).sum(),
+            lambda x, w: (_matmul(x, w, cfg) * g).sum(),
             argnums=(0, 1),
         )(x, w)
 
@@ -115,10 +121,10 @@ def test_same_key_bitwise_equal_under_noise():
     wq = jnp.asarray(rng.integers(-127, 128, (96, 24), dtype=np.int8))
     cfg = DPUConfig(dpe_size=24, noise_sigma_lsb=4.0)
     key = jax.random.PRNGKey(0)
-    a = dpu_int_gemm(xq, wq, cfg, prng_key=key)
-    b = dpu_int_gemm(xq, wq, cfg, prng_key=key)
+    a = _int_gemm(xq, wq, cfg, prng_key=key)
+    b = _int_gemm(xq, wq, cfg, prng_key=key)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    c = dpu_int_gemm(xq, wq, cfg, prng_key=jax.random.PRNGKey(1))
+    c = _int_gemm(xq, wq, cfg, prng_key=jax.random.PRNGKey(1))
     assert (np.asarray(a) != np.asarray(c)).any()
 
 
@@ -127,12 +133,12 @@ def test_noise_without_seed_is_explicit_error():
     xq = jnp.asarray(rng.integers(-127, 128, (4, 32), dtype=np.int8))
     wq = jnp.asarray(rng.integers(-127, 128, (32, 8), dtype=np.int8))
     with pytest.raises(ValueError, match="randomness source"):
-        dpu_int_gemm(xq, wq, DPUConfig(dpe_size=16, noise_sigma_lsb=2.0))
+        _int_gemm(xq, wq, DPUConfig(dpe_size=16, noise_sigma_lsb=2.0))
     ch = build_channel_model("ASMW", n=16)
     with pytest.raises(ValueError, match="randomness source"):
-        dpu_int_gemm(xq, wq, DPUConfig(organization="ASMW", dpe_size=16, channel=ch))
+        _int_gemm(xq, wq, DPUConfig(organization="ASMW", dpe_size=16, channel=ch))
     # Crosstalk-only channels are deterministic — no seed needed.
-    out = dpu_int_gemm(
+    out = _int_gemm(
         xq,
         wq,
         DPUConfig(
@@ -154,8 +160,8 @@ def test_same_seed_distinct_operands_decorrelated():
     cfg = DPUConfig(dpe_size=16, channel=ch, noise_seed=0)
     from repro.kernels.photonic_gemm.ref import exact_int_gemm
 
-    n1 = np.asarray(dpu_int_gemm(xq, w1, cfg)) - np.asarray(exact_int_gemm(xq, w1))
-    n2 = np.asarray(dpu_int_gemm(xq, w2, cfg)) - np.asarray(exact_int_gemm(xq, w2))
+    n1 = np.asarray(_int_gemm(xq, w1, cfg)) - np.asarray(exact_int_gemm(xq, w1))
+    n2 = np.asarray(_int_gemm(xq, w2, cfg)) - np.asarray(exact_int_gemm(xq, w2))
     assert (n1 != n2).any()
     corr = np.corrcoef(n1.ravel().astype(float), n2.ravel().astype(float))[0, 1]
     assert abs(corr) < 0.3, corr
@@ -167,9 +173,9 @@ def test_noise_seed_documented_deterministic_path():
     w = jnp.asarray(rng.normal(size=(64, 16)), jnp.float32)
     ch = build_channel_model("MASW", n=21)
     cfg = DPUConfig(organization="MASW", dpe_size=21, channel=ch, noise_seed=42)
-    a = photonic_matmul(x, w, cfg)
-    b = photonic_matmul(x, w, cfg)
+    a = _matmul(x, w, cfg)
+    b = _matmul(x, w, cfg)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # An explicit key overrides the config seed.
-    c = photonic_matmul(x, w, cfg, prng_key=jax.random.PRNGKey(9))
+    c = _matmul(x, w, cfg, prng_key=jax.random.PRNGKey(9))
     assert (np.asarray(a) != np.asarray(c)).any()

@@ -8,7 +8,7 @@ The load-bearing contracts:
   channel, on both backends, eager and jitted — including the tiling
   edge cases (non-divisible K/C, ``tile_c > 128``, R=1 decode rows);
 * one fused-QKV GEMM (``fuse_qkv_params``) is bitwise-equal to the three
-  separate projections, for every weight layout the packer accepts;
+  separate projections, for both weight layouts the packer accepts;
 * ``hlo_analysis.dispatch_summary`` proves the fusion *structurally*:
   the fused module's entry op sequence is strictly shorter.
 """
@@ -25,11 +25,13 @@ import pytest
 from repro.core.dpu import DPUConfig, quantize_symmetric
 from repro.launch import hlo_analysis, profile
 from repro.models import attention as attn
-from repro.models.common import ModelConfig, dense, init_tree
+from repro.models.common import ModelConfig, init_tree
 from repro.photonic import (
     ACTIVATIONS,
+    Epilogue,
     EpilogueArgs,
     EpilogueSpec,
+    PackedDense,
     engine_for,
     fuse_qkv_params,
     pack_dense,
@@ -80,9 +82,8 @@ class TestFusedEpilogueBitwise:
         eng = engine_for(DPU, backend)
         pk = pack_dense({"w": _arr(100, 130, scale=0.1)}, eng)["w"]
         b, x = _arr(130, scale=0.02), _arr(3, 100)
-        fused = jax.jit(
-            lambda x: eng.matmul(x, pk, site="s", bias=b, activation=activation)
-        )
+        ep = Epilogue(EpilogueSpec(bias=True, activation=activation), b)
+        fused = jax.jit(lambda x: eng.matmul(x, pk, site="s", epilogue=ep))
         manual = jax.jit(lambda x: _manual_unfused(eng, x, pk, b, activation))
         np.testing.assert_allclose(
             np.asarray(fused(x)), np.asarray(manual(x)), rtol=1e-6, atol=1e-6
@@ -97,7 +98,8 @@ class TestFusedEpilogueBitwise:
             # same float weight packed per backend: layouts differ
             # (pallas pads to its tiling), values must not
             pk = pack_dense({"w": w}, eng)["w"]
-            outs[backend] = np.asarray(eng.matmul(x, pk, site="s", bias=b))
+            ep = Epilogue(EpilogueSpec(bias=True), b)
+            outs[backend] = np.asarray(eng.matmul(x, pk, site="s", epilogue=ep))
         np.testing.assert_array_equal(outs["ref"], outs["pallas"])
 
     @pytest.mark.parametrize("with_epilogue", [False, True])
@@ -132,15 +134,12 @@ class TestFusedEpilogueBitwise:
 # ---------------------------------------------------------------------------
 # fuse_qkv_params — one QKV bank == three separate projections
 # ---------------------------------------------------------------------------
-def _qkv_params(d, eng=None, bias=False, scaled=False):
+def _qkv_params(d, eng=None, bias=False):
     params = {}
     for name in ("wq", "wk", "wv"):
         w = _arr(d, d, scale=d**-0.5)
         if eng is not None:
             p = pack_dense({"w": w}, eng)
-        elif scaled:
-            ws = jnp.max(jnp.abs(w), axis=0) * (1.0 / 127.0)
-            p = {"w": jnp.round(w / ws).astype(jnp.int8), "w_scale": ws}
         else:
             p = {"w": w}
         if bias:
@@ -159,25 +158,42 @@ class TestFuseQKV:
         fused = fuse_qkv_params(params, eng)
         assert "wqkv" in fused and "wq" not in fused and "wo" in fused
         x = _arr(3, d)
-        kw = {"bias": fused["wqkv"].get("b")} if bias else {}
-        y = eng.matmul(x, fused["wqkv"]["w"], site="attn.wqkv", **kw)
+
+        def ep(p):
+            return Epilogue(EpilogueSpec(bias=bias), p.get("b"))
+
+        y = eng.matmul(
+            x, fused["wqkv"]["w"], site="attn.wqkv", epilogue=ep(fused["wqkv"])
+        )
         parts = []
         for name in ("wq", "wk", "wv"):
-            kw1 = {"bias": params[name].get("b")} if bias else {}
+            p = params[name]
             parts.append(
-                eng.matmul(x, params[name]["w"], site=f"attn.{name}", **kw1)
+                eng.matmul(x, p["w"], site=f"attn.{name}", epilogue=ep(p))
             )
         np.testing.assert_array_equal(
             np.asarray(y), np.asarray(jnp.concatenate(parts, axis=-1))
         )
 
     def test_int8_stored_layout(self):
+        """The int8 layout the packer fuses is the prepacked one: the fused
+        bank holds the three banks' logical int8 columns and per-column
+        scales side by side, re-laid out once for the fused width."""
         d = 32
-        params = _qkv_params(d, scaled=True)
-        eng = engine_for(DPU, "ref")
-        fused = fuse_qkv_params(params, eng)
-        assert fused["wqkv"]["w"].dtype == jnp.int8
-        assert fused["wqkv"]["w_scale"].shape == (3 * d,)
+        eng = engine_for(DPU, "pallas")
+        params = _qkv_params(d, eng=eng)
+        fused = fuse_qkv_params(params, eng)["wqkv"]["w"]
+        assert isinstance(fused, PackedDense) and fused.wq.dtype == jnp.int8
+        assert fused.w_scale.shape == (3 * d,)
+        packs = [params[n]["w"] for n in ("wq", "wk", "wv")]
+        np.testing.assert_array_equal(
+            np.asarray(fused.logical_wq()),
+            np.asarray(jnp.concatenate([pk.logical_wq() for pk in packs], -1)),
+        )
+        np.testing.assert_array_equal(
+            np.asarray(fused.w_scale),
+            np.asarray(jnp.concatenate([pk.w_scale for pk in packs])),
+        )
 
     def test_float_layout(self):
         d = 32
@@ -286,7 +302,8 @@ class TestDispatchSummary:
         b_f = jnp.concatenate(bs)
 
         def fused(x):
-            return eng.matmul(x, pk_f, site="s", bias=b_f)
+            ep = Epilogue(EpilogueSpec(bias=True), b_f)
+            return eng.matmul(x, pk_f, site="s", epilogue=ep)
 
         x = _arr(1, 48)
         counts = {}

@@ -10,10 +10,9 @@ import numpy as np
 import pytest
 
 from repro.core import scalability
-from repro.core.dpu import DPUConfig, dpu_int_gemm
+from repro.core.dpu import DPUConfig
 from repro.core.organizations import ORGANIZATIONS, through_device_count
-from repro.kernels.photonic_gemm.ops import photonic_gemm_int
-from repro.kernels.photonic_gemm.ref import exact_int_gemm
+from repro.kernels.photonic_gemm.ref import exact_int_gemm, photonic_gemm_ref
 from repro.noise import (
     apply_channel_psum,
     build_channel_model,
@@ -22,6 +21,12 @@ from repro.noise import (
     neighbor_sum,
     round_ste,
 )
+from repro.photonic import engine_for
+
+
+def _int_gemm(xq, wq, cfg, *, backend, prng_key=None, **kw):
+    """Integer GEMM of ``cfg``'s DPU through one engine backend."""
+    return engine_for(cfg, backend).int_gemm(xq, wq, prng_key=prng_key, **kw)
 
 
 def _rand_int8(rng, shape):
@@ -115,9 +120,12 @@ def test_disabled_channel_bit_identical(org):
     cfg = DPUConfig(organization=org, dpe_size=21, channel=ch)
     gold = np.asarray(exact_int_gemm(xq, wq))
     for backend in ("ref", "pallas"):
-        out = photonic_gemm_int(xq, wq, cfg, backend=backend)
+        out = _int_gemm(xq, wq, cfg, backend=backend)
         np.testing.assert_array_equal(np.asarray(out), gold)
-    np.testing.assert_array_equal(np.asarray(dpu_int_gemm(xq, wq, cfg)), gold)
+    # ...and the compiled oracle too.
+    eng = engine_for(cfg, "ref")
+    out = jax.jit(lambda a, b: eng.int_gemm(a, b))(xq, wq)
+    np.testing.assert_array_equal(np.asarray(out), gold)
 
 
 def test_builder_enable_flags_disable_stages():
@@ -135,7 +143,7 @@ def test_builder_enable_flags_disable_stages():
 
 
 # ---------------------------------------------------------------------------
-# Deterministic stages: oracle / ref / pallas agree bitwise
+# Deterministic stages: ref / pallas agree bitwise, whatever the tiling
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("org,n", [("ASMW", 17), ("MASW", 21), ("SMWA", 42)])
 def test_crosstalk_stages_bitwise_across_backends(org, n):
@@ -144,11 +152,13 @@ def test_crosstalk_stages_bitwise_across_backends(org, n):
     wq = _rand_int8(rng, (200, 64))
     ch = build_channel_model(org, n=n).disable("detector")
     cfg = DPUConfig(organization=org, dpe_size=n, channel=ch)
-    ref = np.asarray(photonic_gemm_int(xq, wq, cfg, backend="ref"))
-    pal = np.asarray(photonic_gemm_int(xq, wq, cfg, backend="pallas"))
-    orc = np.asarray(dpu_int_gemm(xq, wq, cfg))
+    ref = np.asarray(_int_gemm(xq, wq, cfg, backend="ref"))
+    pal = np.asarray(_int_gemm(xq, wq, cfg, backend="pallas"))
+    pal_tiled = np.asarray(
+        _int_gemm(xq, wq, cfg, backend="pallas", tile_r=8, tile_c=256)
+    )
     np.testing.assert_array_equal(ref, pal)
-    np.testing.assert_array_equal(ref, orc)
+    np.testing.assert_array_equal(ref, pal_tiled)
 
 
 def test_crosstalk_perturbs_but_smwa_unbiased_by_neighbors():
@@ -161,16 +171,16 @@ def test_crosstalk_perturbs_but_smwa_unbiased_by_neighbors():
     for org in ("ASMW", "MASW"):
         ch = build_channel_model(org, n=21).disable("detector", "filter")
         cfg = DPUConfig(organization=org, dpe_size=21, channel=ch)
-        out = np.asarray(photonic_gemm_int(xq, wq, cfg, backend="ref"))
+        out = np.asarray(_int_gemm(xq, wq, cfg, backend="ref"))
         assert (out != gold).any(), org
     ch = build_channel_model("SMWA", n=21).disable("detector", "filter")
     cfg = DPUConfig(organization="SMWA", dpe_size=21, channel=ch)
-    out = np.asarray(photonic_gemm_int(xq, wq, cfg, backend="ref"))
+    out = np.asarray(_int_gemm(xq, wq, cfg, backend="ref"))
     np.testing.assert_array_equal(out, gold)  # nothing left to perturb
 
 
 # ---------------------------------------------------------------------------
-# Noise: statistical pallas/oracle agreement, bitwise ref==dpu
+# Noise: statistical pallas/oracle agreement, bitwise ref==oracle
 # ---------------------------------------------------------------------------
 def test_pallas_noise_statistics_match_oracle():
     rng = np.random.default_rng(4)
@@ -180,10 +190,10 @@ def test_pallas_noise_statistics_match_oracle():
     cfg = DPUConfig(dpe_size=64, channel=ch, noise_seed=3)
     gold = np.asarray(exact_int_gemm(xq, wq), np.float64)
     e_pal = (
-        np.asarray(photonic_gemm_int(xq, wq, cfg, backend="pallas"), np.float64)
+        np.asarray(_int_gemm(xq, wq, cfg, backend="pallas"), np.float64)
         - gold
     )
-    e_ref = np.asarray(photonic_gemm_int(xq, wq, cfg, backend="ref"), np.float64) - gold
+    e_ref = np.asarray(_int_gemm(xq, wq, cfg, backend="ref"), np.float64) - gold
     assert abs(e_pal.std() / e_ref.std() - 1.0) < 0.1, (e_pal.std(), e_ref.std())
     # Means consistent with zero (std over sqrt(n_samples) scale).
     tol = 4 * e_ref.std() / np.sqrt(e_ref.size)
@@ -199,23 +209,36 @@ def test_pallas_noise_statistics_ragged_k():
     cfg = DPUConfig(dpe_size=83, channel=ch, noise_seed=9)
     gold = np.asarray(exact_int_gemm(xq, wq), np.float64)
     e_pal = (
-        np.asarray(photonic_gemm_int(xq, wq, cfg, backend="pallas"), np.float64)
+        np.asarray(_int_gemm(xq, wq, cfg, backend="pallas"), np.float64)
         - gold
     )
-    e_ref = np.asarray(photonic_gemm_int(xq, wq, cfg, backend="ref"), np.float64) - gold
+    e_ref = np.asarray(_int_gemm(xq, wq, cfg, backend="ref"), np.float64) - gold
     assert abs(e_pal.std() / e_ref.std() - 1.0) < 0.1, (e_pal.std(), e_ref.std())
 
 
 def test_noisy_ref_bitwise_equals_dpu_oracle():
+    """The ``ref`` backend under noise is the jnp oracle fed the engine's
+    documented stream seed (``site=None``: the bare key), bit for bit."""
     rng = np.random.default_rng(6)
     xq = _rand_int8(rng, (16, 100))
     wq = _rand_int8(rng, (100, 24))
     ch = build_channel_model("ASMW", n=17)
     cfg = DPUConfig(organization="ASMW", dpe_size=17, channel=ch)
     key = jax.random.PRNGKey(11)
-    a = dpu_int_gemm(xq, wq, cfg, prng_key=key)
-    b = photonic_gemm_int(xq, wq, cfg, backend="ref", prng_key=key)
+    eng = engine_for(cfg, "ref")
+    a = photonic_gemm_ref(
+        xq,
+        wq,
+        slice_bits=cfg.bits,
+        num_slices=cfg.num_slices,
+        n_chunk=cfg.n,
+        channel=ch,
+        seed=eng.stream_seed(None, None, key, xq, wq),
+    )
+    b = _int_gemm(xq, wq, cfg, backend="ref", prng_key=key)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    c = _int_gemm(xq, wq, cfg, backend="ref", prng_key=jax.random.PRNGKey(12))
+    assert (np.asarray(b) != np.asarray(c)).any()
 
 
 def test_pallas_noise_seed_determinism():
@@ -225,9 +248,9 @@ def test_pallas_noise_seed_determinism():
     ch = build_channel_model("MASW", n=32)
     c1 = DPUConfig(organization="MASW", dpe_size=32, channel=ch, noise_seed=1)
     c2 = DPUConfig(organization="MASW", dpe_size=32, channel=ch, noise_seed=2)
-    a = photonic_gemm_int(xq, wq, c1, backend="pallas")
-    b = photonic_gemm_int(xq, wq, c1, backend="pallas")
-    c = photonic_gemm_int(xq, wq, c2, backend="pallas")
+    a = _int_gemm(xq, wq, c1, backend="pallas")
+    b = _int_gemm(xq, wq, c1, backend="pallas")
+    c = _int_gemm(xq, wq, c2, backend="pallas")
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert (np.asarray(a) != np.asarray(c)).any()
 
@@ -295,10 +318,10 @@ def test_adc_saturation_under_channel():
     ch = build_channel_model("SMWA", n=32, adc_bits=8).disable("detector", "filter")
     cfg = DPUConfig(dpe_size=32, channel=ch)
     gold = np.asarray(exact_int_gemm(xq, wq))
-    sat = np.asarray(photonic_gemm_int(xq, wq, cfg, backend="ref"))
+    sat = np.asarray(_int_gemm(xq, wq, cfg, backend="ref"))
     assert np.abs(sat).max() <= np.abs(gold).max()
     assert (sat != gold).any()
     # Same semantics on the Pallas path.
     np.testing.assert_array_equal(
-        sat, np.asarray(photonic_gemm_int(xq, wq, cfg, backend="pallas"))
+        sat, np.asarray(_int_gemm(xq, wq, cfg, backend="pallas"))
     )

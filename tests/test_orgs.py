@@ -17,12 +17,13 @@ from _hypothesis_compat import given, settings, strategies as st
 from repro import orgs
 from repro.core import organizations as org_tables
 from repro.core import scalability as sc
-from repro.core.dpu import DPUConfig, dpu_int_gemm
+from repro.core.dpu import DPUConfig
 from repro.core.params import PhotonicParams
 from repro.core.perfmodel import AcceleratorConfig
 from repro.kernels.photonic_gemm.ref import exact_int_gemm
 from repro.noise import build_channel_model, shard_local_channel
 from repro.orgs import OrgSpec, resolve, valid_orderings
+from repro.photonic import engine_for
 
 # ---------------------------------------------------------------------------
 # The paper's hand-tabulated values (Tables II, III, IV and §IV-B1), kept
@@ -279,5 +280,5 @@ class TestDesignSpace:
         gold = exact_int_gemm(xq, wq)
         for order in ("MWAS", "SAMW", "MSAW"):
             cfg = DPUConfig(organization=order, bits=4, dpe_size=16)
-            out = dpu_int_gemm(xq, wq, cfg)
+            out = engine_for(cfg, "ref").int_gemm(xq, wq)
             assert jnp.array_equal(out, gold), order

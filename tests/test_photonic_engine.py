@@ -16,7 +16,6 @@ from repro.models.common import (
     dense,
     engine_from_model_config,
     init_tree,
-    quantize_params,
 )
 from repro.noise import build_channel_model
 from repro.photonic import (
@@ -120,30 +119,34 @@ def test_prepack_pallas_layout_is_tile_padded():
 
 
 def test_prepack_reuses_existing_int8_layout_bitwise():
-    """Prepacking int8-stored params reuses their quantization bit-for-bit
-    (only the layout changes)."""
+    """Prepacking a model's float weights gives exactly the int8 values and
+    per-column scales of ``quantize_symmetric(w, bits, axis=0)`` — layer
+    by layer through a stacked tree, bit for bit (only the layout is the
+    pack's own)."""
+    from repro.core.dpu import quantize_symmetric
+
     arch = registry.get("qwen2-0.5b")
     mcfg = dataclasses.replace(
         arch.smoke_config,
         remat=False,
         photonic=DPUConfig(dpe_size=21),
         photonic_backend="ref",
-        photonic_scope="weights_int8",
     )
-    fcfg = dataclasses.replace(mcfg, photonic_scope="weights")
-    params = init_tree(arch.param_defs(fcfg), jax.random.PRNGKey(0), mcfg.param_dtype)
-    defs_q = arch.param_defs(mcfg)
-    params_q = quantize_params(params, defs_q)
+    defs = arch.param_defs(mcfg)
+    params = init_tree(defs, jax.random.PRNGKey(0), mcfg.param_dtype)
     eng = engine_from_model_config(mcfg)
-    packed = prepack_params(params_q, defs_q, eng)
+    packed = prepack_params(params, defs, eng)
 
-    leaf_q = params_q["layers"]["attn"]["wq"]
+    w = params["layers"]["attn"]["wq"]["w"]  # (layers, K, C)
     leaf_p = packed["layers"]["attn"]["wq"]["w"]
     assert isinstance(leaf_p, PackedDense) and leaf_p.wq.dtype == jnp.int8
-    np.testing.assert_array_equal(np.asarray(leaf_q["w"]), np.asarray(leaf_p.wq))
-    np.testing.assert_array_equal(
-        np.asarray(leaf_q["w_scale"], np.float32), np.asarray(leaf_p.w_scale)
-    )
+    assert leaf_p.tiling is None and leaf_p.wq.shape == w.shape
+    for i in range(w.shape[0]):
+        wq, sw = quantize_symmetric(w[i], eng.dpu.operand_bits, axis=0)
+        np.testing.assert_array_equal(np.asarray(wq), np.asarray(leaf_p.wq[i]))
+        np.testing.assert_array_equal(
+            np.asarray(sw[0]), np.asarray(leaf_p.w_scale[i])
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -217,8 +220,12 @@ def test_model_config_validates_scope_and_backend():
         ModelConfig(photonic_scope="weights_int4")
     with pytest.raises(ValueError, match="photonic_backend"):
         ModelConfig(photonic_backend="cuda")
-    for scope in ("none", "weights", "weights_int8"):
+    for scope in ("none", "weights"):
         ModelConfig(photonic_scope=scope)  # documented values accepted
+    # int8 weights exist only as prepacked PackedDense leaves, not as a
+    # scope of the param tree.
+    with pytest.raises(ValueError, match="photonic_scope"):
+        ModelConfig(photonic_scope="weights_int8")
     assert (
         engine_from_model_config(
             ModelConfig(photonic=DPUConfig(dpe_size=8), photonic_scope="none")
@@ -231,20 +238,19 @@ def test_model_config_validates_scope_and_backend():
 # PRNG-key threading (satellite bugfix)
 # ---------------------------------------------------------------------------
 def test_int8_branch_threads_prng_key_end_to_end():
-    """The int8-stored dense branch accepts prng_key (same key => bitwise
-    equal; different key => different) and raises the documented
+    """The prepacked (int8) dense branch accepts prng_key (same key =>
+    bitwise equal; different key => different) and raises the documented
     ValueError when a noisy channel has no randomness source at all."""
     ch = build_channel_model("SMWA", n=21, bits=4, datarate_gs=5.0)
     cfg = ModelConfig(
         photonic=DPUConfig(dpe_size=21, channel=ch),  # NO noise_seed
         photonic_backend="ref",
-        photonic_scope="weights_int8",
     )
     wq, sw = (
         jnp.asarray(RNG.integers(-127, 128, (200, 96)), jnp.int8),
         jnp.asarray(RNG.uniform(0.005, 0.02, (96,)), jnp.float32),
     )
-    params = {"w": wq, "w_scale": sw}
+    params = {"w": PackedDense(wq, sw, 200, 96)}
     k1, k2 = jax.random.PRNGKey(0), jax.random.PRNGKey(1)
     a = dense(params, X, cfg, site="ffn.wi", prng_key=k1)
     b = dense(params, X, cfg, site="ffn.wi", prng_key=k1)
@@ -429,17 +435,18 @@ def test_serve_prepacked_outputs_match_per_call():
 # Legacy API stability
 # ---------------------------------------------------------------------------
 def test_legacy_photonic_gemm_matches_oracle_composition():
-    """photonic_gemm (compat wrapper, site=None) == quantize ∘ dpu_int_gemm
-    ∘ dequantize with the legacy seed derivation — the pre-engine pipeline."""
-    from repro.core.dpu import dpu_int_gemm, quantize_symmetric
-    from repro.kernels.photonic_gemm.ops import photonic_gemm
+    """matmul_float (site=None) == quantize ∘ int_gemm ∘ dequantize with
+    the unfolded seed derivation — the float entry point is the integer
+    datapath between the two digital shoulders, bit for bit, noise
+    included."""
+    from repro.core.dpu import quantize_symmetric
 
-    dpu = _noisy_dpu(noise_seed=9)
-    y = photonic_gemm(X, W, dpu, "ref")
+    eng = engine_for(_noisy_dpu(noise_seed=9), "ref")
+    y = eng.matmul_float(X, W)
     xq, sx = quantize_symmetric(X, 8)
     wq, sw = quantize_symmetric(W, 8, axis=0)
     gold = (
-        np.asarray(dpu_int_gemm(xq, wq, dpu), np.float32)
+        np.asarray(eng.int_gemm(xq, wq), np.float32)
         * np.asarray(sx)
         * np.asarray(sw)
     )
@@ -586,14 +593,12 @@ def test_serve_prepack_preserves_absorbed_mla_decode_bitwise():
 
 
 def test_legacy_tile_c_parameter_honored():
-    """photonic_gemm_int(tile_c=256) keeps the legacy tiling (values above
-    128 are legal for the per-call pallas path)."""
-    from repro.kernels.photonic_gemm.ops import photonic_gemm_int
-
+    """int_gemm(tile_c=256) honours the caller's column tile (values
+    above 128 are legal for the per-call pallas path)."""
     rng = np.random.default_rng(2)
     xq = jnp.asarray(rng.integers(-127, 128, (8, 256)), jnp.int8)
     wq = jnp.asarray(rng.integers(-127, 128, (256, 256)), jnp.int8)
     cfg = DPUConfig(organization="SMWA", bits=4, dpe_size=64)
     gold = exact_int_gemm(xq, wq)
-    out = photonic_gemm_int(xq, wq, cfg, backend="pallas", tile_c=256)
+    out = engine_for(cfg, "pallas").int_gemm(xq, wq, tile_c=256)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(gold))

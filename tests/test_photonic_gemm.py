@@ -7,19 +7,12 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, strategies as st
 
-from repro.core.dpu import (
-    DPUConfig,
-    bit_slices,
-    dpu_int_gemm,
-    photonic_matmul,
-    photonic_matmul_ste,
-    quantize_symmetric,
-)
-from repro.kernels.photonic_gemm.ops import photonic_gemm, photonic_gemm_int
+from repro.core.dpu import DPUConfig, bit_slices, quantize_symmetric
 from repro.kernels.photonic_gemm.ref import (
     exact_int_gemm,
     slice_decompose,
 )
+from repro.photonic import engine_for
 
 
 def _rand_int8(rng, shape):
@@ -52,8 +45,8 @@ def test_pallas_matches_oracle(shape, bits, operand_bits):
         wq = jnp.clip(wq, -lim, lim)
     cfg = DPUConfig(bits=bits, operand_bits=operand_bits, dpe_size=83)
     gold = exact_int_gemm(xq, wq)
-    ref = photonic_gemm_int(xq, wq, cfg, backend="ref")
-    pal = photonic_gemm_int(xq, wq, cfg, backend="pallas")
+    ref = engine_for(cfg, "ref").int_gemm(xq, wq)
+    pal = engine_for(cfg, "pallas").int_gemm(xq, wq)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(gold))
     np.testing.assert_array_equal(np.asarray(pal), np.asarray(gold))
 
@@ -64,8 +57,8 @@ def test_pallas_adc_saturation_matches_ref(adc_bits):
     xq = _rand_int8(rng, (32, 256))
     wq = _rand_int8(rng, (256, 64))
     cfg = DPUConfig(dpe_size=42, adc_bits=adc_bits)
-    ref = photonic_gemm_int(xq, wq, cfg, backend="ref")
-    pal = photonic_gemm_int(xq, wq, cfg, backend="pallas")
+    ref = engine_for(cfg, "ref").int_gemm(xq, wq)
+    pal = engine_for(cfg, "pallas").int_gemm(xq, wq)
     np.testing.assert_array_equal(np.asarray(pal), np.asarray(ref))
 
 
@@ -75,9 +68,8 @@ def test_adc_saturation_bounds_error():
     xq = _rand_int8(rng, (16, 512))
     wq = _rand_int8(rng, (512, 32))
     gold = np.asarray(exact_int_gemm(xq, wq))
-    sat = np.asarray(
-        photonic_gemm_int(xq, wq, DPUConfig(dpe_size=64, adc_bits=8), backend="ref")
-    )
+    cfg = DPUConfig(dpe_size=64, adc_bits=8)
+    sat = np.asarray(engine_for(cfg, "ref").int_gemm(xq, wq))
     assert np.abs(sat).max() <= np.abs(gold).max()
 
 
@@ -86,7 +78,7 @@ def test_float_roundtrip_error_small(dtype):
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(8, 96)), dtype)
     w = jnp.asarray(rng.normal(size=(96, 48)), dtype)
-    y = photonic_gemm(x, w, DPUConfig(dpe_size=48), "pallas")
+    y = engine_for(DPUConfig(dpe_size=48), "pallas").matmul_float(x, w)
     ye = (x.astype(jnp.float32) @ w.astype(jnp.float32)).astype(dtype)
     rel = float(
         jnp.linalg.norm((y - ye).astype(jnp.float32))
@@ -114,7 +106,7 @@ def test_dpu_gemm_exact_property(r, k, c, bits, n, seed):
     xq = _rand_int8(rng, (r, k))
     wq = _rand_int8(rng, (k, c))
     cfg = DPUConfig(bits=bits, dpe_size=n)
-    out = dpu_int_gemm(xq, wq, cfg)
+    out = engine_for(cfg, "ref").int_gemm(xq, wq)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(exact_int_gemm(xq, wq)))
 
 
@@ -164,7 +156,7 @@ def test_more_noise_worse_error_monotonicity(b_lo, seed):
 
     def err(sigma):
         cfg = DPUConfig(bits=b_lo, dpe_size=32, noise_sigma_lsb=sigma)
-        y = photonic_matmul(x, w, cfg, prng_key=key)
+        y = engine_for(cfg, "ref").matmul_float(x, w, prng_key=key)
         return float(jnp.linalg.norm(y - exact))
 
     e0, e1, e2 = err(0.0), err(2.0), err(16.0)
@@ -177,14 +169,14 @@ def test_ste_gradients_match_dense_path():
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.normal(size=(4, 8, 32)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(32, 16)), jnp.float32)
-    cfg = DPUConfig(dpe_size=16)
+    eng = engine_for(DPUConfig(dpe_size=16), "ref")
 
     gx, gw = jax.grad(
-        lambda x, w: (photonic_matmul_ste(x, w, cfg) ** 2).sum(), argnums=(0, 1)
+        lambda x, w: (eng.matmul_float(x, w) ** 2).sum(), argnums=(0, 1)
     )(x, w)
     # Compare direction against the dense-path gradient of the same loss
     # evaluated at the quantized output (STE: identity through quantizer).
-    y = photonic_matmul(x, w, cfg)
+    y = eng.matmul_float(x, w)
     gx_e = jnp.einsum("bsc,kc->bsk", 2 * y, w)
     gw_e = jnp.einsum("bsk,bsc->kc", x, 2 * y)
     assert float(jnp.linalg.norm(gx - gx_e) / jnp.linalg.norm(gx_e)) < 1e-5

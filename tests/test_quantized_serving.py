@@ -1,4 +1,4 @@
-"""int8 serving paths: int8 KV cache and int8-stored (photonic) weights."""
+"""int8 serving paths: int8 KV cache and prepacked int8 (photonic) weights."""
 
 import dataclasses
 
@@ -9,7 +9,8 @@ import pytest
 
 from repro.core.dpu import DPUConfig
 from repro.models import registry
-from repro.models.common import init_tree, quantize_params
+from repro.models.common import engine_from_model_config, init_tree
+from repro.photonic import PackedDense, prepack_params
 
 
 def _roundtrip(arch, cfg, params, toks, T):
@@ -57,14 +58,16 @@ def test_int8_weight_storage_close_to_float():
         cfg,
         photonic=DPUConfig(organization="SMWA", bits=4, datarate_gs=5.0),
         photonic_backend="ref",
-        photonic_scope="weights_int8",
     )
     params = init_tree(arch.param_defs(cfg), jax.random.PRNGKey(0), cfg.param_dtype)
     defs_q = arch.param_defs(cfg_q)
-    params_q = quantize_params(params, defs_q)
-    # int8 leaves exist with scales
-    leaves = jax.tree_util.tree_flatten_with_path(params_q)[0]
-    assert any(l.dtype == jnp.int8 for _, l in leaves)
+    params_q = prepack_params(params, defs_q, engine_from_model_config(cfg_q))
+    # int8 weights exist, as prepacked leaves with their scales
+    packs = jax.tree_util.tree_leaves(
+        params_q, is_leaf=lambda l: isinstance(l, PackedDense)
+    )
+    packs = [p for p in packs if isinstance(p, PackedDense)]
+    assert packs and all(p.wq.dtype == jnp.int8 for p in packs)
 
     rng = np.random.default_rng(1)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 16)), jnp.int32)
